@@ -8,8 +8,10 @@
 # Build:  docker build -t kepler-tpu:latest .
 # The same image serves both roles:
 #   node agent :  kepler-tpu  (default CMD)
-#   aggregator :  kepler-tpu-aggregator  (needs TPU-visible runtime, e.g.
-#                 a node pool with TPU drivers; JAX falls back to CPU)
+#   aggregator :  kepler-tpu-aggregator  (owns the chip: needs a TPU-visible
+#                 runtime, e.g. a node pool with TPU drivers. With
+#                 `tpu.platform: tpu` it refuses to start without one;
+#                 `tpu.platform: cpu` runs it on the CPU on purpose)
 
 FROM python:3.12-slim AS build
 

@@ -39,8 +39,12 @@ chaos-long: ## extended kepchaos sweep: 100 randomized schedules from seed 1
 .PHONY: verify
 verify: lint chaos multihost ## the lint surface plus the chaos subset and the multi-host dryrun — the PR gate's sibling path
 
+.PHONY: chip-smoke
+chip-smoke: ## ON A TPU HOST: the aggregator's window path end to end, checked against NumPy; fails without a chip (tier-1 covers its logic on a CPU child)
+	$(PYTHON) chip_smoke.py
+
 .PHONY: bench
-bench: ## north-star benchmark; prints one JSON line (BASELINE.json metric)
+bench: ## north-star benchmark; one process, fails when JAX finds no accelerator unless JAX_PLATFORMS=cpu is set on purpose
 	$(PYTHON) bench.py
 
 .PHONY: bench-scenarios
@@ -48,7 +52,7 @@ bench-scenarios: ## five BASELINE.json scenarios + temporal-fleet; budget GATE (
 	$(PYTHON) benchmarks/scenarios.py
 
 .PHONY: dryrun
-dryrun: ## compile-check driver entry points on a virtual 8-device mesh
+dryrun: ## compile-check driver entry points on a virtual 8-device mesh (CPU-pinned child; the parent stays off jax)
 	$(PYTHON) __graft_entry__.py
 
 .PHONY: multichip

@@ -333,6 +333,9 @@ def main() -> None:
     ap.add_argument("--replay", nargs="?", const=DEFAULT_CAPTURE,
                     help="validate a capture instead of the live host")
     args = ap.parse_args()
+    from kepler_tpu.utils.jaxenv import configure_compile_cache
+
+    configure_compile_cache()
 
     if args.capture:
         print(json.dumps(capture(args.capture, args.windows,

@@ -904,10 +904,9 @@ def main() -> None:
                    help="steady-state (post-ramp) RSS growth gate")
     p.add_argument("--no-gate", action="store_true")
     args = p.parse_args()
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        import jax
+    from kepler_tpu.utils.jaxenv import configure_compile_cache
 
-        jax.config.update("jax_platforms", "cpu")
+    configure_compile_cache()
     if args.diurnal and (args.shed or args.kill_at):
         p.error("--diurnal runs its own scale schedule; it does not "
                 "compose with --shed or --kill-at")

@@ -110,8 +110,12 @@ DESCRIPTIONS = {
     "kube.node_name": "This node's name (the informer watch filters "
                       "`spec.nodeName`; also the `node_name` metric "
                       "label).",
-    "tpu.platform": "Device selection for the attribution program: "
-                    "`auto`, `tpu`, or `cpu`.",
+    "tpu.platform": "JAX platform, pinned before the backend starts: "
+                    "`tpu` refuses to start without a TPU, `cpu` pins "
+                    "the CPU, `auto` is JAX's choice in the aggregator "
+                    "(logged with `device_kind`) and `cpu` in the node "
+                    "agent — one process per chip, and the agent does "
+                    "not own it.",
     "tpu.workload_bucket": "Workload-axis padding bucket — ragged "
                            "workload counts round up to a multiple so "
                            "the jit cache sees O(buckets) shapes.",
@@ -124,9 +128,12 @@ DESCRIPTIONS = {
                          "(XLA-fused) or `pallas` (hand-written Mosaic "
                          "kernel).",
     "tpu.compilation_cache_dir": "Persistent XLA compilation cache "
-                                 "directory (empty = off): "
-                                 "bucket-crossing and restart compiles "
-                                 "become disk hits.",
+                                 "directory (empty = "
+                                 "`<checkout>/.jax_cache`; the "
+                                 "`JAX_COMPILATION_CACHE_DIR` "
+                                 "environment variable wins over "
+                                 "both): bucket-crossing and restart "
+                                 "compiles become disk hits.",
     "aggregator.enabled": "Run the cluster-aggregator role (ingest node "
                           "reports, batched fleet attribution).",
     "aggregator.listen_address": "Aggregator API listen address.",

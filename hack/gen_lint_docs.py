@@ -265,7 +265,7 @@ strict mypy tier (`config/`, `monitor/snapshot`, `fleet/wire`,
 `fleet/window`, `fleet/scoreboard`, `fleet/aggregator`,
 `fleet/membership`, `fleet/delivery`, `fault/`, `analysis/` (the
 protocol tier included), `parallel/packed`, `parallel/mesh`,
-`parallel/compat` — fully typed, `disallow_untyped_defs`) and a
+`utils/jaxenv` — fully typed, `disallow_untyped_defs`) and a
 checked tier (`monitor/`, `fleet/`, `service/` —
 `check_untyped_defs`); modules move *up* tiers, never down.
 

@@ -44,6 +44,11 @@ COLLECTIVE_PRIMS = frozenset({
     "all_gather", "all_gather_invariant", "reduce_scatter", "pgather",
 })
 
+#: how the installed jax spells a collective under the varying-axes
+#: checker → the name the allowlists use (``lax.psum`` inside a checked
+#: ``shard_map`` traces as ``psum_invariant``)
+COLLECTIVE_ALIASES = {"psum_invariant": "psum"}
+
 #: wrapper/bookkeeping primitives whose counts are jax-version noise
 #: (pjit nesting depth, replication-cast insertion); excluded from the
 #: fingerprint histogram so the KTL123 ratchet pins PROGRAM structure,
@@ -155,9 +160,11 @@ def parse_main_arg_attrs(text: str) -> dict[int, dict[str, bool]]:
 
     → ``{flat_arg_index: {"aliased": bool, "donor": bool}}``. The
     signature is located as the lines from ``func.func public @main(``
-    up to the body-opening brace; attribute dicts may embed quoted
-    strings that themselves contain braces (``mhlo.sharding``), which
-    the regex tolerates.
+    up to the body-opening brace. An attribute dict is read by brace
+    matching, not by a regex: it may embed quoted strings that contain
+    braces (``mhlo.sharding = "{devices=[8,1]<=[8]}"``) and, under
+    Shardy, nested braces of its own
+    (``sdy.sharding = #sdy.sharding<@mesh, [{"node"}, {}]>``).
     """
     start = text.find("func.func public @main(")
     if start < 0:
@@ -171,16 +178,34 @@ def parse_main_arg_attrs(text: str) -> dict[int, dict[str, bool]]:
             break
     sig = " ".join(sig_lines)
     out: dict[int, dict[str, bool]] = {}
-    for m in re.finditer(
-            r'%arg(\d+):\s*tensor<[^>]*>\s*'
-            r'(\{(?:[^{}"]|"[^"]*")*\})?', sig):
-        idx = int(m.group(1))
-        attrs = m.group(2) or ""
-        out[idx] = {
+    for m in re.finditer(r'%arg(\d+):\s*tensor<[^>]*>\s*', sig):
+        attrs = _attr_dict_at(sig, m.end())
+        out[int(m.group(1))] = {
             "aliased": "tf.aliasing_output" in attrs,
             "donor": "jax.buffer_donor" in attrs,
         }
     return out
+
+
+def _attr_dict_at(sig: str, pos: int) -> str:
+    """The balanced ``{...}`` starting at ``sig[pos]`` ("" when the
+    argument carries no attribute dict). Braces inside double-quoted
+    strings do not count."""
+    if pos >= len(sig) or sig[pos] != "{":
+        return ""
+    depth = 0
+    quoted = False
+    for i in range(pos, len(sig)):
+        ch = sig[i]
+        if ch == '"':
+            quoted = not quoted
+        elif not quoted and ch == "{":
+            depth += 1
+        elif not quoted and ch == "}":
+            depth -= 1
+            if depth == 0:
+                return sig[pos:i + 1]
+    return sig[pos:]  # unbalanced: the rest of the signature
 
 
 def trace_case(spec: "ProgramSpec", case: "ProgramCase") -> TraceReport:
@@ -201,8 +226,9 @@ def trace_case(spec: "ProgramSpec", case: "ProgramCase") -> TraceReport:
             report.prim_counts[name] = report.prim_counts.get(name, 0) + 1
         if name == "shard_map":
             report.has_shard_map = True
-        if name in COLLECTIVE_PRIMS:
-            report.collectives.add(name)
+        collective = COLLECTIVE_ALIASES.get(name, name)
+        if collective in COLLECTIVE_PRIMS:
+            report.collectives.add(collective)
         elif name == "convert_element_type":
             src, dst = _dtype_name(eqn.invars[0]), _dtype_name(eqn.outvars[0])
             if src in HALF_DTYPES or dst in HALF_DTYPES:

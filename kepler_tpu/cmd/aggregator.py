@@ -44,6 +44,11 @@ def main(argv: Sequence[str] | None = None) -> int:
     from kepler_tpu import fault, telemetry
     fault.install_from_config(cfg.fault)
     telemetry.install_from_config(cfg.telemetry)
+    # platform pin + compile-cache placement come before anything can
+    # touch the backend (the multi-host join below is the first that does)
+    from kepler_tpu.utils import jaxenv
+    jaxenv.select_platform(cfg.tpu.platform)
+    cache_dir = jaxenv.configure_compile_cache(cfg.tpu.compilation_cache_dir)
     # multi-host DCN: join the cluster BEFORE any jax API initialises the
     # backend (no-op single-host). Config knobs take precedence over the
     # JAX_* env convention; a failed join logs its DISTINCT reason
@@ -62,9 +67,16 @@ def main(argv: Sequence[str] | None = None) -> int:
         log.warning("multihost enabled but not joined (%s)%s — running "
                     "single-host", joined.reason,
                     f": {joined.detail}" if joined.detail else "")
+    try:
+        device = jaxenv.require_devices(cfg.tpu.platform)
+    except RuntimeError as err:
+        print(f"error: {err}", file=sys.stderr)
+        return 1
     info = version.info()
     log.info("kepler-tpu aggregator %s (%s, %s)", info.version,
              info.python_version, info.platform)
+    log.info("jax %s (tpu.platform=%s), compile cache %s", device,
+             cfg.tpu.platform, cache_dir)
 
     params = None
     if cfg.aggregator.params_path:

@@ -36,6 +36,7 @@ from kepler_tpu.service.lifecycle import (
     init_services,
     run_services,
 )
+from kepler_tpu.utils import jaxenv
 from kepler_tpu.utils.logger import new_logger
 
 log = logging.getLogger("kepler.main")
@@ -74,13 +75,6 @@ def create_cpu_meter(cfg: Config):
 
 def create_services(cfg: Config) -> list:
     """reference createServices (main.go:124-225)."""
-    if cfg.tpu.compilation_cache_dir:
-        # persistent XLA cache: bucket-crossing / restart compiles become
-        # disk hits (statelessness stays intact — it is only a cache)
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir",
-                          cfg.tpu.compilation_cache_dir)
     meter = create_cpu_meter(cfg)
 
     pod_lookup = None
@@ -247,6 +241,17 @@ def main(argv: Sequence[str] | None = None) -> int:
              info.platform)
 
     try:
+        # the node agent does not own the chip (the aggregator does, and
+        # a chip serves one process): auto means the CPU here
+        platform = "cpu" if cfg.tpu.platform == "auto" else cfg.tpu.platform
+        jaxenv.select_platform(platform)
+        # persistent XLA cache: bucket-crossing / restart compiles become
+        # disk hits (statelessness stays intact — it is only a cache)
+        cache_dir = jaxenv.configure_compile_cache(
+            cfg.tpu.compilation_cache_dir)
+        device = jaxenv.require_devices(platform)
+        log.info("jax %s (tpu.platform=%s), compile cache %s", device,
+                 cfg.tpu.platform, cache_dir)
         fault.install_from_config(cfg.fault)
         telemetry.install_from_config(cfg.telemetry)
         if cfg.telemetry.journal.enabled:

@@ -119,6 +119,10 @@ def main(argv: Sequence[str] | None = None) -> int:
     logging.basicConfig(level=logging.INFO, format="%(message)s",
                         stream=sys.stderr)
 
+    from kepler_tpu.utils.jaxenv import configure_compile_cache
+
+    configure_compile_cache()
+
     import jax
     import jax.numpy as jnp
 

@@ -187,15 +187,20 @@ class TPUConfig:
     Controls where attribution math runs and how fleet batches are shaped.
     """
 
-    platform: str = "auto"  # auto | tpu | cpu — jax platform for attribution
+    # jax platform, pinned before the backend starts: tpu = refuse to
+    # start without one, cpu = pin the CPU, auto = jax's choice in the
+    # aggregator (logged) and cpu in the node agent, which does not own
+    # the chip (one process per chip)
+    platform: str = "auto"
     # Pad workload axis to the next multiple of this to bound recompilation
     # (bucketed batch shapes; SURVEY §7 hard part (a)).
     workload_bucket: int = 256
     node_bucket: int = 8  # fleet aggregator node-axis bucket
     mesh_shape: list[int] = field(default_factory=list)  # [] = all devices, 1D
     mesh_axes: list[str] = field(default_factory=lambda: ["node"])
-    # persistent XLA compilation cache dir ("" = off): bucket-crossing and
-    # restart compiles become disk hits instead of fresh XLA runs
+    # persistent XLA compilation cache dir ("" = <checkout>/.jax_cache);
+    # JAX_COMPILATION_CACHE_DIR, when set, wins over both. Bucket-crossing
+    # and restart compiles become disk hits instead of fresh XLA runs
     compilation_cache_dir: str = ""
     # fleet attribution contraction: "einsum" (XLA-fused) | "pallas"
     # (hand-written Mosaic kernel, shard_map over the node axis)

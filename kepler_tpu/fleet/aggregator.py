@@ -843,6 +843,7 @@ class Aggregator:
         # lazy, replaced after a stall abandons it; used only by the
         # publish path (serialized by _pipeline_lock)
         self._fetch_worker: _FetchWorker | None = None
+        self._device_info: dict[str, Any] = {}  # see _device_fields()
 
     def name(self) -> str:
         return "fleet-aggregator"
@@ -959,9 +960,23 @@ class Aggregator:
             # params validated — an empty fleet is still a ready aggregator
             health.register_readiness("fleet-aggregator",
                                       lambda: {"ok": True})
-        log.info("aggregator: mesh=%s devices=%d model=%s interval=%.1fs",
-                 dict(self._mesh.shape), n_dev, self._model_mode,
-                 self._interval)
+        device = self._device_fields()
+        log.info("aggregator: platform=%s device_kind=%s devices=%d mesh=%s "
+                 "model=%s interval=%.1fs", device["platform"],
+                 device["device_kind"], device["devices"],
+                 dict(self._mesh.shape), self._model_mode, self._interval)
+
+    def _device_fields(self) -> dict[str, Any]:
+        """The engine mesh's first device as jax reports it, and the
+        mesh's device count — read once, then served by /debug/window and
+        the start-up log, so an aggregator serving off the CPU can never
+        pass for one on the chip. Empty until a mesh exists."""
+        if not self._device_info and self._mesh is not None:
+            first = self._mesh.devices.flat[0]
+            self._device_info = {"platform": first.platform,
+                                 "device_kind": first.device_kind,
+                                 "devices": int(self._mesh.devices.size)}
+        return self._device_info
 
     def _mesh_shard_count(self, mesh: Any = None) -> int:
         """Shards the packed window runs over: the node-axis size when
@@ -2704,8 +2719,8 @@ class Aggregator:
     def _fetch_device(self, fn: "Callable[[], object]") -> object:
         """Blocking device fetch with MonitorWatchdog-style stall
         detection: the fetch runs on the persistent ``_FetchWorker``
-        thread bounded by ``dispatch_timeout`` — a hung dispatch (wedged
-        tunnel, dead device runtime) DEMOTES instead of wedging the
+        thread bounded by ``dispatch_timeout`` — a hung dispatch (dead
+        device runtime, lost chip) DEMOTES instead of wedging the
         aggregation loop forever. On a stall the worker is abandoned
         (parked in native code on a handle the ring re-seed guarantees
         nothing else reads) and replaced lazily. ``device.stall``
@@ -3665,6 +3680,7 @@ class Aggregator:
         introspection snapshot (coherent, no live engine access)."""
         with self._results_lock:
             payload: dict = {
+                **self._device_fields(),
                 "rung": self._rung,
                 "rung_name": self._rung_display(self._rung),
                 "shards": (self._shard_count

@@ -102,62 +102,71 @@ def outer_product_attribution(
             jnp.transpose(power_znw, (1, 2, 0)))
 
 
-def _fused_window_kernel(res_ref, rows_ref, idx_ref, newres_ref, out_ref,
-                         *, lay, tn):
+def _fused_window_kernel(res_ref, rows_ref, idx_ref, newres_ref, watts_ref,
+                         active_ref, total_ref, *, lay, tn):
     """One grid step of the fused window mega-kernel (node tile ``i``).
 
     Does the WHOLE rung-0 window for its ``[TN, width]`` resident tile in
     one pass: scatter the interval's delta rows into the tile, unpack the
-    packed fields, run ratio attribution, and emit the packed f16 watts
-    block (workload rows + node ACTIVE + node TOTAL) — the three device
+    packed fields, run ratio attribution, and emit the window's watts
+    (workload rows, node ACTIVE, node TOTAL) — the three device
     round-trips of the unfused path collapsed into one kernel body.
 
-    The scatter has no in-kernel gather: a ``[DB, TN]`` hit matrix
-    (delta index == global row id) turns row selection into a 0/1 matmul
-    — exact, since delta indices are unique per interval, so every output
-    row sums at most one product. NaN (the invalid-slot encoding in the
-    cpu columns) would poison ``0 × NaN``; the NaN mask rides through a
-    second matmul and is re-applied after.
+    The scatter has no in-kernel gather: a ``[TN, DB]`` hit matrix
+    (global row id == delta index) turns row selection into a 0/1 matmul
+    — exact at HIGHEST precision, since delta indices are unique per
+    interval, so every output row sums at most one product. NaN (the
+    invalid-slot encoding in the cpu columns) would poison ``0 × NaN``;
+    the NaN mask rides through a second matmul and is re-applied after.
+
+    Every intermediate stays rank-2 with the node tile on sublanes:
+    Mosaic has no relayout from a lane-major ``[TN]`` vector to a
+    ``[TN, 1]`` column, so single columns are width-1 slices and the hit
+    matrix is built already in ``[TN, DB]`` orientation from a ``[1, DB]``
+    index row.
     """
     i = pl.program_id(0)
     res = res_ref[...]  # [TN, width] f32
     drows = rows_ref[...]  # [DB, width] f32
-    didx = idx_ref[...]  # [DB, 1] i32 (pad = N: matches no row id)
-    row_ids = i * tn + jax.lax.broadcasted_iota(jnp.int32, (1, tn), 1)
-    hit = didx == row_ids  # [DB, TN]
-    anyhit = jnp.any(hit, axis=0)  # [TN]
-    hitf = hit.astype(jnp.float32)
+    didx = idx_ref[...]  # [1, DB] i32 (pad = N: matches no row id)
+    db = drows.shape[0]
+    row_ids = i * tn + jax.lax.broadcasted_iota(jnp.int32, (tn, db), 0)
+    hitf = (row_ids == didx).astype(jnp.float32)  # [TN, DB]
+    anyhit = jnp.sum(hitf, axis=1, keepdims=True) > 0.5  # [TN, 1]
     nan_mask = jnp.isnan(drows)
-    sel = jnp.dot(hitf.T, jnp.where(nan_mask, 0.0, drows))  # [TN, width]
-    sel_nan = jnp.dot(hitf.T, nan_mask.astype(jnp.float32))
+    exact = dict(preferred_element_type=jnp.float32,
+                 precision=jax.lax.Precision.HIGHEST)
+    sel = jnp.dot(hitf, jnp.where(nan_mask, 0.0, drows), **exact)
+    sel_nan = jnp.dot(hitf, nan_mask.astype(jnp.float32), **exact)
     sel = jnp.where(sel_nan > 0.5, jnp.float32(jnp.nan), sel)
-    rows = jnp.where(anyhit[:, None], sel, res)
+    rows = jnp.where(anyhit, sel, res)  # [TN, width]
     newres_ref[...] = rows
 
     # unpack (PackedLayout-derived slices, passed in statically) + the
     # exact ops.attribution formula chain, tile-local
+    def col(c):
+        return rows[:, c:c + 1]  # [TN, 1]
+
     cpu_nan = rows[:, lay.cpu]
     workload_valid = ~jnp.isnan(cpu_nan)
     cpu = jnp.where(workload_valid, cpu_nan, 0.0)
     zone = rows[:, lay.zone]
     zone_valid = rows[:, lay.zone_valid] > 0.5
-    ratio = rows[:, lay.col_ratio]
-    denom = rows[:, lay.col_denom]
-    dt = rows[:, lay.col_dt]
+    ratio = col(lay.col_ratio)
+    denom = col(lay.col_denom)
+    dt = col(lay.col_dt)
 
     deltas = jnp.where(zone_valid, zone, 0.0)  # [TN, Z]
-    active = deltas * jnp.clip(ratio, 0.0, 1.0)[:, None]
-    dtc = dt[:, None]
-    safe_dt = jnp.where(dtc > 0.0, dtc, 1.0)
-    total_uw = jnp.where(dtc > 0.0, deltas / safe_dt, 0.0)
-    active_uw = jnp.where(dtc > 0.0, active / safe_dt, 0.0)
-    d = denom[:, None]
-    ratios = jnp.where(d > 0.0, cpu / jnp.maximum(d, 1e-30), 0.0)  # [TN, W]
+    active = deltas * jnp.clip(ratio, 0.0, 1.0)
+    safe_dt = jnp.where(dt > 0.0, dt, 1.0)
+    total_uw = jnp.where(dt > 0.0, deltas / safe_dt, 0.0)
+    active_uw = jnp.where(dt > 0.0, active / safe_dt, 0.0)
+    ratios = jnp.where(denom > 0.0, cpu / jnp.maximum(denom, 1e-30),
+                       0.0)  # [TN, W]
+    active_ref[...] = active_uw * 1e-6  # µW → W, as _pack_watts_f16
+    total_ref[...] = total_uw * 1e-6
     for zi in range(lay.n_zones):  # static unroll (Z is tiny)
-        col_a = active_uw[:, zi][:, None]  # [TN, 1]
-        col_t = total_uw[:, zi][:, None]
-        watts = jnp.concatenate([ratios * col_a, col_a, col_t], axis=1)
-        out_ref[zi] = (watts * 1e-6).astype(jnp.float16)
+        watts_ref[zi] = (ratios * active_uw[:, zi:zi + 1]) * 1e-6
 
 
 def fused_window_step(
@@ -177,36 +186,45 @@ def fused_window_step(
     design (the dense-model fused path composes XLA ops instead); used
     as the ``lax.scan`` body of the pallas-backend fused window program.
 
-    The kernel grid is 1-D over node tiles; the watts output lands as
-    ``[Z, N, W+2]`` (lane-friendly tiles, same trick as
-    ``outer_product_attribution``) and is transposed once on the way out.
+    The kernel grid is 1-D over node tiles. Workload watts land as f32
+    ``[Z, N, W]`` (lane-aligned tiles, same trick as
+    ``outer_product_attribution``) and the two node rows as ``[N, Z]``
+    each; the wrapper assembles the packed ``[N, W+2, Z]`` layout and
+    quantizes to f16 in one XLA pass (the TPU vector unit has no f16,
+    and a lane-axis concatenate at W is not a Mosaic layout).
     """
     n = resident.shape[0]
     db = delta_rows.shape[0]
+    z, w = lay.n_zones, lay.n_workloads
     tn = _tile(n, 512, 8)
     grid = (n // tn,)
     kernel = functools.partial(_fused_window_kernel, lay=lay, tn=tn)
     res_spec = pl.BlockSpec((tn, lay.width), lambda i: (i, 0))
-    out_znw = jax.ShapeDtypeStruct((lay.n_zones, n, lay.n_workloads + 2),
-                                   jnp.float16)
-    newres, watts_znw = pl.pallas_call(
+    node_spec = pl.BlockSpec((tn, z), lambda i: (i, 0))
+    node_shape = jax.ShapeDtypeStruct((n, z), jnp.float32)
+    newres, watts_znw, active_w, total_w = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             res_spec,
             pl.BlockSpec((db, lay.width), lambda i: (0, 0)),
-            pl.BlockSpec((db, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, db), lambda i: (0, 0)),
         ],
         out_specs=[
             res_spec,
-            pl.BlockSpec((lay.n_zones, tn, lay.n_workloads + 2),
-                         lambda i: (0, i, 0)),
+            pl.BlockSpec((z, tn, w), lambda i: (0, i, 0)),
+            node_spec,
+            node_spec,
         ],
         out_shape=[jax.ShapeDtypeStruct((n, lay.width), jnp.float32),
-                   out_znw],
+                   jax.ShapeDtypeStruct((z, n, w), jnp.float32),
+                   node_shape, node_shape],
         interpret=interpret,
-    )(resident, delta_rows, delta_idx[:, None])
-    return newres, jnp.transpose(watts_znw, (1, 2, 0))
+    )(resident, delta_rows, delta_idx[None, :])
+    packed = jnp.concatenate(
+        [jnp.transpose(watts_znw, (1, 2, 0)), active_w[:, None, :],
+         total_w[:, None, :]], axis=1)
+    return newres, packed.astype(jnp.float16)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -177,10 +177,8 @@ def shard_by_node(fn, mesh: Mesh, in_specs):
     layout, not semantics. check_vma=False because pallas_call defeats the
     varying-axes checker.
     """
-    from kepler_tpu.parallel.compat import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=P(NODE_AXIS), check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(NODE_AXIS), check_vma=False)
 
 
 def accuracy_mode_predictor(predict_fn, model_mode: str):

@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kepler_tpu.models.moe import MoEParams, expert_forward, gate_logits
-from kepler_tpu.parallel.compat import shard_map
 
 EXPERT_AXIS = "expert"
 
@@ -109,7 +108,7 @@ def make_expert_parallel_moe(
                                  capacity=capacity,
                                  compute_dtype=compute_dtype)
         experts = {k: params[k] for k in expert_keys}
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=({k: P(axis_name) for k in expert_keys},

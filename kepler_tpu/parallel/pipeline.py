@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from kepler_tpu.parallel.compat import pcast_varying, shard_map
-
 STAGE_AXIS = "stage"
 
 
@@ -63,8 +61,9 @@ def _pp_shard(stage_params, x_mb, *, axis_name, stage_fn):
 
     # zeros-initialised carries must be marked device-varying over the stage
     # axis up front or the fori_loop carry types mismatch (shard_map vma rule)
-    state = pcast_varying(jnp.zeros_like(x_mb[0]), axis_name)
-    out = pcast_varying(jnp.zeros_like(x_mb), axis_name)
+    state = jax.lax.pcast(jnp.zeros_like(x_mb[0]), axis_name,
+                          to="varying")
+    out = jax.lax.pcast(jnp.zeros_like(x_mb), axis_name, to="varying")
     _, out = jax.lax.fori_loop(0, m + n - 1, tick, (state, out))
     # every stage wrote a buffer; only the last stage's is the answer —
     # zero the rest and psum so the result replicates
@@ -96,7 +95,7 @@ def make_pipeline(
             raise ValueError(
                 f"batch {b} not divisible by {n_microbatches} microbatches")
         x_mb = x.reshape(n_microbatches, b // n_microbatches, *x.shape[1:])
-        out = shard_map(
+        out = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(axis_name), P()),

@@ -35,7 +35,6 @@ from kepler_tpu.ops.attention import (
     merge_blocks,
     stats_to_out,
 )
-from kepler_tpu.parallel.compat import pcast_varying, shard_map
 
 SEQ_AXIS = "seq"
 
@@ -55,7 +54,7 @@ def _ring_shard(q, k, v, t_valid, *, axis_name, causal, compute_dtype,
     # zeros-initialised carries must be marked device-varying over the ring
     # axis up front or the fori_loop carry types mismatch (shard_map vma rule)
     def vary(x):
-        return pcast_varying(x, axis_name)
+        return jax.lax.pcast(x, axis_name, to="varying")
     o = vary(jnp.zeros((b, t_loc, h, d), jnp.float32))
     m = vary(jnp.full((b, h, t_loc), _NEG_INF, jnp.float32))
     l = vary(jnp.zeros((b, h, t_loc), jnp.float32))  # noqa: E741
@@ -112,7 +111,7 @@ def ring_attention_shardmap(
     body = functools.partial(_ring_shard, axis_name=axis_name,
                              causal=causal, compute_dtype=compute_dtype,
                              backend=backend)
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(None, axis_name), P(None, axis_name),

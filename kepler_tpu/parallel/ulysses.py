@@ -35,7 +35,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kepler_tpu.ops.attention import full_attention
-from kepler_tpu.parallel.compat import shard_map
 from kepler_tpu.parallel.ring import SEQ_AXIS
 
 
@@ -81,7 +80,7 @@ def ulysses_attention_shardmap(
                 f"Ulysses needs heads ({q.shape[2]}) divisible by the "
                 f"'{axis_name}' mesh size ({n}); use the ring for more "
                 "parallelism than heads")
-        return shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(None, axis_name), P(None, axis_name),

@@ -56,8 +56,7 @@ def bind_collision(stderr: str) -> bool:
     return any(m in low for m in BIND_MARKERS)
 
 
-def run_workers(repo: str, n_proc: int, port: int,
-                sanitize_env: tuple = ()) -> list:
+def run_workers(repo: str, n_proc: int, port: int) -> list:
     """Spawn ``n_proc`` workers against one coordinator port; → per-
     worker (rc, stdout, stderr) with a HARD timeout (kill + stderr
     capture — a dead coordinator must not leave its peer blocked
@@ -68,8 +67,6 @@ def run_workers(repo: str, n_proc: int, port: int,
     pythonpath = repo + os.pathsep + os.environ.get("PYTHONPATH", "")
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "PYTHONPATH": pythonpath.rstrip(os.pathsep)}
-    for var in sanitize_env:
-        env.pop(var, None)
     workers = [
         subprocess.Popen(
             [sys.executable,

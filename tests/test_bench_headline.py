@@ -1,12 +1,11 @@
 """The bench evidence contract (ROADMAP item 5, ISSUE 6 satellite).
 
 The driver captures only a bounded TAIL of bench stdout (~2000 chars);
-rounds 4 and 5 lost the whole TPU measurement because the detail row
-outgrew it (BENCH_r04 rc=1, BENCH_r05 ``parsed: null``). The contract
-pinned here:
+round 5 lost the whole measurement because the detail row outgrew it
+(BENCH_r05 ``parsed: null``). The contract pinned here:
 
 * ``bench.py``'s LAST stdout line is a compact single-line JSON headline
-  (metric, platform, ``cpu_fallback``, gate booleans) that stays ≤ 1000
+  (metric, platform, gate booleans) that stays ≤ 1000
   chars no matter how fat the detail row gets, so it survives any
   ~2000-char tail truncation;
 * the full detail row goes to a file (``BENCH_DETAIL.json``), referenced
@@ -42,7 +41,6 @@ def fat_result(**overrides) -> dict:
         "vs_baseline": 8.1,
         "platform": "tpu",
         "backend": "einsum",
-        "cpu_fallback": False,
         "accuracy_ok": True,
         "e2e_pipeline_ok": True,
         "soak_ok": True,
@@ -97,7 +95,6 @@ class TestHeadline:
         head = json.loads(line)
         assert head["metric"] == "attribution_program_p99_ms_10k_pods"
         assert head["platform"] == "tpu"
-        assert head["cpu_fallback"] is False
         assert head["ok"] is True
         assert head["detail_file"] == "BENCH_DETAIL.json"
         for gate in ("accuracy_ok", "e2e_pipeline_ok", "soak_ok",

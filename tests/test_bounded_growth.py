@@ -11,6 +11,7 @@ cumulative history — the node-agent analog of the aggregator's RSS soak
 """
 
 import os
+import shutil
 
 import pytest
 

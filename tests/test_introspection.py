@@ -107,6 +107,30 @@ class TestDebugWindow:
         json.dumps(payload)
         agg.shutdown()
 
+    def test_names_the_device_it_serves_from(self, caplog):
+        """platform / device_kind / devices: read once from the engine's
+        mesh at init, served on /debug/window and written to the start-up
+        log — what chip_smoke.py asserts on, and what tells an aggregator
+        on the chip from one quietly serving off the CPU."""
+        import logging
+
+        import jax
+
+        first = jax.devices()[0]
+        agg = make_agg(2)
+        with caplog.at_level(logging.INFO, "kepler.fleet.aggregator"):
+            agg.init()
+        payload = window_payload(agg)  # before any window: already there
+        assert payload["platform"] == first.platform == "cpu"
+        assert payload["device_kind"] == first.device_kind
+        assert payload["devices"] == len(jax.devices())
+        line = next(r.getMessage() for r in caplog.records
+                    if r.getMessage().startswith("aggregator:"))
+        assert f"platform={first.platform}" in line
+        assert f"device_kind={first.device_kind}" in line
+        assert f"devices={len(jax.devices())}" in line
+        agg.shutdown()
+
     def test_endpoints_valid_before_first_window(self):
         agg = Aggregator(APIServer(), model_mode=None)
         payload = window_payload(agg)

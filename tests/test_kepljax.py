@@ -177,6 +177,45 @@ class TestDonationAlias:
         assert analyze_device_programs(REPO, only={"KTL121"},
                                        specs=(spec,)) == []
 
+    def test_attr_dicts_with_nested_braces_are_read(self):
+        """Shardy writes ``[{"node"}, {}]`` INSIDE the attribute dict
+        (the lowering of the window update under JAX 0.9). A parser that
+        stops at the first inner brace reports the sharded, donated
+        argument as unaliased — the stale-checker KTL121 that read as a
+        dropped donation."""
+        from kepler_tpu.analysis.device.trace import parse_main_arg_attrs
+
+        text = (
+            'module @jit_scatter_rows {\n'
+            '  sdy.mesh @mesh = <["node"=8]>\n'
+            '  func.func public @main('
+            '%arg0: tensor<16x16xf32> {sdy.sharding = #sdy.sharding<@mesh, '
+            '[{"node"}, {}]>, tf.aliasing_output = 0 : i32}, '
+            '%arg1: tensor<8x16xf32> {sdy.sharding = #sdy.sharding<@mesh, '
+            '[{}, {}]>}, '
+            '%arg2: tensor<8xi32>, '
+            '%arg3: tensor<4xf32> {mhlo.sharding = "{devices=[8,1]<=[8]}", '
+            'jax.buffer_donor = true}) -> (tensor<16x16xf32> '
+            '{jax.result_info = "result"}) {\n'
+            '    return %arg0 : tensor<16x16xf32>\n  }\n}\n')
+        assert parse_main_arg_attrs(text) == {
+            0: {"aliased": True, "donor": False},
+            1: {"aliased": False, "donor": False},
+            2: {"aliased": False, "donor": False},
+            3: {"aliased": False, "donor": True},
+        }
+
+    def test_sharded_window_update_donation_is_realized(self):
+        """The real single-device-engine update (explicit mesh
+        shardings, so Shardy attributes on every argument) lowers with
+        its resident batch aliased to the output."""
+        from kepler_tpu.analysis.device.trace import trace_case
+
+        real = spec_by_name("window.update")
+        report = trace_case(real, real.cases[0])
+        assert report.flat_indices_of_arg(0) <= report.aliased_args
+        assert report.donation_warnings == []
+
 
 # ---------------------------------------------------------------------------
 # KTL122 collective-discipline
@@ -203,14 +242,13 @@ class TestCollectiveDiscipline:
         def build(case):
             from jax.sharding import PartitionSpec as P
 
-            from kepler_tpu.parallel.compat import shard_map
             from kepler_tpu.parallel.mesh import make_mesh
 
             mesh = make_mesh((8,), ("node",),
                              devices=jax.devices()[:8])
-            body = shard_map(lambda x: jax.lax.psum(x, "node"),
-                             mesh=mesh, in_specs=(P("node"),),
-                             out_specs=P(), check_vma=False)
+            body = jax.shard_map(lambda x: jax.lax.psum(x, "node"),
+                                 mesh=mesh, in_specs=(P("node"),),
+                                 out_specs=P(), check_vma=False)
             return jax.jit(body), (_f32((8, 4)),)
 
         spec = _spec("fx.rogue_psum", build, n_devices=8,
@@ -224,14 +262,13 @@ class TestCollectiveDiscipline:
         def build(case):
             from jax.sharding import PartitionSpec as P
 
-            from kepler_tpu.parallel.compat import shard_map
             from kepler_tpu.parallel.mesh import make_mesh
 
             mesh = make_mesh((8,), ("node",),
                              devices=jax.devices()[:8])
-            body = shard_map(lambda x: jax.lax.psum(x, "node"),
-                             mesh=mesh, in_specs=(P("node"),),
-                             out_specs=P(), check_vma=False)
+            body = jax.shard_map(lambda x: jax.lax.psum(x, "node"),
+                                 mesh=mesh, in_specs=(P("node"),),
+                                 out_specs=P(), check_vma=False)
             return jax.jit(body), (_f32((8, 4)),)
 
         spec = _spec("fx.ok_psum", build, n_devices=8,

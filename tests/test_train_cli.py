@@ -21,6 +21,18 @@ from kepler_tpu.server.http import APIServer
 from kepler_tpu.parallel.mesh import make_mesh
 
 
+@pytest.fixture(autouse=True)
+def _compile_cache_stays_put(monkeypatch):
+    """train_main places the persistent compile cache like every binary;
+    in-process that would point the REST of the test session's compiles
+    at <checkout>/.jax_cache. The placement itself is tested in
+    tests/test_jaxenv.py."""
+    calls = []
+    monkeypatch.setattr("kepler_tpu.utils.jaxenv.configure_compile_cache",
+                        lambda configured="": calls.append(configured))
+    return calls
+
+
 def feed_reports(agg, n_windows=3, nodes=2, w=4, seed=0):
     rng = np.random.default_rng(seed)
 
@@ -202,6 +214,13 @@ class TestTrainCLI:
     def test_missing_data_dir_errors(self, tmp_path):
         with pytest.raises(FileNotFoundError, match="window-"):
             load_windows(str(tmp_path))
+
+    def test_places_the_compile_cache_before_the_first_compile(
+            self, tmp_path, _compile_cache_stays_put):
+        with pytest.raises(FileNotFoundError):
+            train_main(["--data", str(tmp_path), "--out",
+                        str(tmp_path / "p.npz")])
+        assert _compile_cache_stays_put == [""]  # the shared default
 
 
 class TestNestedParamsRoundtrip:
