@@ -44,6 +44,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Iterator, Mapping, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from kepler_tpu import fault, telemetry
 from kepler_tpu.fleet.admission import (
@@ -88,6 +89,8 @@ from kepler_tpu.fleet.wire import (
     try_parse_header,
 )
 from kepler_tpu.fleet.scoreboard import STATE_NAMES, FleetScoreboard
+from kepler_tpu.fleet.window_record import (WindowLedger, WindowRecord,
+                                            records_json)
 from kepler_tpu.fleet.window import (DeviceWindowError, FusedFlush,
                                      FusedWindowEngine,
                                      MultiHostWindowEngine,
@@ -99,7 +102,7 @@ from kepler_tpu.telemetry import DEFAULT_DELIVERY_BUCKETS, Histogram
 from kepler_tpu.parallel.aggregator_core import (
     make_fleet_program,
     make_temporal_fleet_program,
-    run_fleet_attribution,
+    put_fleet_batch,
 )
 from kepler_tpu.parallel.fleet import (MODE_MODEL, NodeReport,
                                        assemble_fleet_batch)
@@ -217,6 +220,12 @@ def _primary_introspect(snap: Mapping[str, dict]) -> dict | None:
     return fused or pipelined or serial
 
 
+def _no_clock() -> float:
+    """In the place of ``time.monotonic`` where telemetry is off: the
+    ingest sums read no clock then."""
+    return 0.0
+
+
 def _report_power_w(report: NodeReport) -> float:
     """The node's self-reported power this window (valid zone energy
     over the window interval), the scoreboard's anomaly signal. Returns
@@ -246,10 +255,10 @@ class _Pending:
     out: object  # device handle(s): packed f16 array, or FleetResult
     meta: WindowMeta | None  # packed path row layout
     now: float  # publication timestamp (dispatch-time clock)
-    assembly_ms: float
-    dispatch_ms: float
+    # the window's own record: its marks are the path's one clock, the
+    # last_*_ms gauges are differences of them (fleet/window_record.py)
+    rec: WindowRecord
     h2d_rows: int
-    compiled: bool
     # packed path: per-shard H2D breakdown + shard count ((), 1 when the
     # dispatching engine was unsharded; legacy/numpy paths leave 1)
     h2d_shards: tuple = ()
@@ -260,7 +269,7 @@ class _Pending:
     fetch: Callable | None = None
     # fused path (kind "fused"): `out` is already a HOST slice of the
     # batch fetch. The whole batch's device cost is carried by its LAST
-    # window (`dispatch_ms`; earlier windows publish with 0 — the K−1
+    # window's record (earlier windows publish with zero legs — the K−1
     # free rides are the amortization), and sync_per_window_ms is the
     # honest averaged figure (−1 on non-fused windows).
     sync_per_window_ms: float = -1.0
@@ -767,6 +776,21 @@ class Aggregator:
         self._cum_last_seen: dict[str, float] = {}
         self._cum_retention = max(stale_after * 20.0, 600.0)
         self._program = None  # legacy-path jit; jax caches per input shape
+        self._legacy_compiles = 0  # its cold dispatches (aggregation loop)
+        # one record per window (fleet/window_record.py): the id the next
+        # snapshot takes, when the loop began the wait before it, and the
+        # complete records with their cumulative legs
+        self._window_seq = 0
+        self._tick_began: float | None = None
+        self._window_ledger = WindowLedger()  # keplint: guarded-by=_results_lock
+        # ingest seconds since start, one sample per report that reached
+        # the store: decode, waiting for the store lock, the merge under
+        # it, and the history push inside the merge (/debug/window).
+        # Company of the aggregator.decode / .merge spans: like them, not
+        # taken with telemetry.enabled false
+        self._ingest_legs = {"reports": 0, "decode_s": 0.0,  # keplint: guarded-by=_lock
+                             "lock_wait_s": 0.0, "merge_s": 0.0,
+                             "history_push_s": 0.0}
         # untrained fallbacks per zone count — never clobber trained params
         self._fallback_params: dict[int, object] = {}
         # -- window pipeline (fleet.window) --------------------------------
@@ -804,7 +828,7 @@ class Aggregator:
         self._fused_degraded = False  # keplint: guarded-by=_results_lock
         # per-un-flushed-window aggregation snapshots, oldest first,
         # parallel to the fused engine's pending ring: (stored_sorted,
-        # zone_names, now, t_win). Popped as the flush publishes; after
+        # zone_names, now, record). Popped as the flush publishes; after
         # a failure resets the engine these are ORPHANED and
         # _replay_fused_pending republishes them at the demoted tier —
         # the zero-gaps invariant. Aggregation-loop-only state.
@@ -1112,7 +1136,13 @@ class Aggregator:
 
     def run(self, ctx: CancelContext) -> None:
         while not ctx.cancelled():
-            if ctx.wait(self._interval):
+            # the wait is the first leg of the next window's record: its
+            # span is laid on the record's marks in aggregate_once
+            self._tick_began = _time.monotonic()
+            with TraceAnnotation("window.tick_wait",
+                                 window=self._window_seq):
+                cancelled = ctx.wait(self._interval)
+            if cancelled:
                 break
             try:
                 self.aggregate_once()
@@ -1377,6 +1407,9 @@ class Aggregator:
                 self._ingest_bytes[version] = \
                     self._ingest_bytes.get(version, 0) + len(body)
         content_changed = True
+        clock = (_time.monotonic if telemetry.recorder().enabled
+                 else _no_clock)
+        t_decode = clock()
         try:
             with telemetry.span("aggregator.decode"):
                 if (parsed is not None and parsed.version == 2
@@ -1411,6 +1444,7 @@ class Aggregator:
                 if node:
                     self._record_degraded_locked(node, "malformed", str(err))
             return 400, {"Content-Type": "text/plain"}, f"{err}\n".encode()
+        decode_s = clock() - t_decode
         received = self._clock()
         sent_at = header.get("sent_at")
         if (self._skew_tolerance > 0
@@ -1495,7 +1529,10 @@ class Aggregator:
         # scoreboard input, computed OFF the store lock: the node's
         # self-reported power this window (valid zone energy over dt)
         report_power_w = _report_power_w(report)
+        t_wait = clock()
         with telemetry.span("aggregator.merge"), self._lock:
+            t_held = clock()
+            push_s = 0.0
             prev = self._reports.get(report.node_name)
             # When BOTH sides carry a run nonce the cases are unambiguous:
             # different nonce = fresh agent process (restart), same nonce +
@@ -1590,6 +1627,8 @@ class Aggregator:
                     self._stats["reports_total"] += 1
                     self._scoreboard.observe_duplicate(report.node_name,
                                                        received)
+                    self._count_ingest_locked(clock, decode_s, t_wait,
+                                              t_held, push_s)
                     return 204, self._epoch_headers(), b""
                 if lost:
                     lost_windows = lost
@@ -1631,14 +1670,32 @@ class Aggregator:
                         and (report.mode == MODE_MODEL or self._dump_dir)
                         and (prev is None or restarted
                              or stored.seq != prev.seq)):
+                    t_push = clock()
                     self._push_history(report)
+                    push_s = clock() - t_push
             self._scoreboard.observe_report(report.node_name, received,
                                             report_power_w,
                                             lost=lost_windows)
             self._observe_delivery_locked(report.node_name, header,
                                           received)
             self._stats["reports_total"] += 1
+            self._count_ingest_locked(clock, decode_s, t_wait, t_held,
+                                      push_s)
         return 204, self._epoch_headers(), b""
+
+    def _count_ingest_locked(self, clock: Callable[[], float],
+                             decode_s: float, t_wait: float,
+                             t_held: float, push_s: float) -> None:
+        """One report's ingest legs into the sums ``/debug/window``
+        serves. Caller holds the store lock, at the end of the merge."""
+        if clock is _no_clock:
+            return
+        legs = self._ingest_legs
+        legs["reports"] += 1
+        legs["decode_s"] += decode_s
+        legs["lock_wait_s"] += t_held - t_wait
+        legs["history_push_s"] += push_s
+        legs["merge_s"] += clock() - t_held - push_s
 
     def _epoch_headers(self) -> dict[str, str]:
         """Accepts advertise the ring epoch so settled agents notice a
@@ -2774,74 +2831,99 @@ class Aggregator:
         An empty fleet drains the pipeline instead of dispatching, so
         results never rot in flight when reports stop.
         """
-        t_win = _time.perf_counter()
+        begin = _time.monotonic()
         now = self._clock()
-        with self._lock:
-            live = {name: s for name, s in self._reports.items()
-                    if now - s.received <= self._stale_after}
-            self._reports = dict(live)
-            for name in [n for n in self._history if n not in live]:
-                del self._history[name]
-            for name in [n for n in self._superseded_runs if n not in live]:
-                del self._superseded_runs[name]
-            # _seq_trackers are NOT pruned here: they must survive
-            # partitions longer than stale_after (see __init__ comment)
-            for name in [n for n, e in self._degraded.items()
-                         if now - e["last_at"] > self._degraded_ttl]:
-                del self._degraded[name]
-        # one autoscale observation per aggregation interval — BEFORE
-        # the empty-fleet early return, so an idle fleet still feeds
-        # the scale-down streak
-        self._autoscale_tick()
-        if not live:
-            return self._drain_pipeline()
-        # one telemetry cycle per non-empty fleet window, with the
-        # assembly/h2d/compile/wait legs as stages (the same legs the
-        # last_*_ms stats report — the histograms add distribution)
-        with telemetry.span("aggregator.window"):
-            stored_sorted = sorted(live.values(),
-                                   key=lambda s: s.report.node_name)
-            zone_names = sorted(
-                {z for s in stored_sorted for z in s.zone_names})
-            # degradation-ladder retry loop: a device-leg failure demotes
-            # one rung and RECOMPUTES this interval's window there, so a
-            # dead device costs latency, never a publish. Bounded: the
-            # rung strictly increases per retry and the bottom rung's
-            # failures re-raise (a NumPy bug is a bug, not degradation).
-            while True:
-                try:
-                    # republish windows a fused-tier failure orphaned
-                    # (no-op while the fused ring is intact or empty);
-                    # a failure HERE re-enters the same demote+retry
-                    # loop with the un-replayed snapshots preserved
-                    self._replay_fused_pending()
-                    return self._window_step(stored_sorted, zone_names,
-                                             now, t_win)
-                except Exception as err:
-                    if (not self._fallback_enabled
-                            or self._rung >= RUNG_NUMPY):
-                        raise
-                    self._handle_device_failure(err)
+        # the window's record: the last_*_ms gauges, the legs on
+        # /debug/window and the legs' spans are all differences of its
+        # marks. A tick that finds the fleet empty takes no sequence
+        # number and leaves no record.
+        rec = WindowRecord(self._window_seq, now, begin, self._tick_began)
+        self._tick_began = None
+        if rec.tick is not None:  # a cycle of its own, as the wait was
+            telemetry.mark_span("window.tick_wait", rec.tick, begin,
+                                window=rec.seq)
+        # one telemetry cycle per non-empty fleet window, opened on the
+        # record's first mark so that it covers the snapshot: a cycle is
+        # what last_attribution_ms counts, with the record's legs as
+        # stages (the histograms add distribution). An empty fleet's
+        # cycle is discarded.
+        cycle = telemetry.span("aggregator.window", window=rec.seq)
+        cycle.open_at(begin)
+        try:
+            with rec.leg("window.snapshot"), self._lock:
+                live = {name: s for name, s in self._reports.items()
+                        if now - s.received <= self._stale_after}
+                self._reports = dict(live)
+                for name in [n for n in self._history if n not in live]:
+                    del self._history[name]
+                for name in [n for n in self._superseded_runs
+                             if n not in live]:
+                    del self._superseded_runs[name]
+                # _seq_trackers are NOT pruned here: they must survive
+                # partitions longer than stale_after (see __init__
+                # comment)
+                for name in [n for n, e in self._degraded.items()
+                             if now - e["last_at"] > self._degraded_ttl]:
+                    del self._degraded[name]
+            # one autoscale observation per aggregation interval — BEFORE
+            # the empty-fleet early return, so an idle fleet still feeds
+            # the scale-down streak
+            self._autoscale_tick()
+            if live:
+                self._window_seq += 1
+                return self._attribute_window(live, now, rec)
+            cycle.discard()
+        finally:
+            cycle.close_at(_time.monotonic())
+        return self._drain_pipeline()
+
+    def _attribute_window(self, live: dict, now: float,
+                          rec: WindowRecord) -> "FleetResults | None":
+        stored_sorted = sorted(live.values(),
+                               key=lambda s: s.report.node_name)
+        zone_names = sorted(
+            {z for s in stored_sorted for z in s.zone_names})
+        # degradation-ladder retry loop: a device-leg failure demotes
+        # one rung and RECOMPUTES this interval's window there, so a
+        # dead device costs latency, never a publish. Bounded: the
+        # rung strictly increases per retry and the bottom rung's
+        # failures re-raise (a NumPy bug is a bug, not degradation).
+        while True:
+            try:
+                # republish windows a fused-tier failure orphaned
+                # (no-op while the fused ring is intact or empty);
+                # a failure HERE re-enters the same demote+retry
+                # loop with the un-replayed snapshots preserved
+                self._replay_fused_pending()
+                return self._window_step(stored_sorted, zone_names,
+                                         now, rec)
+            except Exception as err:
+                if (not self._fallback_enabled
+                        or self._rung >= RUNG_NUMPY):
+                    raise
+                self._handle_device_failure(err)
 
     def _window_step(self, stored_sorted: list, zone_names: list[str],
-                     now: float, t_win: float) -> "FleetResults | None":
+                     now: float,
+                     rec: WindowRecord) -> "FleetResults | None":
         """One dispatch+publish pass at the CURRENT ladder rung."""
         rung = self._rung
+        rec.restart()  # a retry keeps none of the failed rung's marks
         if rung >= RUNG_NUMPY:
             pending = self._dispatch_numpy(stored_sorted, zone_names,
-                                           now, t_win)
+                                           now, rec)
         elif rung >= RUNG_EINSUM or not self._use_packed():
             pending = self._dispatch_legacy(stored_sorted, zone_names,
-                                            now, t_win)
+                                            now, rec)
         elif rung == RUNG_PIPELINED and self._fused_tier_active():
             # the fused tier publishes on its own cadence (K windows
             # per flush, all inside the flush call) — it never enters
             # the per-window pipeline deque below
             return self._window_step_fused(stored_sorted, zone_names,
-                                           now, t_win)
+                                           now, rec)
         else:
             pending = self._dispatch_packed(stored_sorted, zone_names,
-                                            now, t_win, rung)
+                                            now, rec, rung)
         # every demoted rung drains each window (no in-flight handle
         # outlives its own interval); only the healthy rung pipelines —
         # the legacy path included (temporal/accuracy modes pipeline at
@@ -2886,8 +2968,8 @@ class Aggregator:
                     params = np.zeros((), np.float32)
                 flush = eng.flush(params)
                 if flush is not None:
-                    published = self._dispatch_fused_flush(eng, flush,
-                                                           0.0)
+                    published = self._dispatch_fused_flush(
+                        eng, flush, staged=False)
             except Exception as err:
                 failure = err
         with self._pipeline_lock:
@@ -2939,7 +3021,7 @@ class Aggregator:
 
     def _window_step_fused(self, stored_sorted: list,
                            zone_names: list[str], now: float,
-                           t_win: float) -> "FleetResults | None":
+                           rec: WindowRecord) -> "FleetResults | None":
         """One interval at the fused tier: HOST-ONLY staging, and — on
         every K-th interval (or a forced shape-change flush) — one
         device dispatch + one batched fetch publishing all pending
@@ -2962,12 +3044,11 @@ class Aggregator:
         # retry recomputes THIS interval itself, so only the snapshot is
         # popped back off; EARLIER snapshots stay until their windows
         # actually publish (the zero-gaps invariant)
-        self._fused_pending.append((stored_sorted, zone_names, now,
-                                    t_win))
+        self._fused_pending.append((stored_sorted, zone_names, now, rec))
         try:
-            with telemetry.span("window.h2d_delta"):
+            with telemetry.span("window.h2d_delta", window=rec.seq):
                 _meta, flush = engine.stage(rows, zone_names, params)
-            t_staged = _time.perf_counter()
+            rec.assembled = _time.monotonic()
             # consulted AFTER the host staging, covering both flush and
             # accumulate intervals — a mid-scan fault abandons the ring
             # and the pending windows republish at the demoted tier
@@ -2978,70 +3059,76 @@ class Aggregator:
         except BaseException:
             self._fused_pending.pop()
             raise
-        stage_ms = (t_staged - t_win) * 1e3
         if flush is None:
             # ring filling: no device leg this interval. The per-call
             # leg stats say so honestly (the previous flush's batch
             # cost must not read as THIS interval's device time).
             with self._results_lock:
-                self._stats["last_assembly_ms"] = stage_ms
+                self._stats["last_assembly_ms"] = rec.ms("begin",
+                                                         "assembled")
                 self._stats["last_dispatch_ms"] = 0.0
                 self._stats["last_wait_ms"] = 0.0
                 self._stats["last_fetch_ms"] = 0.0
                 self._stats["last_device_ms"] = 0.0
                 self._stats["last_h2d_rows"] = 0
             return None
-        published = self._dispatch_fused_flush(engine, flush, stage_ms)
+        published = self._dispatch_fused_flush(engine, flush, staged=True)
         if published is not None:
             self._ladder_window_ok()
         return published
 
     def _dispatch_fused_flush(self, engine: FusedWindowEngine,
                               flush: FusedFlush,
-                              stage_ms: float) -> "FleetResults | None":
+                              staged: bool) -> "FleetResults | None":
         """Dispatch one fused batch, fetch ALL its outputs in one
         transfer, publish every live window oldest-first. The batch's
         whole device cost lands on its LAST window's stats sample
         (earlier windows ride free — that is the measured amortization);
-        ``sync_per_window_ms`` carries the averaged per-window figure."""
-        t0 = _time.perf_counter()
-        with telemetry.span("window.fused_scan"):
+        ``sync_per_window_ms`` carries the averaged per-window figure.
+        ``staged``: the last window is this interval's, staged just now
+        (a drain's is an earlier interval's, and has no assembly leg)."""
+        seq = self._fused_pending[-1][3].seq
+        t0 = _time.monotonic()
+        with telemetry.span("window.fused_scan", window=seq):
             if flush.cold:
                 # first dispatch of this (buckets, zones, mode, K, DB)
                 # key blocks on trace + XLA compile
-                with telemetry.span("window.compile"):
+                with telemetry.span("window.compile", window=seq):
                     outs = engine.dispatch(flush)
             else:
                 outs = engine.dispatch(flush)
-        t_disp = _time.perf_counter()
         fetch_box = [0.0]
 
         def _materialize() -> np.ndarray:
-            with telemetry.span("window.publish_fetch"):
-                t_f = _time.perf_counter()
+            with telemetry.span("window.publish_fetch", window=seq):
+                t_f = _time.monotonic()
                 plane = np.asarray(outs)
-                fetch_box[0] = (_time.perf_counter() - t_f) * 1e3
+                fetch_box[0] = (_time.monotonic() - t_f) * 1e3
             return plane
 
-        with telemetry.span("window.pipeline_wait"):
+        with telemetry.span("window.pipeline_wait", window=seq):
             plane = self._fetch_device(_materialize)
-        t_done = _time.perf_counter()
-        batch_ms = (t_done - t0) * 1e3
-        spw = batch_ms / max(1, flush.k_live)
+        t_done = _time.monotonic()
+        spw = (t_done - t0) * 1e3 / max(1, flush.k_live)
         published = None
         with self._pipeline_lock:
             for j, meta in enumerate(flush.metas):
                 # each published window keeps ITS OWN interval's clock
                 # (snapshotted at stage time) — staleness is visible in
                 # the timestamps, exactly like pipeline-depth staleness
-                _, _, w_now, _ = self._fused_pending[0]
+                _, _, w_now, rec = self._fused_pending[0]
                 last = j == len(flush.metas) - 1
+                if not last:
+                    rec.assembled = rec.dispatched = rec.begin
+                else:
+                    if not staged:
+                        rec.begin = rec.assembled = t0
+                    rec.dispatched = t_done
+                    rec.compiled = flush.cold
                 published = self._publish(_Pending(
                     kind="fused", out=plane[j], meta=meta, now=w_now,
-                    assembly_ms=stage_ms if last else 0.0,
-                    dispatch_ms=batch_ms if last else 0.0,
+                    rec=rec,
                     h2d_rows=flush.h2d_rows if last else 0,
-                    compiled=flush.cold and last,
                     sync_per_window_ms=spw,
                     fused_fetch_ms=fetch_box[0] if last else 0.0))
                 self._fused_pending.pop(0)
@@ -3121,7 +3208,7 @@ class Aggregator:
         return self._engine_serial
 
     def _dispatch_packed(self, stored_sorted: list, zone_names: list[str],
-                         now: float, t_win: float,
+                         now: float, rec: WindowRecord,
                          rung: int = RUNG_PIPELINED) -> _Pending:
         """Sync the device-resident packed batch (delta H2D) and dispatch
         the packed-f16 program asynchronously."""
@@ -3138,9 +3225,9 @@ class Aggregator:
         params = self._params_for_zones(len(zone_names))
         if params is None:
             params = np.zeros((), np.float32)  # ratio-only: unused leaf
-        with telemetry.span("window.h2d_delta"):
+        with telemetry.span("window.h2d_delta", window=rec.seq):
             plan = engine.plan_window(rows, zone_names, params)
-        t_planned = _time.perf_counter()
+        rec.assembled = _time.monotonic()
         # consulted AFTER the donated ring update ran: a dispatch that
         # dies here leaves a consumed donated buffer behind — exactly the
         # poisoned-ring state the ladder's reset() re-seed exists for
@@ -3151,59 +3238,72 @@ class Aggregator:
         if plan.cold:
             # first dispatch of this (buckets, zones, mode) key: the call
             # blocks on trace+XLA-compile; execution itself stays async
-            with telemetry.span("window.compile"):
+            with telemetry.span("window.compile", window=rec.seq):
                 out = plan.program(*plan.args)
         else:
             out = plan.program(*plan.args)
         copy_async = getattr(out, "copy_to_host_async", None)
         if copy_async is not None:
             copy_async()  # D2H queues behind the compute, off the host
-        t_dispatched = _time.perf_counter()
+        rec.dispatched = _time.monotonic()
+        rec.compiled = plan.cold
         return _Pending(
-            kind="packed", out=out, meta=plan.meta, now=now,
-            assembly_ms=(t_planned - t_win) * 1e3,
-            dispatch_ms=(t_dispatched - t_planned) * 1e3,
-            h2d_rows=plan.h2d_rows, compiled=plan.cold,
+            kind="packed", out=out, meta=plan.meta, now=now, rec=rec,
+            h2d_rows=plan.h2d_rows,
             h2d_shards=plan.h2d_shards, shards=plan.n_shards,
             fetch=plan.fetch)
 
     def _dispatch_legacy(self, stored_sorted: list, zone_names: list[str],
-                         now: float, t_win: float) -> _Pending:
+                         now: float, rec: WindowRecord) -> _Pending:
         """Serial-path dispatch: full assemble, one big H2D, the sharded
-        einsum/temporal program, async output copies."""
-        aligned = [s.report for s in stored_sorted]
-        n_zones = len(zone_names)
-        zd_mat, zv_mat = align_zone_matrices(
-            aligned, [s.zone_names for s in stored_sorted], zone_names)
-        batch = assemble_fleet_batch(
-            aligned, n_zones=n_zones, node_bucket=self._node_bucket,
-            workload_bucket=self._workload_bucket,
-            zone_deltas_mat=zd_mat, zone_valid_mat=zv_mat)
-        cold = self._program is None
-        if cold:
-            if fault.fire("device.compile_error") is not None:
-                raise DeviceWindowError(
-                    "compile_error",
-                    "injected compile failure (serial fleet program)")
-            if self._model_mode == "temporal":
-                self._program = make_temporal_fleet_program(
-                    self._mesh, backend=self._backend,
-                    accuracy_mode=self._accuracy_mode)
-            else:
-                self._program = make_fleet_program(
-                    self._mesh, model_mode=self._model_mode,
-                    backend=self._backend,
-                    accuracy_mode=self._accuracy_mode)
-        program = self._program
-        params = self._params_for_zones(n_zones)
+        einsum/temporal program, async output copies. Every leg is a
+        span with the window's id that lies on two marks of its record
+        (the batch leg starts at the snapshot's end, so it also holds the
+        autoscale observation and the sort of the reports)."""
+        temporal = self._model_mode == "temporal"
+        with rec.leg("window.batch"):
+            aligned = [s.report for s in stored_sorted]
+            n_zones = len(zone_names)
+            zd_mat, zv_mat = align_zone_matrices(
+                aligned, [s.zone_names for s in stored_sorted], zone_names)
+            batch = assemble_fleet_batch(
+                aligned, n_zones=n_zones, node_bucket=self._node_bucket,
+                workload_bucket=self._workload_bucket,
+                zone_deltas_mat=zd_mat, zone_valid_mat=zv_mat)
+            cold = self._program is None
+            if cold:
+                if fault.fire("device.compile_error") is not None:
+                    raise DeviceWindowError(
+                        "compile_error",
+                        "injected compile failure (serial fleet program)")
+                if temporal:
+                    self._program = make_temporal_fleet_program(
+                        self._mesh, backend=self._backend,
+                        accuracy_mode=self._accuracy_mode)
+                else:
+                    self._program = make_fleet_program(
+                        self._mesh, model_mode=self._model_mode,
+                        backend=self._backend,
+                        accuracy_mode=self._accuracy_mode)
+            program = self._program
+            params = self._params_for_zones(n_zones)
         feat_hist = t_valid = None
-        if self._model_mode == "temporal":
-            feat_hist, t_valid = self._history_windows(batch)
-        t_assembled = _time.perf_counter()
+        # the loop thread's CPU time is read inside the wall-clock leg, so
+        # that wall − CPU (time off the processor) cannot come out negative
+        if temporal:
+            with rec.leg("window.history"):
+                feat_hist, t_valid = self._history_windows(batch)
+                cpu_end_ns = _time.thread_time_ns()
+        else:
+            cpu_end_ns = _time.thread_time_ns()
+            rec.assembled = rec.batch
+        rec.assembly_cpu_s = (cpu_end_ns - rec.cpu_begin_ns) / 1e9
         if fault.fire("device.dispatch_error") is not None:
             raise DeviceWindowError(
                 "dispatch_error",
                 "injected dispatch failure (serial fleet program)")
+        with rec.leg("window.h2d"):
+            args = put_fleet_batch(batch, params, feat_hist, t_valid)
         # ASYNC dispatch: jax returns device futures immediately; the D2H
         # copies start NOW (they queue behind the compute on the device
         # stream) instead of at the np.asarray fetch in _publish. The
@@ -3211,29 +3311,35 @@ class Aggregator:
         # window.compile stage (later per-shape recompiles hide inside
         # jax's own cache and are not individually attributable here;
         # the packed path's keyed program cache counts those exactly)
-        if cold:
-            with telemetry.span("window.compile"):
-                result = run_fleet_attribution(program, batch, params,
-                                               feat_hist, t_valid)
-        else:
-            result = run_fleet_attribution(program, batch, params,
-                                           feat_hist, t_valid)
-        for arr in (result.node_power_uw, result.node_energy_uj,
-                    result.workload_power_uw, result.workload_energy_uj):
-            copy_async = getattr(arr, "copy_to_host_async", None)
-            if copy_async is not None:
-                copy_async()
-        t_dispatched = _time.perf_counter()
+        with rec.leg("window.dispatch"):
+            if cold:
+                with telemetry.span("window.compile", window=rec.seq):
+                    result = program(*args)
+                self._legacy_compiles += 1
+            else:
+                result = program(*args)
+            for arr in (result.node_power_uw, result.node_energy_uj,
+                        result.workload_power_uw,
+                        result.workload_energy_uj):
+                copy_async = getattr(arr, "copy_to_host_async", None)
+                if copy_async is not None:
+                    copy_async()
+        # the counts, after the last mark: they are on no gauge's clock
+        rec.compiled = cold
+        rec.rows_program = batch.cpu_deltas.size
+        if self._model_mode:
+            counts = np.asarray(batch.workload_counts)
+            rec.rows_work = int(counts[
+                batch.mode[:len(counts)] == MODE_MODEL].sum())
+        rec.h2d_bytes = sum(int(a.nbytes) for a in args[1:])
         return _Pending(
-            kind="legacy", out=result, meta=None, now=now,
-            assembly_ms=(t_assembled - t_win) * 1e3,
-            dispatch_ms=(t_dispatched - t_assembled) * 1e3,
-            h2d_rows=batch.n_nodes, compiled=cold,
+            kind="legacy", out=result, meta=None, now=now, rec=rec,
+            h2d_rows=batch.n_nodes,
             batch=batch, aligned=aligned, zone_names=zone_names,
             feat_hist=feat_hist, t_valid=t_valid)
 
     def _dispatch_numpy(self, stored_sorted: list, zone_names: list[str],
-                        now: float, t_win: float) -> _Pending:
+                        now: float, rec: WindowRecord) -> _Pending:
         """Bottom ladder rung: the whole window in host NumPy — no jax,
         no device, no compile. Ratio attribution is exact; model rows are
         served for the NumPy-mirrored estimators (linear, mlp) when the
@@ -3253,7 +3359,7 @@ class Aggregator:
             workload_bucket=self._workload_bucket,
             zone_deltas_mat=zd_mat, zone_valid_mat=zv_mat)
         packed = pack_fleet_inputs(batch)
-        t_assembled = _time.perf_counter()
+        rec.assembled = _time.monotonic()
         params = None
         if (self._model_mode in ("linear", "mlp")
                 and self._params is not None
@@ -3261,7 +3367,7 @@ class Aggregator:
             params = self._params
         watts = numpy_fleet_window(packed, batch.cpu_deltas.shape[1],
                                    n_zones, params, self._model_mode)
-        t_done = _time.perf_counter()
+        rec.dispatched = _time.monotonic()
         n_real = batch.n_nodes
         names = list(batch.node_names[:n_real])
         meta = WindowMeta(
@@ -3278,10 +3384,8 @@ class Aggregator:
             n_rows=watts.shape[0],
         )
         return _Pending(
-            kind="numpy", out=watts, meta=meta, now=now,
-            assembly_ms=(t_assembled - t_win) * 1e3,
-            dispatch_ms=(t_done - t_assembled) * 1e3,
-            h2d_rows=0, compiled=False)
+            kind="numpy", out=watts, meta=meta, now=now, rec=rec,
+            h2d_rows=0)
 
     # -- publish half -------------------------------------------------------
 
@@ -3292,7 +3396,10 @@ class Aggregator:
         Holding the pipeline lock keeps a lifecycle-thread drain from
         interleaving publishes (out-of-order ``_results``) with the
         aggregation loop's own."""
-        t0 = _time.perf_counter()
+        rec = p.rec
+        seq = rec.seq
+        rec.kind = p.kind
+        rec.publish_begin = _time.monotonic()
         fetch_ms = 0.0
         if p.kind == "packed":
             # the engine's plan may override the fetch (per-shard
@@ -3301,17 +3408,16 @@ class Aggregator:
             fetch_fn = p.fetch or np.asarray
 
             def _materialize() -> np.ndarray:
-                with telemetry.span("window.publish_fetch"):
-                    t_f = _time.perf_counter()
+                with telemetry.span("window.publish_fetch", window=seq):
+                    t_f = _time.monotonic()
                     plane = fetch_fn(p.out)
-                    nonlocal_box[0] = (_time.perf_counter() - t_f) * 1e3
+                    nonlocal_box[0] = (_time.monotonic() - t_f) * 1e3
                 return plane
 
             nonlocal_box = [0.0]
-            with telemetry.span("window.pipeline_wait"):
+            with rec.leg("window.pipeline_wait"):
                 packed = self._fetch_device(_materialize)
             fetch_ms = nonlocal_box[0]
-            t_fetched = _time.perf_counter()
             results = self._scatter_packed(p, packed)
         elif p.kind in ("numpy", "fused"):
             # host rung: the "fetch" is a no-op — p.out is already a host
@@ -3320,51 +3426,56 @@ class Aggregator:
             # the time they publish: the flush materialized the whole
             # K-batch in one transfer and sliced this window's plane out
             # host-side (the batched fetch cost rides in fused_fetch_ms).
-            t_fetched = _time.perf_counter()
+            rec.fetched = _time.monotonic()
             fetch_ms = p.fused_fetch_ms
             results = self._scatter_packed(p, p.out)
         else:
             result = p.out
-            with telemetry.span("window.pipeline_wait"):
+            with rec.leg("window.pipeline_wait"):
                 fetched = self._fetch_device(lambda: (
                     np.asarray(result.node_power_uw),
                     np.asarray(result.node_energy_uj),
                     np.asarray(result.workload_power_uw),
                     np.asarray(result.workload_energy_uj)))
             node_power, node_energy, wl_power, wl_energy = fetched
-            t_fetched = _time.perf_counter()
-            results = self._scatter_legacy(p, node_power, node_energy,
-                                           wl_power, wl_energy)
-        t_done = _time.perf_counter()
-        wait_ms = (t_fetched - t0) * 1e3
-        scatter_ms = (t_done - t_fetched) * 1e3
+            with rec.leg("window.scatter"):
+                results = self._scatter_legacy(p, node_power, node_energy,
+                                               wl_power, wl_energy)
+        if rec.scattered is None:  # the packed scatter is no leg
+            rec.scattered = _time.monotonic()
+        assembly_ms = rec.ms("begin", "assembled")
+        dispatch_ms = rec.ms("assembled", "dispatched")
+        wait_ms = rec.ms("publish_begin", "fetched")
+        scatter_ms = rec.ms("fetched", "scattered")
         n_workloads = sum(results.counts)
-        with self._results_lock:
+        with TraceAnnotation("window.publish",
+                             window=seq), self._results_lock:
             self._results = results
             self._last_window_at = p.now
             self._stats["attributions_total"] += 1
             self._stats["last_batch_nodes"] = len(results.names)
             self._stats["last_batch_workloads"] = int(n_workloads)
-            self._stats["last_assembly_ms"] = p.assembly_ms
-            self._stats["last_dispatch_ms"] = p.dispatch_ms
+            self._stats["last_assembly_ms"] = assembly_ms
+            self._stats["last_dispatch_ms"] = dispatch_ms
             self._stats["last_wait_ms"] = wait_ms
             self._stats["last_fetch_ms"] = fetch_ms
-            self._stats["last_device_ms"] = p.dispatch_ms + wait_ms
+            self._stats["last_device_ms"] = dispatch_ms + wait_ms
             self._stats["last_scatter_ms"] = scatter_ms
             self._stats["last_attribution_ms"] = (
-                p.assembly_ms + p.dispatch_ms + wait_ms + scatter_ms)
+                assembly_ms + dispatch_ms + wait_ms + scatter_ms)
             self._stats["last_h2d_rows"] = p.h2d_rows
             self._stats["window_shards"] = p.shards
             self._stats["last_h2d_shards"] = list(p.h2d_shards)
             if p.sync_per_window_ms >= 0.0:
                 self._stats["last_sync_per_window_ms"] = (
                     p.sync_per_window_ms)
-            engines_all = (self._engine, self._engine_serial,
-                           self._engine_fused)
-            if any(e is not None for e in engines_all):
-                self._stats["window_compiles_total"] = sum(
-                    e.compile_count for e in engines_all
-                    if e is not None)
+            # the engines' program caches count their own compiles; the
+            # serial path's one program is counted at its cold dispatch
+            self._stats["window_compiles_total"] = (
+                self._legacy_compiles + sum(
+                    e.compile_count for e in (
+                        self._engine, self._engine_serial,
+                        self._engine_fused) if e is not None))
             # per-window engine introspection snapshot: computed HERE
             # (the only thread that owns engine state) so /debug/window
             # and collect() read a coherent copy off-thread without
@@ -3383,6 +3494,18 @@ class Aggregator:
                     skew = max(occupied) / (sum(occupied) / len(occupied))
             self._stats["shard_skew"] = round(skew, 4)
             self._introspect_cache = engines
+            # the record is complete once the results are stored (they
+            # are visible when this lock is released, a moment later)
+            rec.published = _time.monotonic()
+            self._window_ledger.add(rec)
+        # the two legs no with-block covers: how long the dispatched
+        # window waited for its publication (at pipelineDepth 2 the
+        # interval and the next window's assembly), and the lock section
+        # that made the results visible
+        telemetry.mark_span("window.queued", rec.dispatched,
+                            rec.publish_begin, window=seq)
+        telemetry.mark_span("window.publish", rec.scattered, rec.published,
+                            window=seq)
         log.debug("fleet attribution: %d nodes, %d workloads, %.2f ms "
                   "(h2d rows %d)", len(results.names), n_workloads,
                   self._stats["last_attribution_ms"], p.h2d_rows)
@@ -3677,7 +3800,10 @@ class Aggregator:
         dump — rung + transition timeline, shard layout, bucket
         ladders, compile-cache keys with their cost stats, last H2D per
         shard, sticky-map skew. Engine state comes from the per-window
-        introspection snapshot (coherent, no live engine access)."""
+        introspection snapshot (coherent, no live engine access).
+        ``records`` are the last complete window records, ``counts`` and
+        ``ingest`` the windows' counts and the ingest seconds summed
+        since start (``fleet/window_record.py``)."""
         with self._results_lock:
             payload: dict = {
                 **self._device_fields(),
@@ -3715,8 +3841,17 @@ class Aggregator:
                 }
             if self._last_window_failure:
                 payload["last_failure"] = self._last_window_failure
-        return (200, {"Content-Type": "application/json"},
-                json.dumps(payload).encode())
+            records, counts = self._window_ledger.snapshot()
+        with self._lock:
+            ingest = dict(self._ingest_legs)
+        # copied under the locks, rendered outside them. The records are
+        # JSON text (a row is rendered once): they are spliced in as the
+        # body's last key, not parsed and dumped again
+        payload["counts"] = counts
+        payload["ingest"] = ingest
+        body = (f'{json.dumps(payload)[:-1]}, '
+                f'"records": {records_json(records)}}}')
+        return 200, {"Content-Type": "application/json"}, body.encode()
 
     def _handle_ring_debug(
             self, request: Any) -> tuple[int, dict[str, str], bytes]:

@@ -160,41 +160,50 @@ def _last_query_trunk(
     dh = d // h
     cd = compute_dtype
 
-    x = acc_matmul(feat_hist, params["in_proj"], cd)
-    x = x + params["pos_emb"][:t]
-    x = jnp.where(t_valid[..., None], x, 0.0)
-    last = jnp.maximum(jnp.sum(t_valid, axis=-1) - 1, 0).astype(jnp.int32)
+    # the scopes reach the HLO's op metadata: a profile of the served
+    # program reads by stage
+    with jax.named_scope("history_embed"):
+        x = acc_matmul(feat_hist, params["in_proj"], cd)
+        x = x + params["pos_emb"][:t]
+        x = jnp.where(t_valid[..., None], x, 0.0)
+        last = jnp.maximum(jnp.sum(t_valid, axis=-1) - 1,
+                           0).astype(jnp.int32)
+        y = layer_norm(x, params["ln1_scale"], params["ln1_bias"])
+    with jax.named_scope("last_query_attention"):
+        y_last = jnp.take_along_axis(y, last[:, None, None], axis=1)[:, 0]
+        q = acc_matmul(y_last, params["wq"], cd).reshape(b, h, dh)
+    with jax.named_scope("kv_proj"):
+        k = acc_matmul(y, params["wk"], cd).reshape(b, t, h, dh)
+        v = acc_matmul(y, params["wv"], cd).reshape(b, t, h, dh)
+    with jax.named_scope("last_query_attention"):
+        scores = jnp.einsum("bhd,bthd->bht", q.astype(cd), k.astype(cd),
+                            preferred_element_type=jnp.float32)
+        scores = scores / jnp.sqrt(jnp.asarray(dh, jnp.float32))
+        # finite mask value (not -inf): an all-invalid window must yield
+        # 0 attention, not softmax(-inf…)=NaN — parity with
+        # full_attention's l_safe clamping for fully-masked rows. The
+        # causal constraint (position ≤ last) keeps this path exact on
+        # gapped t_valid masks, not just the contiguous right-padded
+        # prefixes history windows produce — full parity with the
+        # all-positions trunk.
+        causal = jnp.arange(t, dtype=jnp.int32)[None, :] <= last[:, None]
+        scores = jnp.where((t_valid & causal)[:, None, :], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1)
+        any_valid = t_valid.any(axis=-1)
+        probs = jnp.where(any_valid[:, None, None], probs, 0.0)
+        attn = jnp.einsum("bht,bthd->bhd", probs.astype(cd), v.astype(cd),
+                          preferred_element_type=jnp.float32).reshape(b, d)
 
-    y = layer_norm(x, params["ln1_scale"], params["ln1_bias"])
-    y_last = jnp.take_along_axis(y, last[:, None, None], axis=1)[:, 0]
-    q = acc_matmul(y_last, params["wq"], cd).reshape(b, h, dh)
-    k = acc_matmul(y, params["wk"], cd).reshape(b, t, h, dh)
-    v = acc_matmul(y, params["wv"], cd).reshape(b, t, h, dh)
-    scores = jnp.einsum("bhd,bthd->bht", q.astype(cd), k.astype(cd),
-                        preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(jnp.asarray(dh, jnp.float32))
-    # finite mask value (not -inf): an all-invalid window must yield 0
-    # attention, not softmax(-inf…)=NaN — parity with full_attention's
-    # l_safe clamping for fully-masked rows. The causal constraint
-    # (position ≤ last) keeps this path exact on gapped t_valid masks,
-    # not just the contiguous right-padded prefixes history windows
-    # produce — full parity with the all-positions trunk.
-    causal = jnp.arange(t, dtype=jnp.int32)[None, :] <= last[:, None]
-    scores = jnp.where((t_valid & causal)[:, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    any_valid = t_valid.any(axis=-1)
-    probs = jnp.where(any_valid[:, None, None], probs, 0.0)
-    attn = jnp.einsum("bht,bthd->bhd", probs.astype(cd), v.astype(cd),
-                      preferred_element_type=jnp.float32).reshape(b, d)
+        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+        x_last = x_last + acc_matmul(attn, params["wo"], cd)
 
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    x_last = x_last + acc_matmul(attn, params["wo"], cd)
-
-    y = layer_norm(x_last, params["ln2_scale"], params["ln2_bias"])
-    y = jax.nn.gelu(acc_matmul(y, params["w_mlp0"], cd)
-                    + params["b_mlp0"])
-    x_last = x_last + acc_matmul(y, params["w_mlp1"], cd) + params["b_mlp1"]
-    return layer_norm(x_last, params["ln_f_scale"], params["ln_f_bias"])
+    with jax.named_scope("mlp"):
+        y = layer_norm(x_last, params["ln2_scale"], params["ln2_bias"])
+        y = jax.nn.gelu(acc_matmul(y, params["w_mlp0"], cd)
+                        + params["b_mlp0"])
+        x_last = (x_last + acc_matmul(y, params["w_mlp1"], cd)
+                  + params["b_mlp1"])
+        return layer_norm(x_last, params["ln_f_scale"], params["ln_f_bias"])
 
 
 def predict_temporal(
@@ -231,11 +240,13 @@ def predict_temporal(
     # wide-and-deep: the current (= last valid) tick's raw features carry
     # the first-order linear power signal in f32; the attention trunk adds
     # the history-conditioned correction (see predict_mlp's w_skip note)
-    feat_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    watts = (pooled @ params["w_head"]
-             + feat_last.astype(jnp.float32) @ params["w_skip"]
-             + params["b_head"])
-    watts = watts.reshape(*lead, -1)
-    if clamp:
-        watts = jnp.maximum(watts, 0.0)
-    return jnp.where(workload_valid[..., None], watts, 0.0)
+    with jax.named_scope("head"):
+        feat_last = jnp.take_along_axis(
+            x, last[:, None, None], axis=1)[:, 0]
+        watts = (pooled @ params["w_head"]
+                 + feat_last.astype(jnp.float32) @ params["w_skip"]
+                 + params["b_head"])
+        watts = watts.reshape(*lead, -1)
+        if clamp:
+            watts = jnp.maximum(watts, 0.0)
+        return jnp.where(workload_valid[..., None], watts, 0.0)

@@ -5,6 +5,7 @@ from kepler_tpu.parallel.aggregator_core import (
     fleet_attribution_program,
     make_fleet_program,
     make_temporal_fleet_program,
+    put_fleet_batch,
     run_fleet_attribution,
     temporal_fleet_program,
 )
@@ -88,6 +89,7 @@ __all__ = [
     "MultihostInit",
     "multihost_status",
     "mlp_param_shardings",
+    "put_fleet_batch",
     "run_fleet_attribution",
     "shard_train_state",
 ]

@@ -18,6 +18,7 @@ from kepler_tpu.telemetry.spans import (
     install_from_config,
     installed,
     make_traces_handler,
+    mark_span,
     recent_traces,
     recorder,
     span,
@@ -38,6 +39,7 @@ __all__ = [
     "install_from_config",
     "installed",
     "make_traces_handler",
+    "mark_span",
     "recent_traces",
     "recorder",
     "span",

@@ -127,6 +127,11 @@ class SpanEvent:
     # per-instance span names (window.h2d_delta.s<k>) from minting one
     # kepler_self_stage_duration_seconds series per shard/index.
     stage: str | None = None
+    # the fleet window this span worked on (its record's ``seq``): the
+    # spans of one window share it across cycles — at pipelineDepth 2 a
+    # window is dispatched in one aggregator.window cycle and published
+    # in the next
+    window: int | None = None
 
 
 @dataclass(frozen=True)
@@ -152,7 +157,8 @@ class CycleTrace:
                 {"name": e.name, "depth": e.depth,
                  "rel_start_s": e.rel_start_s,
                  "duration_s": e.duration_s,
-                 **({"stage": e.stage} if e.stage is not None else {})}
+                 **({"stage": e.stage} if e.stage is not None else {}),
+                 **({"window": e.window} if e.window is not None else {})}
                 for e in self.events
             ],
         }
@@ -182,40 +188,67 @@ class _Span:
     not supported — ``span()`` returns a fresh handle per with-block."""
 
     __slots__ = ("_rec", "_st", "_name", "_budget", "_t0", "_depth",
-                 "_stage")
+                 "_stage", "_window", "_discarded")
 
     def __init__(self, rec: "SpanRecorder", st: _ThreadState, name: str,
                  budget_s: float | None,
-                 stage: str | None = None) -> None:
+                 stage: str | None = None,
+                 window: int | None = None) -> None:
         self._rec = rec
         self._st = st
         self._name = name
         self._budget = budget_s
         self._stage = stage
+        self._window = window
+        self._discarded = False
 
     def __enter__(self) -> "_Span":
+        self._open(self._rec._monotonic())
+        return self
+
+    def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> None:
+        self.close_at(self._rec._monotonic())
+
+    def discard(self) -> None:
+        """Drop this span when it closes. On a cycle's outermost span the
+        whole cycle goes unrecorded (an aggregation tick that found an
+        empty fleet is no window)."""
+        self._discarded = True
+
+    def open_at(self, t0: float) -> None:
+        """Open at a monotonic reading the caller already took (with
+        :meth:`close_at`, in the place of a with-block): the span and the
+        caller's own figures are then one clock. A cycle opened so is
+        anchored where it began."""
+        self._open(t0)
+        if self._depth == 0:
+            self._st.wall_anchor -= max(0.0, self._rec._monotonic() - t0)
+
+    def _open(self, t0: float) -> None:
         st = self._st
         if not st.stack:
             st.events = []
             st.wall_anchor = self._rec._clock()
-            st.mono_anchor = self._rec._monotonic()
+            st.mono_anchor = t0
         self._depth = len(st.stack)
-        self._t0 = self._rec._monotonic()
-        st.stack.append((self._name, self._t0, self._budget))
-        return self
+        self._t0 = t0
+        st.stack.append((self._name, t0, self._budget))
 
-    def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> None:
-        t1 = self._rec._monotonic()
+    def close_at(self, t1: float) -> None:
         st = self._st
         if st.stack:
             st.stack.pop()
-        st.events.append(SpanEvent(
-            name=self._name, depth=self._depth,
-            rel_start_s=self._t0 - st.mono_anchor,
-            duration_s=max(0.0, t1 - self._t0),
-            stage=self._stage))
+        if not self._discarded:
+            st.events.append(SpanEvent(
+                name=self._name, depth=self._depth,
+                rel_start_s=self._t0 - st.mono_anchor,
+                duration_s=max(0.0, t1 - self._t0),
+                stage=self._stage, window=self._window))
         if not st.stack:
-            self._rec._complete_cycle(st, self._budget)
+            if self._discarded:
+                st.events = []
+            else:
+                self._rec._complete_cycle(st, self._budget)
 
 
 class _NoopSpan:
@@ -229,9 +262,17 @@ class _NoopSpan:
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> None:
         return None
 
+    def discard(self) -> None:
+        return None
+
+    def open_at(self, t0: float) -> None:
+        return None
+
+    def close_at(self, t1: float) -> None:
+        return None
+
 
 _NOOP = _NoopSpan()
-
 
 class SpanRecorder:
     """Span sink: stage histograms, overrun counters, trace ring.
@@ -279,15 +320,28 @@ class SpanRecorder:
     # -- span API ------------------------------------------------------------
 
     def span(self, name: str, budget_s: float | None = None,
-             stage: str | None = None):
+             stage: str | None = None, window: int | None = None):
         """Context manager timing one stage. ``budget_s`` is meaningful
         on the OUTERMOST span of a cycle: exceeding it counts one
         ``kepler_self_cycle_overrun_total{cycle=name}``. ``stage``
         overrides the histogram key (``""`` = trace-only) — see
-        :class:`SpanEvent`."""
+        :class:`SpanEvent`. ``window`` is the fleet window's id."""
         if not self._enabled:
             return _NOOP
-        return _Span(self, self._state(), name, budget_s, stage)
+        return _Span(self, self._state(), name, budget_s, stage, window)
+
+    def mark_span(self, name: str, start: float, end: float,
+                  window: int | None = None) -> None:
+        """Record a span from two monotonic readings the caller already
+        took: a leg that no with-block can cover (a fleet window's wait
+        between its dispatch in one call and its publication in the
+        next). Nests in the cycle open on this thread, or stands as a
+        cycle alone."""
+        if not self._enabled:
+            return
+        sp = _Span(self, self._state(), name, None, None, window)
+        sp.open_at(start)
+        sp.close_at(end)
 
     def _state(self) -> _ThreadState:
         st = getattr(self._tls, "state", None)
@@ -442,7 +496,9 @@ class SpanRecorder:
                     "ts": base_us + ev.rel_start_s * 1e6,
                     "dur": ev.duration_s * 1e6,
                     "pid": 0, "tid": tr.thread_id,
-                    "args": {"depth": ev.depth},
+                    "args": ({"depth": ev.depth} if ev.window is None
+                             else {"depth": ev.depth,
+                                   "window": ev.window}),
                 })
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
@@ -469,15 +525,21 @@ def install(rec: SpanRecorder) -> SpanRecorder:
 
 
 def span(name: str, budget_s: float | None = None,
-         stage: str | None = None):
+         stage: str | None = None, window: int | None = None):
     """The instrumentation point. Disabled cost: one global read, one
     attribute check, a shared no-op context manager. ``stage``
     re-keys the stage histogram (``""`` = trace-only), so per-instance
-    span names never mint per-instance metric series."""
+    span names never mint per-instance metric series. ``window`` tags
+    the span with the fleet window it worked on."""
     rec = _active
     if not rec._enabled:
         return _NOOP
-    return rec.span(name, budget_s, stage)
+    return rec.span(name, budget_s, stage, window)
+
+
+def mark_span(name: str, start: float, end: float,
+              window: int | None = None) -> None:
+    _active.mark_span(name, start, end, window)
 
 
 def inflight() -> list[dict]:
