@@ -3695,7 +3695,8 @@ class Aggregator:
 
         Holds only ONE node's buffer lock at a time (never the report-
         store lock), so ingest POSTs stall at most for one node's
-        ``window_arrays`` — not the whole [N, W, T, F] assembly."""
+        ``window_arrays`` — not the whole [N, W, T, F] assembly. Each
+        node's windows are unrolled straight into its rows of the result."""
         from kepler_tpu.models.features import NUM_FEATURES
 
         n, w = batch.cpu_deltas.shape
@@ -3710,10 +3711,9 @@ class Aggregator:
             if entry is None or not ids:
                 continue
             lock, buf = entry
+            k = len(ids)
             with lock:
-                f, v = buf.window_arrays(ids)
-            hist[i, :len(ids)] = f
-            tv[i, :len(ids)] = v
+                buf.window_arrays(ids, out=(hist[i, :k], tv[i, :k]))
         return hist, tv
 
     def _check_params_shape(self) -> None:

@@ -68,9 +68,10 @@ FIELDS = ("seq", "stamp", "kind") + MARKS + ("assembly_cpu_s",) + COUNTS \
 
 # complete records served on /debug/window. A reader that joins a run's
 # windows to their records needs every window of its measured stretch in
-# the last body: raise this before a program publishes more than some 200
-# windows in a benchmark run (PERF.md section 7).
-RECORDS_KEPT = 256
+# the last body: a benchmark run publishes some 600 at 0.12 s a window.
+# Raise this before a program publishes more than some 1800 in a run
+# (PERF.md section 7).
+RECORDS_KEPT = 2048
 
 
 class WindowRecord:
@@ -174,8 +175,8 @@ def records_json(records: list[WindowRecord]) -> str:
     """The ``records`` value of ``/debug/window``, as JSON text. A
     complete record never changes, so its row is rendered once, by the
     first request that serves it: a poller asks for the whole ring once per
-    published window, and 256 rows rendered anew each time would hold the
-    interpreter for milliseconds of every window's assembly."""
+    published window, and a full ring's rows rendered anew each time would
+    hold the interpreter for tens of milliseconds of every window."""
     rows = []
     for rec in records:
         if rec.text is None:

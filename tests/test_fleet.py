@@ -734,6 +734,123 @@ class TestTemporalAggregator:
         post_report(server, make_report("metal", mode=MODE_RATIO))
         assert "metal" not in agg._history
 
+    def test_history_windows_equal_the_per_pod_oracle(self, server):
+        """A small fleet through real ingest — a wrapped model node, one
+        whose pods changed, a young one, a ratio node, and a node the
+        aggregator holds no history of: the [N, W, T, F] assembly equals
+        what per-pod buffers fed the same stored reports give, bit for bit,
+        and every other row is zero."""
+        import dataclasses
+
+        from kepler_tpu.parallel.fleet import assemble_fleet_batch
+        from kepler_tpu.resource.informer import FeatureBatch
+        from tests.test_ring import PerPodHistory, same_bits
+
+        agg = Aggregator(server, model_mode="temporal", node_bucket=8,
+                         workload_bucket=16, history_window=4)
+        agg.init()
+        oracles: dict[str, PerPodHistory] = {}
+
+        def send(report, seq):
+            post_report(server, report, seq=seq)
+            got = agg._reports[report.node_name].report  # as decoded
+            if got.mode == MODE_MODEL:
+                oracles.setdefault(got.node_name, PerPodHistory(4)).push(
+                    FeatureBatch(
+                        kinds=got.workload_kinds, ids=got.workload_ids,
+                        cpu_deltas=np.asarray(got.cpu_deltas, np.float32),
+                        node_cpu_delta=float(got.node_cpu_delta),
+                        usage_ratio=float(got.usage_ratio)),
+                    float(got.dt_s))
+
+        for seq in range(1, 7):
+            send(make_report("m-wrapped", w=5, mode=MODE_MODEL, seed=seq),
+                 seq)
+            send(make_report("r-ratio", w=4, mode=MODE_RATIO, seed=seq), seq)
+            changed = make_report("m-changed", w=5, mode=MODE_MODEL,
+                                  seed=seq)
+            if seq > 3:  # two pods gone, two new, one moved to the front
+                changed = dataclasses.replace(changed, workload_ids=[
+                    "m-changed-w4", "m-changed-w0", "m-changed-w1",
+                    "m-changed-w5", "m-changed-w6"])
+            send(changed, seq)
+            if seq > 4:
+                send(make_report("m-young", w=3, mode=MODE_MODEL, seed=seq),
+                     seq)
+        assert sorted(agg._history) == ["m-changed", "m-wrapped", "m-young"]
+        reports = [agg._reports[name].report for name in sorted(
+            agg._reports)] + [make_report("m-unheard", w=2, mode=MODE_MODEL)]
+        batch = assemble_fleet_batch(reports, n_zones=2, node_bucket=8,
+                                     workload_bucket=16)
+        hist, tv = agg._history_windows(batch)
+        assert hist.shape == (8, 16, 4, 7) and hist.dtype == np.float32
+        assert tv.shape == (8, 16, 4) and tv.dtype == np.bool_
+        want_hist, want_tv = np.zeros_like(hist), np.zeros_like(tv)
+        for i, report in enumerate(reports):
+            oracle = oracles.get(report.node_name)
+            if oracle is not None:
+                k = len(report.workload_ids)
+                want_hist[i, :k], want_tv[i, :k] = oracle.window_arrays(
+                    report.workload_ids)
+        assert same_bits(hist, want_hist) and same_bits(tv, want_tv)
+        counts = {r.node_name: int(tv[i].sum())
+                  for i, r in enumerate(reports)}
+        assert counts == {"m-changed": 3 * 4 + 2 * 3, "m-wrapped": 5 * 4,
+                          "m-young": 3 * 2, "r-ratio": 0, "m-unheard": 0}
+
+    def test_a_push_beside_the_assembly_never_tears_a_window(self, server):
+        """One thread pushes a node's history (every pod's row of push k
+        reads k) while another assembles windows: each assembled window is
+        the same run of consecutive pushes for every pod — never a row of a
+        push whose cursor is not advanced yet, never half the pods ahead.
+        The per-node lock around push and window_arrays is what holds it."""
+        import dataclasses
+        import sys
+        import threading
+
+        from kepler_tpu.parallel.fleet import assemble_fleet_batch
+
+        agg = Aggregator(server, model_mode="temporal", node_bucket=8,
+                         workload_bucket=16, history_window=4)
+
+        def report(k):
+            base = make_report("node-a", w=12, mode=MODE_MODEL)
+            return dataclasses.replace(
+                base, cpu_deltas=np.full(12, float(k), np.float32))
+
+        for k in range(1, 5):
+            agg._push_history(report(k))
+        batch = assemble_fleet_batch([report(0)], n_zones=2, node_bucket=8,
+                                     workload_bucket=16)
+        stop = threading.Event()
+        pushed = [4]
+
+        def pusher():
+            while not stop.is_set() and pushed[0] < 200_000:
+                agg._push_history(report(pushed[0] + 1))
+                pushed[0] += 1
+
+        old_interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        thread = threading.Thread(target=pusher, daemon=True)
+        thread.start()
+        try:
+            deadline = time.monotonic() + 2.0
+            windows = 0
+            while time.monotonic() < deadline and windows < 2000:
+                hist, tv = agg._history_windows(batch)
+                assert tv[0, :12].all()
+                ticks = hist[0, :12, :, 0]
+                assert (ticks == ticks[0]).all(), ticks
+                assert (np.diff(ticks[0]) == 1.0).all(), ticks[0]
+                windows += 1
+        finally:
+            stop.set()
+            thread.join(timeout=30)
+            sys.setswitchinterval(old_interval)
+        assert not thread.is_alive()
+        assert windows > 50 and pushed[0] > 50
+
     def test_window_longer_than_params_rejected_at_startup(self, server):
         import jax
 

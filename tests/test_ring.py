@@ -194,6 +194,165 @@ class TestHistoryBuffer:
         assert np.isfinite(np.asarray(watts)).all()
 
 
+class PerPodHistory:
+    """The oracle: ``HistoryBuffer`` as it was before it became one slab —
+    an ndarray and three dict entries per id, a Python step per id in both
+    methods. Test code only; every window the slab serves must equal this
+    one's bit for bit."""
+
+    def __init__(self, window, n_features=7, evict_after=2):
+        self.window = window
+        self.n_features = n_features
+        self._evict_after = evict_after
+        self._tick = 0
+        self._rows = {}
+        self._count = {}
+        self._cursor = {}
+        self._seen = {}
+
+    def __len__(self):
+        return len(self._rows)
+
+    def push(self, batch, dt_s):
+        rows = feature_rows(batch, dt_s)
+        self._tick += 1
+        for i, wid in enumerate(batch.ids):
+            buf = self._rows.get(wid)
+            if buf is None:
+                buf = np.zeros((self.window, self.n_features), np.float32)
+                self._rows[wid] = buf
+                self._count[wid] = 0
+                self._cursor[wid] = 0
+            buf[self._cursor[wid]] = rows[i]
+            self._cursor[wid] = (self._cursor[wid] + 1) % self.window
+            self._count[wid] = min(self._count[wid] + 1, self.window)
+            self._seen[wid] = self._tick
+        if self._evict_after > 0:
+            dead = [wid for wid, seen in self._seen.items()
+                    if self._tick - seen >= self._evict_after]
+            for wid in dead:
+                for d in (self._rows, self._count, self._cursor, self._seen):
+                    del d[wid]
+
+    def window_arrays(self, ids):
+        w = len(ids)
+        feats = np.zeros((w, self.window, self.n_features), np.float32)
+        t_valid = np.zeros((w, self.window), bool)
+        for i, wid in enumerate(ids):
+            n = self._count.get(wid, 0)
+            if not n:
+                continue
+            ordered = np.roll(self._rows[wid], -self._cursor[wid],
+                              axis=0)[self.window - n:]
+            feats[i, :n] = ordered
+            t_valid[i, :n] = True
+        return feats, t_valid
+
+
+def same_bits(a, b):
+    return a.dtype == b.dtype and a.shape == b.shape and np.array_equal(
+        a.view(np.uint8), b.view(np.uint8))
+
+
+# what a push's id list is drawn from, by scenario: (pool of ids, share of
+# the live ids replaced a push, chance an id sits a push out, chance an id
+# is listed twice, pushes)
+SCENARIOS = {
+    "steady": dict(pool=40, churn=0.0, absent=0.0, twice=0.0, pushes=40),
+    "churn": dict(pool=60, churn=0.1, absent=0.0, twice=0.0, pushes=60),
+    "absences": dict(pool=30, churn=0.0, absent=0.3, twice=0.0, pushes=60),
+    "evict_and_return": dict(pool=12, churn=0.4, absent=0.4, twice=0.0,
+                             pushes=80),
+    "duplicates": dict(pool=20, churn=0.1, absent=0.2, twice=0.2,
+                       pushes=50),
+    "growth": dict(pool=300, churn=0.05, absent=0.05, twice=0.01,
+                   pushes=30),
+}
+
+
+class TestHistoryBufferAgainstPerPodOracle:
+    @pytest.mark.parametrize("evict_after", [2, 1, 0, 3])
+    @pytest.mark.parametrize("window", [1, 3, 16])
+    @pytest.mark.parametrize("scenario", sorted(SCENARIOS))
+    def test_every_window_is_bit_equal(self, scenario, window, evict_after):
+        """Seeded random pushes: ids that churn, sit one push out, are
+        evicted and come back, are listed twice, are unknown; windows
+        shorter than T and wrapped rings; more ids than the first slab
+        holds. After EVERY push the slab's windows and length equal the
+        per-pod oracle's, bit for bit."""
+        cfg = SCENARIOS[scenario]
+        rng = np.random.default_rng(
+            [sorted(SCENARIOS).index(scenario), window, evict_after])
+        new = HistoryBuffer(window=window, evict_after=evict_after)
+        old = PerPodHistory(window=window, evict_after=evict_after)
+        # a small name space, so an evicted id is created again
+        names = [f"pod-{k}" for k in range(2 * cfg["pool"])]
+        live = list(rng.choice(names, cfg["pool"], replace=False))
+        for push in range(cfg["pushes"]):
+            for k in np.flatnonzero(rng.random(len(live)) < cfg["churn"]):
+                live[k] = str(rng.choice(names))  # may even be live: twice
+            ids = [wid for wid in live if rng.random() >= cfg["absent"]]
+            for wid in list(ids):
+                if rng.random() < cfg["twice"]:
+                    ids.insert(int(rng.integers(len(ids) + 1)), wid)
+            if push % 7 == 3:
+                ids = ids[::-1]  # same set, another order: new slot vector
+            deltas = rng.uniform(0.0, 4.0, len(ids)).astype(np.float32)
+            batch = FeatureBatch(
+                kinds=np.zeros(len(ids), np.int8), ids=ids,
+                cpu_deltas=deltas,
+                node_cpu_delta=float(rng.choice([0.0, deltas.sum() + 1.0])),
+                usage_ratio=float(rng.random()))
+            dt_s = float(rng.choice([0.0, 1.0, 5.0]))
+            new.push(batch, dt_s)
+            old.push(batch, dt_s)
+            assert len(new) == len(old)
+            # the pushed list itself; every name there is (known, evicted,
+            # never seen) with one that cannot exist, shuffled; and as many
+            # of those as were pushed, since a list like the last one in
+            # all but its ids must not be taken for it
+            ask = names + ["ghost"]
+            rng.shuffle(ask)
+            for query in (ids, list(ask), ask[:len(ids)]):
+                feats, valid = new.window_arrays(query)
+                want_feats, want_valid = old.window_arrays(query)
+                assert same_bits(feats, want_feats), (push, query)
+                assert same_bits(valid, want_valid), (push, query)
+        if scenario == "growth":
+            assert len(new._ids) > 16 * 8  # grew past the first slab, often
+
+    def test_out_arrays_are_filled_and_returned(self):
+        buf = HistoryBuffer(window=4)
+        for tick in range(6):
+            buf.push(FeatureBatch(
+                kinds=np.zeros(2, np.int8), ids=["a", "b"],
+                cpu_deltas=np.asarray([tick, 2.0], np.float32),
+                node_cpu_delta=10.0, usage_ratio=0.5), dt_s=5.0)
+        want = buf.window_arrays(["b", "ghost", "a"])
+        # a destination that holds garbage, as a slice of a larger array
+        feats = np.full((5, 4, 7), np.nan, np.float32)
+        valid = np.ones((5, 4), bool)
+        got = buf.window_arrays(["b", "ghost", "a"],
+                                out=(feats[1:4], valid[1:4]))
+        assert got[0].base is feats and got[1].base is valid
+        assert same_bits(feats[1:4], want[0])
+        assert same_bits(valid[1:4], want[1])
+        assert np.isnan(feats[0]).all() and np.isnan(feats[4]).all()
+
+    def test_a_callers_later_edit_of_its_id_list_changes_nothing(self):
+        ids = ["a", "b"]
+        buf = HistoryBuffer(window=4)
+        batch = FeatureBatch(kinds=np.zeros(2, np.int8), ids=ids,
+                             cpu_deltas=np.asarray([1.0, 2.0], np.float32),
+                             node_cpu_delta=10.0, usage_ratio=0.5)
+        buf.push(batch, dt_s=5.0)
+        ids[0] = "c"  # the same list object, now other ids
+        buf.push(batch, dt_s=5.0)
+        feats, valid = buf.window_arrays(["a", "b", "c"])
+        assert valid.sum(axis=1).tolist() == [1, 2, 1]
+        assert feats[2, 0, 0] == 1.0
+
+
 class TestSequenceParallelTraining:
     def test_grads_flow_through_ring_and_match_dense(self):
         """One SP train step == one single-device dense train step: the

@@ -287,7 +287,7 @@ def test_the_run_loop_times_its_wait_as_the_next_windows_tick_leg():
         s.close()
 
 
-def test_the_ledger_keeps_the_last_256_records_and_every_count():
+def test_the_ledger_keeps_the_last_records_kept_and_every_count():
     ledger = WindowLedger()
     for seq in range(RECORDS_KEPT + 44):
         rec = WindowRecord(seq, 1000.0 + seq, begin=float(seq))
