@@ -376,7 +376,12 @@ DESCRIPTIONS = {
                                  "frames merge against. Eviction "
                                  "costs the node one structured 409 "
                                  "needs-keyframe round-trip (it "
-                                 "resends full), never data.",
+                                 "resends full), never data. Set it to "
+                                 "at least the fleet's size: under more "
+                                 "round-robin senders than bases every "
+                                 "base is evicted before its node "
+                                 "reports again, so every delta costs "
+                                 "a 409 and a keyframe.",
     "agent.spool.dir": "Crash-safe report spool directory: windows are "
                        "appended (CRC-framed) before any send and only "
                        "acked on 2xx, so crashes/outages replay instead "

@@ -37,6 +37,7 @@ import collections
 import hashlib
 import json
 import logging
+import math
 import queue
 import threading
 import time as _time
@@ -100,6 +101,7 @@ from kepler_tpu.fleet.window import (DeviceWindowError, FusedFlush,
 from kepler_tpu.monitor.history import HistoryBuffer
 from kepler_tpu.telemetry import DEFAULT_DELIVERY_BUCKETS, Histogram
 from kepler_tpu.parallel.aggregator_core import (
+    fleet_shardings,
     make_fleet_program,
     make_temporal_fleet_program,
     put_fleet_batch,
@@ -750,6 +752,9 @@ class Aggregator:
                        # fused tier publishes)
                        "last_sync_per_window_ms": 0.0,
                        "last_h2d_rows": 0,
+                       # serial path: of the last window's H2D bytes, the
+                       # most any one device was sent (0 on other paths)
+                       "last_h2d_device_bytes": 0,
                        # sharded window: device shards the last window ran
                        # over (1 = unsharded engine or demoted rung) and
                        # the per-shard H2D breakdown
@@ -793,6 +798,9 @@ class Aggregator:
                              "history_push_s": 0.0}
         # untrained fallbacks per zone count — never clobber trained params
         self._fallback_params: dict[int, object] = {}
+        # (params as _params_for_zones gave them, the same replicated over
+        # the mesh): see _params_on_mesh
+        self._params_placed: tuple[object, object] | None = None
         # -- window pipeline (fleet.window) --------------------------------
         # depth 1 = serial (dispatch then fetch in the same call, the
         # library-call contract every aggregate_once() test relies on);
@@ -3286,7 +3294,7 @@ class Aggregator:
                         backend=self._backend,
                         accuracy_mode=self._accuracy_mode)
             program = self._program
-            params = self._params_for_zones(n_zones)
+            params = self._params_on_mesh(n_zones)
         feat_hist = t_valid = None
         # the loop thread's CPU time is read inside the wall-clock leg, so
         # that wall − CPU (time off the processor) cannot come out negative
@@ -3302,8 +3310,11 @@ class Aggregator:
             raise DeviceWindowError(
                 "dispatch_error",
                 "injected dispatch failure (serial fleet program)")
-        with rec.leg("window.h2d"):
-            args = put_fleet_batch(batch, params, feat_hist, t_valid)
+        # every device is sent its own nodes' rows, and nothing else
+        rec.devices = int(self._mesh.devices.size)
+        with rec.leg("window.h2d", devices=rec.devices):
+            args = put_fleet_batch(batch, params, feat_hist, t_valid,
+                                   mesh=self._mesh)
         # ASYNC dispatch: jax returns device futures immediately; the D2H
         # copies start NOW (they queue behind the compute on the device
         # stream) instead of at the np.asarray fetch in _publish. The
@@ -3332,6 +3343,11 @@ class Aggregator:
             rec.rows_work = int(counts[
                 batch.mode[:len(counts)] == MODE_MODEL].sum())
         rec.h2d_bytes = sum(int(a.nbytes) for a in args[1:])
+        # a NamedSharding's shards are all of one shape, so the device
+        # that was sent most was sent one shard of every argument
+        rec.h2d_bytes_max_device = sum(
+            math.prod(a.sharding.shard_shape(a.shape)) * a.dtype.itemsize
+            for a in args[1:])
         return _Pending(
             kind="legacy", out=result, meta=None, now=now, rec=rec,
             h2d_rows=batch.n_nodes,
@@ -3431,7 +3447,10 @@ class Aggregator:
             results = self._scatter_packed(p, p.out)
         else:
             result = p.out
-            with rec.leg("window.pipeline_wait"):
+            # np.asarray of a node-sharded result copies each device's
+            # rows from that device into their place in one host array:
+            # a shard at a time, nothing gathered on a device
+            with rec.leg("window.pipeline_wait", devices=rec.devices):
                 fetched = self._fetch_device(lambda: (
                     np.asarray(result.node_power_uw),
                     np.asarray(result.node_energy_uj),
@@ -3464,6 +3483,7 @@ class Aggregator:
             self._stats["last_attribution_ms"] = (
                 assembly_ms + dispatch_ms + wait_ms + scatter_ms)
             self._stats["last_h2d_rows"] = p.h2d_rows
+            self._stats["last_h2d_device_bytes"] = rec.h2d_bytes_max_device
             self._stats["window_shards"] = p.shards
             self._stats["last_h2d_shards"] = list(p.h2d_shards)
             if p.sync_per_window_ms >= 0.0:
@@ -3628,6 +3648,22 @@ class Aggregator:
                 jax.random.PRNGKey(0), n_zones=n_zones, **kwargs)
             self._fallback_params[n_zones] = fallback
         return fallback
+
+    def _params_on_mesh(self, n_zones: int) -> Any:
+        """:meth:`_params_for_zones` replicated over the mesh, placed once
+        per params object and kept: the serial program finds them on
+        every device and no window sends them again."""
+        params = self._params_for_zones(n_zones)
+        if params is None:
+            return None
+        held = self._params_placed
+        if held is None or held[0] is not params:
+            import jax
+
+            replicated, _by_node = fleet_shardings(self._mesh)
+            held = self._params_placed = (
+                params, jax.device_put(params, replicated))
+        return held[1]
 
     def _dump_training_window(self, batch: Any, wl_power_uw: np.ndarray,
                               zone_names: list[str], now: float,
@@ -3823,7 +3859,8 @@ class Aggregator:
                     "last_wait_ms", "last_fetch_ms",
                     "last_sync_per_window_ms", "last_scatter_ms",
                     "last_attribution_ms", "last_h2d_rows",
-                    "last_h2d_shards", "window_shards", "shard_skew",
+                    "last_h2d_device_bytes", "last_h2d_shards",
+                    "window_shards", "shard_skew",
                     "window_compiles_total", "window_rung",
                     "window_demotions_total",
                     "window_repromotions_total", "last_batch_nodes",
@@ -4013,6 +4050,15 @@ class Aggregator:
             "— 0 when the resident device batch was already current")
         h2d_rows.add_metric([], stats["last_h2d_rows"])
         yield h2d_rows
+        h2d_device = GaugeMetricFamily(
+            "kepler_fleet_window_h2d_device_bytes",
+            "Bytes the last fleet window sent to the device that was sent "
+            "most (serial einsum/temporal path: each device of the mesh is "
+            "put its own nodes' rows, so this is the window's H2D bytes "
+            "over the device count; 0 on the packed paths, whose delta "
+            "H2D counts rows)")
+        h2d_device.add_metric([], stats["last_h2d_device_bytes"])
+        yield h2d_device
         fetch_ms = GaugeMetricFamily(
             "kepler_fleet_window_fetch_ms",
             "Publish-fetch leg of the last fleet window: per-shard "

@@ -60,11 +60,15 @@ LEGS = {
     "window.publish": ("scattered", "published"),
 }
 
-# counted where the work happens; summed over windows in ``counts``
-COUNTS = ("rows_program", "rows_work", "h2d_bytes")
+# counted where the work happens; summed over windows in ``counts``.
+# ROW_COUNTS are columns of a served row too; what the put over the mesh
+# counts is served in the sums alone (``chipbench`` pins a row's columns)
+ROW_COUNTS = ("rows_program", "rows_work", "h2d_bytes")
+SUM_COUNTS = ("devices", "h2d_bytes_max_device")
+COUNTS = ROW_COUNTS + SUM_COUNTS
 
-FIELDS = ("seq", "stamp", "kind") + MARKS + ("assembly_cpu_s",) + COUNTS \
-    + ("compiled",)
+FIELDS = ("seq", "stamp", "kind") + MARKS + ("assembly_cpu_s",) \
+    + ROW_COUNTS + ("compiled",)
 
 # complete records served on /debug/window. A reader that joins a run's
 # windows to their records needs every window of its measured stretch in
@@ -78,7 +82,7 @@ class WindowRecord:
     """The marks and counts of one window. Written by the aggregation loop
     alone until it is complete; read-only from then on."""
 
-    __slots__ = FIELDS + ("base", "cpu_begin_ns", "text")
+    __slots__ = FIELDS + SUM_COUNTS + ("base", "cpu_begin_ns", "text")
 
     def __init__(self, seq: int, stamp: float, begin: float,
                  tick: float | None = None) -> None:
@@ -105,23 +109,30 @@ class WindowRecord:
         self.rows_program = 0  # node bucket × workload bucket
         self.rows_work = 0  # pods of model nodes: the estimates published
         self.h2d_bytes = 0
+        self.devices = 0  # the devices the window's program ran over
+        self.h2d_bytes_max_device = 0  # of h2d_bytes, the most one was sent
         self.compiled = False
 
     @contextlib.contextmanager
-    def leg(self, name: str) -> Iterator[None]:
+    def leg(self, name: str, devices: int | None = None) -> Iterator[None]:
         """One leg as a with-block: it starts on the mark the leg before
         it ended on and ends with the block, on a mark of its own, and its
         span (with ``window=seq``) lies on those two marks. The block is
         mirrored onto the JAX profiler's host timeline under the same
         name, with ``window`` as a stat, so a ``/debug/pprof/jax`` capture
         shows host legs and device ops together (no profile running: well
-        under a microsecond). A block that raises sets no mark."""
+        under a microsecond). ``devices``, on the legs that put to or
+        fetch from the mesh, is a second attribute of both. A block that
+        raises sets no mark."""
         a, b = LEGS[name]
-        sp = telemetry.span(name, window=self.seq)
+        attrs = {"window": self.seq}
+        if devices is not None:
+            attrs["devices"] = devices
+        sp = telemetry.span(name, **attrs)
         sp.open_at(getattr(self, a))
         now = None
         try:
-            with TraceAnnotation(name, window=self.seq):
+            with TraceAnnotation(name, **attrs):
                 yield
             now = time.monotonic()
             setattr(self, b, now)
@@ -143,7 +154,7 @@ class WindowRecord:
             out.append(None if t is None else round(t - self.base, 7))
         cpu = self.assembly_cpu_s
         out.append(None if cpu is None else round(cpu, 7))
-        out += [getattr(self, name) for name in COUNTS]
+        out += [getattr(self, name) for name in ROW_COUNTS]
         out.append(self.compiled)
         return out
 

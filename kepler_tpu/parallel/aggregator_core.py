@@ -203,6 +203,13 @@ def accuracy_mode_predictor(predict_fn, model_mode: str):
     return wrapped
 
 
+def fleet_shardings(mesh: Mesh) -> tuple[NamedSharding, NamedSharding]:
+    """→ (replicated, by_node): what the fleet programs over ``mesh``
+    declare for the params and for every per-node argument (the leading
+    axis over ``node``, the rest whole)."""
+    return NamedSharding(mesh, P()), NamedSharding(mesh, P(NODE_AXIS))
+
+
 def make_fleet_program(mesh: Mesh, model_mode: str | None = None,
                        backend: str = "einsum",
                        accuracy_mode: bool = False):
@@ -224,9 +231,7 @@ def make_fleet_program(mesh: Mesh, model_mode: str | None = None,
     predict_fn = predictor(model_mode) if model_mode else None
     if predict_fn is not None and accuracy_mode:
         predict_fn = accuracy_mode_predictor(predict_fn, model_mode)
-    by_node_2d = NamedSharding(mesh, P(NODE_AXIS, None))
-    by_node_1d = NamedSharding(mesh, P(NODE_AXIS))
-    replicated = NamedSharding(mesh, P())
+    replicated, by_node = fleet_shardings(mesh)
 
     attribute_fn = resolve_attribute_fn(mesh, backend)
     fn = functools.partial(fleet_attribution_program,
@@ -245,18 +250,11 @@ def make_fleet_program(mesh: Mesh, model_mode: str | None = None,
 
     return jax.jit(
         fleet_window,
-        in_shardings=(
-            replicated,  # model params (tiny; tensor-sharded in trainer)
-            by_node_2d,  # zone_deltas
-            by_node_2d,  # zone_valid
-            by_node_1d,  # usage_ratio
-            by_node_2d,  # cpu_deltas
-            by_node_2d,  # workload_valid
-            by_node_1d,  # node_cpu_delta
-            by_node_1d,  # dt
-            by_node_1d,  # mode
-        ),
-        out_shardings=NamedSharding(mesh, P(NODE_AXIS)),
+        # model params (tiny; tensor-sharded in trainer), then zone_deltas,
+        # zone_valid, usage_ratio, cpu_deltas, workload_valid,
+        # node_cpu_delta, dt, mode
+        in_shardings=(replicated,) + (by_node,) * 8,
+        out_shardings=by_node,
     )
 
 
@@ -265,8 +263,7 @@ def make_temporal_fleet_program(mesh: Mesh, backend: str = "einsum",
     """jit the TEMPORAL fleet program (extra ``feat_hist``/``t_valid``
     inputs, node-axis sharded). Params replicate — the model is tiny; for
     very long windows serve through ``parallel.sequence`` instead."""
-    by_node = NamedSharding(mesh, P(NODE_AXIS))
-    replicated = NamedSharding(mesh, P())
+    replicated, by_node = fleet_shardings(mesh)
     fn = functools.partial(temporal_fleet_program,
                            attribute_fn=resolve_attribute_fn(mesh, backend),
                            accuracy_mode=accuracy_mode)
@@ -292,23 +289,30 @@ def put_fleet_batch(
     model_params: Any = None,
     feat_hist=None,  # [N, W, T, F] — temporal programs only
     t_valid=None,  # [N, W, T]
+    mesh: Mesh | None = None,
 ) -> list:
     """The H2D half of the host entry: every argument of a fleet program
-    as a device array, in the program's order (the params first)."""
-    args = [
-        model_params if model_params is not None else jnp.zeros(()),
-        jnp.asarray(batch.zone_deltas_uj),
-        jnp.asarray(batch.zone_valid),
-        jnp.asarray(batch.usage_ratio),
-        jnp.asarray(batch.cpu_deltas),
-        jnp.asarray(batch.workload_valid),
-        jnp.asarray(batch.node_cpu_delta),
-        jnp.asarray(batch.dt_s),
-        jnp.asarray(batch.mode),
-    ]
+    as a device array, in the program's order (the params first).
+
+    With the program's ``mesh`` every per-node argument is put with the
+    sharding the program declares for it (:func:`fleet_shardings`): each
+    device is sent its own nodes' rows straight from the host, holds no
+    other device's, and the jit has nothing to move; over one device that
+    is the whole array on that device. Without it every argument goes
+    whole to the default device and the jit moves what belongs elsewhere
+    (the library entry, :func:`run_fleet_attribution`)."""
+    if model_params is None:
+        model_params = jnp.zeros(())
+    data = [batch.zone_deltas_uj, batch.zone_valid, batch.usage_ratio,
+            batch.cpu_deltas, batch.workload_valid, batch.node_cpu_delta,
+            batch.dt_s, batch.mode]
     if feat_hist is not None:
-        args += [jnp.asarray(feat_hist), jnp.asarray(t_valid)]
-    return args
+        data += [feat_hist, t_valid]
+    if mesh is None:
+        return [model_params] + [jnp.asarray(a) for a in data]
+    replicated, by_node = fleet_shardings(mesh)
+    return [jax.device_put(model_params, replicated)] \
+        + jax.device_put(data, by_node)
 
 
 def run_fleet_attribution(

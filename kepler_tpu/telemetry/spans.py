@@ -132,6 +132,9 @@ class SpanEvent:
     # window is dispatched in one aggregator.window cycle and published
     # in the next
     window: int | None = None
+    # the devices the leg put to or fetched from (the serial fleet
+    # window's H2D and fetch legs), None on every other span
+    devices: int | None = None
 
 
 @dataclass(frozen=True)
@@ -158,7 +161,9 @@ class CycleTrace:
                  "rel_start_s": e.rel_start_s,
                  "duration_s": e.duration_s,
                  **({"stage": e.stage} if e.stage is not None else {}),
-                 **({"window": e.window} if e.window is not None else {})}
+                 **({"window": e.window} if e.window is not None else {}),
+                 **({"devices": e.devices} if e.devices is not None
+                    else {})}
                 for e in self.events
             ],
         }
@@ -188,18 +193,20 @@ class _Span:
     not supported — ``span()`` returns a fresh handle per with-block."""
 
     __slots__ = ("_rec", "_st", "_name", "_budget", "_t0", "_depth",
-                 "_stage", "_window", "_discarded")
+                 "_stage", "_window", "_devices", "_discarded")
 
     def __init__(self, rec: "SpanRecorder", st: _ThreadState, name: str,
                  budget_s: float | None,
                  stage: str | None = None,
-                 window: int | None = None) -> None:
+                 window: int | None = None,
+                 devices: int | None = None) -> None:
         self._rec = rec
         self._st = st
         self._name = name
         self._budget = budget_s
         self._stage = stage
         self._window = window
+        self._devices = devices
         self._discarded = False
 
     def __enter__(self) -> "_Span":
@@ -243,7 +250,8 @@ class _Span:
                 name=self._name, depth=self._depth,
                 rel_start_s=self._t0 - st.mono_anchor,
                 duration_s=max(0.0, t1 - self._t0),
-                stage=self._stage, window=self._window))
+                stage=self._stage, window=self._window,
+                devices=self._devices))
         if not st.stack:
             if self._discarded:
                 st.events = []
@@ -320,15 +328,18 @@ class SpanRecorder:
     # -- span API ------------------------------------------------------------
 
     def span(self, name: str, budget_s: float | None = None,
-             stage: str | None = None, window: int | None = None):
+             stage: str | None = None, window: int | None = None,
+             devices: int | None = None):
         """Context manager timing one stage. ``budget_s`` is meaningful
         on the OUTERMOST span of a cycle: exceeding it counts one
         ``kepler_self_cycle_overrun_total{cycle=name}``. ``stage``
         overrides the histogram key (``""`` = trace-only) — see
-        :class:`SpanEvent`. ``window`` is the fleet window's id."""
+        :class:`SpanEvent`. ``window`` is the fleet window's id,
+        ``devices`` how many devices the leg put to or fetched from."""
         if not self._enabled:
             return _NOOP
-        return _Span(self, self._state(), name, budget_s, stage, window)
+        return _Span(self, self._state(), name, budget_s, stage, window,
+                     devices)
 
     def mark_span(self, name: str, start: float, end: float,
                   window: int | None = None) -> None:
@@ -496,9 +507,10 @@ class SpanRecorder:
                     "ts": base_us + ev.rel_start_s * 1e6,
                     "dur": ev.duration_s * 1e6,
                     "pid": 0, "tid": tr.thread_id,
-                    "args": ({"depth": ev.depth} if ev.window is None
-                             else {"depth": ev.depth,
-                                   "window": ev.window}),
+                    "args": {"depth": ev.depth, **{
+                        k: v for k, v in (("window", ev.window),
+                                          ("devices", ev.devices))
+                        if v is not None}},
                 })
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
@@ -525,16 +537,18 @@ def install(rec: SpanRecorder) -> SpanRecorder:
 
 
 def span(name: str, budget_s: float | None = None,
-         stage: str | None = None, window: int | None = None):
+         stage: str | None = None, window: int | None = None,
+         devices: int | None = None):
     """The instrumentation point. Disabled cost: one global read, one
     attribute check, a shared no-op context manager. ``stage``
     re-keys the stage histogram (``""`` = trace-only), so per-instance
     span names never mint per-instance metric series. ``window`` tags
-    the span with the fleet window it worked on."""
+    the span with the fleet window it worked on, ``devices`` with how
+    many devices it put to or fetched from."""
     rec = _active
     if not rec._enabled:
         return _NOOP
-    return rec.span(name, budget_s, stage, window)
+    return rec.span(name, budget_s, stage, window, devices)
 
 
 def mark_span(name: str, start: float, end: float,

@@ -1,0 +1,304 @@
+"""Every device is sent its own rows (ISSUE 31).
+
+``put_fleet_batch`` with the program's mesh puts every per-node argument
+with the sharding the program declares for it, so each device of the mesh
+takes its own nodes' rows straight from the host and the jit moves
+nothing; over a mesh of one device that is the whole array on that device,
+bit for bit what the put without a mesh gives. Here on the CPU's virtual
+devices (conftest gives eight): counts, placements and published values,
+never a time. The served path against the plain reference, on a child with
+four devices, is ``tests/chipbench/test_four_chip_cell.py``.
+"""
+
+from __future__ import annotations
+
+import os
+import urllib.request
+
+import jax
+import numpy as np
+import pytest
+
+from kepler_tpu import telemetry
+from kepler_tpu.fleet.wire import encode_report
+from kepler_tpu.models.temporal import init_temporal
+from kepler_tpu.parallel.aggregator_core import (fleet_shardings,
+                                                 make_fleet_program,
+                                                 make_temporal_fleet_program,
+                                                 put_fleet_batch)
+from kepler_tpu.parallel.fleet import (MODE_MODEL, MODE_RATIO, NodeReport,
+                                       assemble_fleet_batch)
+from kepler_tpu.parallel.mesh import make_mesh
+from kepler_tpu.telemetry.spans import SpanRecorder
+
+from tests import test_window_record
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ZONES = ["package", "dram"]
+NODES, SLOTS, TICKS, FEATURES = 8, 16, 4, 7
+
+
+def report(k: int, seed: int) -> NodeReport:
+    rng = np.random.default_rng([seed, k])
+    w = 3 + k % 4
+    cpu = rng.uniform(0.1, 5.0, w).astype(np.float32)
+    return NodeReport(
+        node_name=f"node-{k}",
+        zone_deltas_uj=rng.uniform(1e7, 5e8, 2).astype(np.float32),
+        zone_valid=np.ones(2, bool), usage_ratio=float(rng.uniform(0.3, 0.8)),
+        cpu_deltas=cpu, workload_ids=[f"n{k}-w{j}" for j in range(w)],
+        node_cpu_delta=float(cpu.sum()), dt_s=5.0,
+        mode=MODE_MODEL if k % 2 else MODE_RATIO,
+        workload_kinds=np.ones(w, np.int8))
+
+
+def seeded_params():
+    """An untrained ``init_temporal`` has a zero head and a zero skip:
+    every model row would be 0 W and equal whatever was computed. A seeded
+    head around a bias of a few watts makes every layer reach the
+    published number."""
+    params = init_temporal(jax.random.PRNGKey(7), n_zones=2)
+    rng = np.random.default_rng(7)
+    d = params["w_head"].shape[0]
+    return dict(
+        params,
+        w_head=(0.09 * rng.standard_normal((d, 2))).astype(np.float32),
+        b_head=rng.uniform(3.0, 6.0, 2).astype(np.float32))
+
+
+def mesh_of(n: int):
+    return make_mesh(devices=jax.devices()[:n])
+
+
+def temporal_inputs():
+    batch = assemble_fleet_batch(
+        [report(k, 1) for k in range(NODES)], n_zones=2, node_bucket=NODES,
+        workload_bucket=SLOTS)
+    rng = np.random.default_rng(0)
+    hist = rng.random((NODES, SLOTS, TICKS, FEATURES), np.float32)
+    t_valid = rng.random((NODES, SLOTS, TICKS)) > 0.2
+    return batch, seeded_params(), hist, t_valid
+
+
+def test_on_four_devices_each_holds_a_quarter_of_every_node_argument():
+    mesh = mesh_of(4)
+    replicated, by_node = fleet_shardings(mesh)
+    args = put_fleet_batch(*temporal_inputs(), mesh=mesh)
+    assert len(args) == 11
+    for leaf in jax.tree.leaves(args[0]):  # the params: whole, everywhere
+        assert leaf.sharding.is_equivalent_to(replicated, leaf.ndim)
+    for arr in args[1:]:
+        assert arr.sharding.is_equivalent_to(by_node, arr.ndim)
+        shards = sorted(arr.addressable_shards,
+                        key=lambda s: s.index[0].start)
+        assert [s.device for s in shards] == list(mesh.devices.flat)
+        # no device holds another's rows: a quarter each, in node order
+        for k, s in enumerate(shards):
+            assert s.data.shape == (NODES // 4,) + arr.shape[1:]
+            assert s.index[0] == slice(2 * k, 2 * k + 2)
+        assert sum(s.data.nbytes for s in shards) == arr.nbytes
+    # the program takes them as they lie; what it returns is by node too,
+    # and np.asarray reads it a shard at a time into one host array (no
+    # gather on a device: the result is never whole on any of them)
+    out = make_temporal_fleet_program(mesh)(*args)
+    power = out.workload_power_uw
+    assert power.sharding.is_equivalent_to(by_node, 3)
+    assert not power.is_fully_replicated
+    assert len(power.addressable_shards) == 4
+    whole = np.asarray(power)
+    for s in power.addressable_shards:
+        np.testing.assert_array_equal(whole[s.index], np.asarray(s.data))
+
+
+def test_on_one_device_the_put_is_bit_equal_to_the_put_without_a_mesh():
+    mesh = mesh_of(1)
+    inputs = temporal_inputs()
+    with_mesh = put_fleet_batch(*inputs, mesh=mesh)
+    without = put_fleet_batch(*inputs)
+    assert len(with_mesh) == len(without) == 11
+    for a, b in zip(with_mesh[1:], without[1:]):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert a.devices() == b.devices() == {jax.devices()[0]}
+        assert len(a.addressable_shards) == 1
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    program = make_temporal_fleet_program(mesh)
+    for got, want in zip(program(*with_mesh), program(*without)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_a_ratio_windows_arguments_are_put_the_same_way(n_dev):
+    """No history and no params: the ratio program's nine arguments, a
+    scalar in the params' place, replicated."""
+    mesh = mesh_of(n_dev)
+    replicated, by_node = fleet_shardings(mesh)
+    batch = temporal_inputs()[0]
+    args = put_fleet_batch(batch, mesh=mesh)
+    assert len(args) == 9
+    assert args[0].sharding.is_equivalent_to(replicated, 0)
+    for arr in args[1:]:
+        assert arr.sharding.is_equivalent_to(by_node, arr.ndim)
+        assert len(arr.addressable_shards) == n_dev
+    out = make_fleet_program(mesh)(*args)
+    want = make_fleet_program(mesh)(*put_fleet_batch(batch))
+    np.testing.assert_array_equal(np.asarray(out.workload_power_uw),
+                                  np.asarray(want.workload_power_uw))
+
+
+class Served(test_window_record.Served):
+    """``tests/test_window_record.py``'s served temporal aggregator (8 node
+    rows × 16 slots, T 4, ``pipelineDepth`` 2) over ``n_dev`` devices,
+    with every node of this file's fleet reporting each window."""
+
+    def __init__(self, n_dev: int, **kw) -> None:
+        super().__init__(mesh=mesh_of(n_dev), model_params=seeded_params(),
+                         **kw)
+
+    def window(self, seq: int, nodes=range(NODES)):
+        for k in nodes:
+            req = urllib.request.Request(
+                self.url("/v1/report"), method="POST",
+                data=encode_report(report(k, seq), ZONES, seq=seq, run="r1"))
+            assert urllib.request.urlopen(req, timeout=10).status == 204
+        return self.agg.aggregate_once()
+
+
+def published(n_dev: int) -> tuple:
+    rec = SpanRecorder(enabled=True)
+    with telemetry.installed(rec):
+        s = Served(n_dev)
+        try:
+            out = [s.window(seq) for seq in range(1, TICKS + 4)]
+            out.append(s.agg._drain_pipeline())
+            counts = s.agg._window_ledger.snapshot()[1]
+            placed = s.agg._params_placed
+            spans = [sp for tr in rec.recent_traces()
+                     for sp in tr.to_dict()["spans"]]
+            metrics = {m.name: m for m in s.agg.collect()}
+            gauge = metrics["kepler_fleet_window_h2d_device_bytes"]
+            return ([r for r in out if r is not None], counts, spans,
+                    placed, gauge.samples[0].value)
+        finally:
+            s.close()
+
+
+def test_the_window_over_four_devices_is_the_window_over_one():
+    """The same seeded reports through the served path on a mesh of four
+    devices and on a mesh of one. Ratio nodes are equal to the bit: their
+    watts are products and sums within one node's row, and a row's
+    arithmetic does not know how many rows lie beside it. Model nodes to
+    float32 rounding: XLA's CPU matmul blocks its rows by the shape it is
+    given, two rows of a device against eight, so a sum may be taken in
+    another order; rtol 1e-5 is some eighty float32 ulps, and a hundred
+    times under the 1e-3 that one bf16 rounding of an operand would
+    show."""
+    four, counts4, spans4, placed4, gauge4 = published(4)
+    one, counts1, spans1, placed1, gauge1 = published(1)
+    assert len(four) == len(one) == TICKS + 3
+    for a, b in zip(four, one):
+        assert a.names == b.names and a.zones == b.zones
+        ratio = np.asarray(a.mode) == MODE_RATIO
+        np.testing.assert_array_equal(a.node_power_uw[ratio],
+                                      b.node_power_uw[ratio])
+        np.testing.assert_array_equal(a.wl_power_uw[ratio],
+                                      b.wl_power_uw[ratio])
+        np.testing.assert_allclose(a.wl_power_uw[~ratio],
+                                   b.wl_power_uw[~ratio], rtol=1e-5,
+                                   atol=1e-3)
+        np.testing.assert_allclose(a.node_power_uw[~ratio],
+                                   b.node_power_uw[~ratio], rtol=1e-5)
+        assert a.wl_power_uw[~ratio].max() > 1e6  # watts, in µW
+    # the record counts the devices and the bytes of the one sent most:
+    # the same bytes a window, a quarter of them to each of four devices
+    n = counts4["windows"]
+    assert n == counts1["windows"] == TICKS + 3
+    assert (counts4["devices"], counts1["devices"]) == (4 * n, n)
+    assert counts4["h2d_bytes"] == counts1["h2d_bytes"]
+    assert counts1["h2d_bytes_max_device"] == counts1["h2d_bytes"]
+    assert counts4["h2d_bytes_max_device"] * 4 == counts4["h2d_bytes"]
+    assert gauge4 * 4 == gauge1 == counts1["h2d_bytes"] / n
+    # the H2D leg and the fetch leg say how many devices they spoke to
+    for spans, n_dev in ((spans4, 4), (spans1, 1)):
+        for name in ("window.h2d", "window.pipeline_wait"):
+            legs = [sp for sp in spans if sp["name"] == name]
+            assert len(legs) == n
+            assert all(sp["devices"] == n_dev for sp in legs)
+        assert all("devices" not in sp for sp in spans
+                   if sp["name"] == "window.history")
+    # the params were placed once, replicated, and kept
+    for placed, n_dev in ((placed4, 4), (placed1, 1)):
+        for leaf in jax.tree.leaves(placed[1]):
+            assert len(leaf.devices()) == n_dev
+
+
+def test_the_benchmarks_reader_reads_a_quarter_of_the_bytes_a_device():
+    """``h2d_max_device_mb.flood4`` as the benchmark reads it: its metric
+    file's reader and arguments over two ``/debug/window`` bodies of an
+    aggregator served over four devices, beside ``h2d_mb.flood``."""
+    from types import SimpleNamespace
+
+    from chipbench import spec
+
+    cell = spec.load_cell(REPO, "temporal-k8s-limit.flood")
+    s = Served(4)
+    try:
+        s.window(1)
+        s.window(2)
+        first = s.get("/debug/window")
+        for seq in range(3, 6):
+            s.window(seq)
+        last = s.get("/debug/window")
+    finally:
+        s.close()
+    assert last["counts"]["windows"] - first["counts"]["windows"] == 3
+    assert last["stats"]["last_h2d_device_bytes"] * 4 * 3 == \
+        last["counts"]["h2d_bytes"] - first["counts"]["h2d_bytes"]
+    this = SimpleNamespace(drive=SimpleNamespace(
+        debug={"first": first, "last": last}))
+    got = {}
+    for name in ("h2d_max_device_mb.flood4", "h2d_mb.flood"):
+        read, args = cell.reader(name)
+        got[name] = read(this, **args)
+    assert got["h2d_max_device_mb.flood4"] * 4 == pytest.approx(
+        got["h2d_mb.flood"])
+    assert got["h2d_mb.flood"] > 8 * 16 * 4 * 7 * 4 / 1e6  # the history
+    # a body of a program without the counter: the metric is left out
+    for body in (first, last):
+        del body["counts"]["h2d_bytes_max_device"]
+    read, args = cell.reader("h2d_max_device_mb.flood4")
+    assert read(this, **args) is None
+
+
+def test_a_node_silent_past_stale_after_leaves_the_results():
+    """What the four-chip cell's ``staleAfter`` leans on: a node whose
+    last report is older than ``aggregator.staleAfter`` when a window is
+    snapshotted is in none of that window's answers, and one heard from
+    within it stays however long the others have been silent."""
+    now = [1000.0]
+    s = Served(1, stale_after=15.0, clock=lambda: now[0])
+    try:
+        for seq in range(1, 3):
+            s.window(seq)
+            now[0] += 1.0
+        res = s.agg._drain_pipeline()
+        assert sorted(res.names) == [f"node-{k}" for k in range(NODES)]
+        # nodes 0-2 go silent; 14 s later they are still answered
+        now[0] += 13.0  # their last report is 14 s old
+        s.window(3, nodes=range(3, NODES))
+        res = s.agg._drain_pipeline()
+        assert len(res.names) == NODES
+        body = s.get("/v1/results")
+        assert len(body["nodes"]) == NODES
+        # 2 s more and they are past staleAfter: gone from the window,
+        # from /v1/results, and the model nodes' histories with them
+        now[0] += 2.0
+        s.window(4, nodes=range(3, NODES))
+        res = s.agg._drain_pipeline()
+        assert sorted(res.names) == [f"node-{k}" for k in range(3, NODES)]
+        body = s.get("/v1/results")
+        assert sorted(body["nodes"]) == [f"node-{k}"
+                                         for k in range(3, NODES)]
+        assert sorted(s.agg._history) == [f"node-{k}"
+                                          for k in range(3, NODES) if k % 2]
+    finally:
+        s.close()

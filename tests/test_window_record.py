@@ -20,7 +20,8 @@ import pytest
 from kepler_tpu import telemetry
 from kepler_tpu.fleet.aggregator import Aggregator
 from kepler_tpu.fleet.window_record import (COUNTS, FIELDS, LEGS, MARKS,
-                                            RECORDS_KEPT, WindowLedger,
+                                            RECORDS_KEPT, ROW_COUNTS,
+                                            SUM_COUNTS, WindowLedger,
                                             WindowRecord, records_json)
 from kepler_tpu.fleet.wire import encode_report
 from kepler_tpu.parallel.fleet import MODE_MODEL, MODE_RATIO, NodeReport
@@ -52,7 +53,7 @@ class Served:
     """A temporal aggregator behind a real HTTP server, driven by hand:
     reports in over POST, one ``aggregate_once`` a window."""
 
-    def __init__(self) -> None:
+    def __init__(self, **agg_kw) -> None:
         self.server = APIServer(listen_addresses=["127.0.0.1:0"])
         self.server.init()
         self.ctx = CancelContext()
@@ -61,7 +62,7 @@ class Served:
         time.sleep(0.05)
         self.agg = Aggregator(self.server, model_mode="temporal",
                               node_bucket=8, workload_bucket=16,
-                              history_window=4, pipeline_depth=2)
+                              history_window=4, pipeline_depth=2, **agg_kw)
         self.agg.init()
         self.seq = 0
 
@@ -175,9 +176,19 @@ def test_counts_and_ingest_only_grow_and_add_up_over_the_records(served):
     between = rows_of(last)[len(rows_of(first)):]
     assert len(between) == WINDOWS - 1
     assert set(last["counts"]) == {"windows", *COUNTS}
-    for key in last["counts"]:
+    grew = {k: last["counts"][k] - first["counts"][k] for k in COUNTS}
+    for key in ("windows", *ROW_COUNTS):
         assert last["counts"][key] - first["counts"][key] == sum(
             1 if key == "windows" else r[key] for r in between)
+    # what the put over the mesh counts is in the sums alone: every window
+    # ran over the mesh's devices, and the device that was sent most was
+    # sent its share of the window's bytes (the node bucket divides evenly)
+    import jax
+    n_dev = len(jax.devices())
+    assert SUM_COUNTS == ("devices", "h2d_bytes_max_device")
+    assert not set(SUM_COUNTS) & set(between[0])
+    assert grew["devices"] == n_dev * len(between)
+    assert grew["h2d_bytes_max_device"] * n_dev == grew["h2d_bytes"]
     row = between[0]
     assert row["rows_program"] == 8 * 16  # node bucket × workload bucket
     assert row["rows_work"] == 3  # node-m's pods
@@ -298,7 +309,8 @@ def test_the_ledger_keeps_the_last_records_kept_and_every_count():
     assert len(kept) == RECORDS_KEPT and kept[0].seq == 44
     assert counts == {"windows": RECORDS_KEPT + 44, "rows_work": 0,
                       "rows_program": 128 * (RECORDS_KEPT + 44),
-                      "h2d_bytes": 0}
+                      "h2d_bytes": 0, "devices": 0,
+                      "h2d_bytes_max_device": 0}
     table = json.loads(records_json(kept))
     assert len(table["rows"]) == RECORDS_KEPT and table["rows"][0][0] == 44
     assert kept[0].text is not None  # rendered once, then served as text
