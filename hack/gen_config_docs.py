@@ -190,14 +190,20 @@ DESCRIPTIONS = {
                                "replay, retries) are absorbed "
                                "idempotently; seq jumps beyond it count "
                                "as `kepler_fleet_windows_lost_total`.",
-    "aggregator.pipeline_depth": "Aggregator: in-flight fleet windows. "
-                                 "`1` = serial assemble→dispatch→fetch; "
-                                 "`2` (default) overlaps window N's "
-                                 "fetch/scatter behind window N+1's "
-                                 "assembly+dispatch — results are at "
-                                 "most `pipelineDepth−1` intervals "
-                                 "stale; shutdown drains in-flight "
-                                 "windows deterministically.",
+    "aggregator.pipeline_depth": "Aggregator: the bound on fleet windows "
+                                 "in flight (dispatched, not yet "
+                                 "published). `1` = serial "
+                                 "assemble→dispatch→fetch; `2` (default) "
+                                 "overlaps window N's program, fetch and "
+                                 "scatter with window N+1's "
+                                 "assembly+dispatch. A window is "
+                                 "published as soon as its program is "
+                                 "done, whatever the depth: a result is "
+                                 "as old as its own assembly and "
+                                 "program, not an interval; the loop "
+                                 "waits only where it would hold more "
+                                 "than this many. Shutdown drains "
+                                 "in-flight windows deterministically.",
     "aggregator.fused_window_k": "Aggregator: intervals batched into "
                                  "one fused device scan at rung 0's top "
                                  "tier. `1` (default) = unfused "

@@ -476,10 +476,13 @@ class AggregatorConfig:
     # retries are absorbed idempotently instead of double-ingesting
     dedup_window: int = 1024
     # -- window pipeline (docs/developer/observability.md) --
-    # in-flight fleet windows: 1 = serial assemble→dispatch→fetch; 2
-    # (the shipped default) overlaps window N's fetch/scatter behind
-    # window N+1's assembly+dispatch — published results are at most
-    # pipelineDepth−1 intervals stale, shutdown drains deterministically
+    # the bound on fleet windows in flight (dispatched, not published):
+    # 1 = serial assemble→dispatch→fetch; 2 (the shipped default)
+    # overlaps window N's program, fetch and scatter with window N+1's
+    # assembly+dispatch. The served loop publishes a window as soon as
+    # its program is done, so no depth makes a result an interval stale;
+    # the loop waits only where it would hold more than this many.
+    # Shutdown drains deterministically
     pipeline_depth: int = 2
     # fused window loop (rung 0's top tier): batch this many intervals'
     # delta rows host-side and run them as ONE donated lax.scan dispatch

@@ -14,11 +14,17 @@ The default window path is DEVICE-RESIDENT and PIPELINED
 (``kepler_tpu.fleet.window``): the padded packed-f16 batch lives on
 device, each window scatter-updates only the rows whose report changed
 (delta H2D through a donated in-place program), and with
-``pipeline_depth`` ≥ 2 the fetch/scatter of window N overlaps window
-N+1's host assembly and dispatch — steady-state cadence approaches
-max(assembly, device) instead of their sum, at the cost of results
-being at most ``pipeline_depth − 1`` intervals stale. Shutdown (and an
-emptied fleet) deterministically drains in-flight windows.
+``pipeline_depth`` ≥ 2 the program, fetch and scatter of window N overlap
+window N+1's host assembly and dispatch — steady-state cadence approaches
+max(assembly, device) instead of their sum. Under the served loop
+(``run``) a window is published when its program is done: a publisher
+thread waits for the outputs of the oldest window in flight while the
+loop sleeps out the interval or assembles the next window, so a result
+is as old as its own assembly and program, and ``pipeline_depth`` is
+only the bound on windows in flight. ``aggregate_once`` called without
+the loop publishes window N inside call N+1 (at most ``pipeline_depth −
+1`` calls behind). Shutdown (and an emptied fleet) deterministically
+drains in-flight windows.
 
 The serial einsum-f32 path — full assemble + one sharded dispatch + a
 multi-array fetch per window — is retained for ``accuracy_mode`` (the
@@ -282,6 +288,10 @@ class _Pending:
     zone_names: list | None = None
     feat_hist: object = None
     t_valid: object = None
+    # what the served loop's publisher thread caught while publishing
+    # this window: it stays at the head of the deque and the loop's next
+    # step (or a drain) raises it where a failed fetch was always raised
+    failure: Exception | None = None
 
 
 class _FetchWorker:
@@ -731,7 +741,11 @@ class Aggregator:
                        # (missing/mismatched base — agent resends full)
                        "keyframe_requests_total": 0,
                        "duplicates_total": 0, "windows_lost_total": 0,
-                       "attributions_total": 0, "last_batch_nodes": 0,
+                       "attributions_total": 0,
+                       # of those, the windows whose publication began
+                       # before a later window was snapshotted
+                       "published_early_total": 0,
+                       "last_batch_nodes": 0,
                        "last_batch_workloads": 0,
                        # whole-window cost (sum of the legs below — in
                        # pipelined mode wall time spans two calls, so the
@@ -804,17 +818,28 @@ class Aggregator:
         # -- window pipeline (fleet.window) --------------------------------
         # depth 1 = serial (dispatch then fetch in the same call, the
         # library-call contract every aggregate_once() test relies on);
-        # depth D ≥ 2 keeps D−1 windows in flight: the fetch/scatter of
-        # window N overlaps window N+1's assembly+dispatch, and published
-        # results are at most D−1 intervals stale. The deque normally
-        # belongs to the aggregation loop alone, but shutdown() may drain
-        # it from the lifecycle thread when the runner overruns its join
-        # timeout — _pipeline_lock serializes those drains (uncontended
-        # in steady state; never held during dispatch).
+        # depth D ≥ 2 keeps at most D−1 windows in flight when a step
+        # returns: the program, fetch and scatter of window N overlap
+        # window N+1's assembly+dispatch. Who publishes the oldest window
+        # is whoever holds _pipeline_lock: under run() the publisher
+        # thread, as soon as the window is dispatched (it waits for the
+        # outputs under the lock); the loop's own step, for what is still
+        # there when the deque reaches the depth; a drain (empty fleet,
+        # run() exit, shutdown() from the lifecycle thread when the
+        # runner overruns its join timeout). Never held during dispatch.
         self._pipeline_depth = max(1, int(pipeline_depth))
         self._bucket_shrink_after = max(1, int(bucket_shrink_after))
         self._pipeline_lock = threading.Lock()
         self._inflight: collections.deque[_Pending] = collections.deque()  # keplint: guarded-by=_pipeline_lock
+        # the served loop's early publisher (run() starts and stops it; a
+        # direct aggregate_once() caller has none): it sleeps on the
+        # condition until the loop appends a window, and runs for as long
+        # as it is the thread named here
+        self._pipeline_cond = threading.Condition(self._pipeline_lock)
+        self._publisher: threading.Thread | None = None  # keplint: guarded-by=_pipeline_lock
+        # windows the publisher published since the loop's last step: the
+        # loop counts them on the ladder, which only it may move
+        self._early_unacked = 0  # keplint: guarded-by=_pipeline_lock
         # rung-0 engine: ShardedWindowEngine on a multi-device 1-D node
         # mesh (per-shard rings, sticky assignment), PackedWindowEngine
         # otherwise; _engine_serial is the single-device demotion engine
@@ -1143,6 +1168,7 @@ class Aggregator:
         self._broadcast_membership(survivors, epoch)
 
     def run(self, ctx: CancelContext) -> None:
+        self._start_publisher()
         while not ctx.cancelled():
             # the wait is the first leg of the next window's record: its
             # span is laid on the record's marks in aggregate_once
@@ -1157,11 +1183,58 @@ class Aggregator:
             except Exception:
                 log.exception("fleet aggregation failed")
         # deterministic drain: every dispatched window is published before
-        # the loop exits — no result is abandoned in flight on shutdown
+        # the loop exits — no result is abandoned in flight on shutdown.
+        # The publisher finishes the window it is on and stops first, so
+        # what is left is published here, in order
+        self._stop_publisher()
         try:
             self._drain_pipeline()
         except Exception:
             log.exception("fleet pipeline drain failed")
+
+    def _start_publisher(self) -> None:
+        thread = threading.Thread(target=self._publish_early, daemon=True,
+                                  name="kepler-window-publish")
+        with self._pipeline_lock:
+            self._publisher = thread
+        thread.start()
+
+    def _stop_publisher(self) -> None:
+        with self._pipeline_lock:
+            thread, self._publisher = self._publisher, None
+            self._pipeline_cond.notify_all()
+        if thread is not None:
+            thread.join()
+
+    # keplint: thread-role=window-publisher
+    def _publish_early(self) -> None:
+        """The served loop's publisher thread: publish the oldest window
+        in flight as soon as there is one, instead of at the loop's next
+        step. The wait for the window's outputs is ``_publish``'s own
+        (``_fetch_device``: the blocking fetch runs on the fetch worker
+        with the interpreter lock released, under ``dispatchTimeout``),
+        so the loop sleeps out its interval and assembles the next window
+        meanwhile, and blocks only where it would append past the depth.
+        A failure is left on the window for the loop to raise: demoting,
+        resetting engines and recomputing are the loop's."""
+        with self._pipeline_lock:
+            while self._publisher is threading.current_thread():
+                if (not self._inflight
+                        or self._inflight[0].failure is not None):
+                    self._pipeline_cond.wait()
+                    continue
+                p = self._inflight[0]
+                # a cycle of its own, as the loop's wait is: the legs of
+                # the publication nest in it on this thread
+                with telemetry.span("aggregator.publish",
+                                    window=p.rec.seq):
+                    try:
+                        self._publish(p, on_loop=False)
+                    except Exception as err:
+                        p.failure = err
+                if p.failure is None:
+                    self._inflight.popleft()
+                    self._early_unacked += 1
 
     # keplint: thread-role=shutdown
     def shutdown(self) -> None:
@@ -2661,6 +2734,9 @@ class Aggregator:
         with self._pipeline_lock:
             abandoned = len(self._inflight)
             self._inflight.clear()
+            # published before the failure: no clean window of the rung
+            # the ladder is about to enter
+            self._early_unacked = 0
         # both packed engines re-seed: the failed rung's ring is poisoned
         # and the OTHER engine's buffers may alias handles a drained
         # window read — re-entering either rung starts from a full re-pack
@@ -2824,17 +2900,20 @@ class Aggregator:
 
     def aggregate_once(self) -> "FleetResults | None":
         """One pipeline step: dispatch this interval's window, publish the
-        oldest in-flight one.
+        oldest in-flight one if it is still there.
 
         At ``pipeline_depth`` 1 (the constructor default) the two halves
         run back-to-back — classic serial semantics, every call publishes
         the window it assembled. At depth D ≥ 2 the dispatched window
-        stays in flight while the PREVIOUS window is fetched, scattered,
-        and published: the device computes window N while the host
-        assembles N+1, and the blocking fetch (``window.pipeline_wait``)
-        only pays whatever the device hasn't already finished. Returns
-        the published :class:`FleetResults` (None when nothing published
-        yet — the pipeline is still filling).
+        stays in flight: the device computes window N while the host
+        assembles N+1. Called directly, call N+1 then fetches, scatters
+        and publishes window N, and the blocking fetch
+        (``window.pipeline_wait``) only pays whatever the device hasn't
+        already finished. Under ``run`` the publisher thread has usually
+        published window N by then, as soon as its program was done, and
+        the step finds the deque below the depth. Returns what THIS call
+        published (None when it published nothing: the pipeline is still
+        filling, or the publisher was there first).
 
         An empty fleet drains the pipeline instead of dispatching, so
         results never rot in flight when reports stop.
@@ -2935,7 +3014,11 @@ class Aggregator:
         # every demoted rung drains each window (no in-flight handle
         # outlives its own interval); only the healthy rung pipelines —
         # the legacy path included (temporal/accuracy modes pipeline at
-        # rung 0 exactly as before the ladder existed)
+        # rung 0 exactly as before the ladder existed). The depth is the
+        # bound on windows in flight: the step publishes (or, where the
+        # publisher holds the lock, waits for) the oldest until fewer
+        # than `depth` are left, and raises a failure the publisher left
+        # on the oldest whatever the depth
         depth = self._pipeline_depth if rung == RUNG_PIPELINED else 1
         with self._pipeline_lock:
             self._inflight.append(pending)
@@ -2946,11 +3029,30 @@ class Aggregator:
                     del self._cum_last_seen[name]
                     self._cum.pop(name)
             published = None
-            while len(self._inflight) >= depth:
-                published = self._publish(self._inflight.popleft())
-        if published is not None:
+            while self._inflight and (
+                    len(self._inflight) >= depth
+                    or self._inflight[0].failure is not None):
+                published = self._publish_oldest()
+            early, self._early_unacked = self._early_unacked, 0
+            if self._inflight:
+                self._pipeline_cond.notify()  # the publisher's turn
+        if early:
+            # the publisher leaves the engines to the thread that owns
+            # them: their snapshot follows here, one step behind
+            with self._results_lock:
+                self._engine_stats_locked()
+        for _ in range(early + (published is not None)):
             self._ladder_window_ok()
         return published
+
+    # keplint: requires-lock=_pipeline_lock
+    def _publish_oldest(self) -> "FleetResults":
+        """Publish the oldest window in flight, or raise what the
+        publisher thread caught on it; either way it leaves the deque."""
+        p = self._inflight.popleft()
+        if p.failure is not None:
+            raise p.failure
+        return self._publish(p)
 
     def _use_packed(self) -> bool:
         """Packed-f16 resident path is the default; the serial einsum-f32
@@ -2983,7 +3085,7 @@ class Aggregator:
         with self._pipeline_lock:
             while self._inflight:
                 try:
-                    published = self._publish(self._inflight.popleft())
+                    published = self._publish_oldest()
                 except Exception as err:
                     # a drain has no current window to recompute (empty
                     # fleet or shutdown) — abandon what's left, demote,
@@ -3406,16 +3508,21 @@ class Aggregator:
     # -- publish half -------------------------------------------------------
 
     # keplint: requires-lock=_pipeline_lock
-    def _publish(self, p: _Pending) -> "FleetResults":
+    def _publish(self, p: _Pending, on_loop: bool = True) -> "FleetResults":
         """Fetch one in-flight window (the pipeline's only blocking point),
         scatter it into a :class:`FleetResults`, publish, account legs.
-        Holding the pipeline lock keeps a lifecycle-thread drain from
-        interleaving publishes (out-of-order ``_results``) with the
-        aggregation loop's own."""
+        Holding the pipeline lock keeps the publisher thread, the loop's
+        own step and a lifecycle-thread drain from interleaving publishes
+        (out-of-order ``_results``). ``on_loop`` is False on the publisher
+        thread, which reads no engine state: the loop is planning the
+        next window on the engines meanwhile."""
         rec = p.rec
         seq = rec.seq
         rec.kind = p.kind
         rec.publish_begin = _time.monotonic()
+        # no later window has taken its sequence number: this one did not
+        # wait for the loop to come round again
+        rec.published_early = int(self._window_seq == seq + 1)
         fetch_ms = 0.0
         if p.kind == "packed":
             # the engine's plan may override the fetch (per-shard
@@ -3472,6 +3579,7 @@ class Aggregator:
             self._results = results
             self._last_window_at = p.now
             self._stats["attributions_total"] += 1
+            self._stats["published_early_total"] += rec.published_early
             self._stats["last_batch_nodes"] = len(results.names)
             self._stats["last_batch_workloads"] = int(n_workloads)
             self._stats["last_assembly_ms"] = assembly_ms
@@ -3489,39 +3597,17 @@ class Aggregator:
             if p.sync_per_window_ms >= 0.0:
                 self._stats["last_sync_per_window_ms"] = (
                     p.sync_per_window_ms)
-            # the engines' program caches count their own compiles; the
-            # serial path's one program is counted at its cold dispatch
-            self._stats["window_compiles_total"] = (
-                self._legacy_compiles + sum(
-                    e.compile_count for e in (
-                        self._engine, self._engine_serial,
-                        self._engine_fused) if e is not None))
-            # per-window engine introspection snapshot: computed HERE
-            # (the only thread that owns engine state) so /debug/window
-            # and collect() read a coherent copy off-thread without
-            # touching live engine internals
-            engines: dict[str, dict] = {}
-            for label, eng in (("pipelined", self._engine),
-                               ("serial", self._engine_serial),
-                               ("fused", self._engine_fused)):
-                if eng is not None:
-                    engines[label] = eng.introspect()
-            primary = _primary_introspect(engines)
-            skew = 0.0
-            if primary is not None:
-                occupied = [s["rows"] for s in primary["shards"]]
-                if any(occupied):
-                    skew = max(occupied) / (sum(occupied) / len(occupied))
-            self._stats["shard_skew"] = round(skew, 4)
-            self._introspect_cache = engines
+            if on_loop:
+                self._engine_stats_locked()
             # the record is complete once the results are stored (they
             # are visible when this lock is released, a moment later)
             rec.published = _time.monotonic()
             self._window_ledger.add(rec)
         # the two legs no with-block covers: how long the dispatched
-        # window waited for its publication (at pipelineDepth 2 the
-        # interval and the next window's assembly), and the lock section
-        # that made the results visible
+        # window waited for its publication to begin (under run() until
+        # the publisher thread has the lock; called directly at depth 2,
+        # until the next call has dispatched), and the lock section that
+        # made the results visible
         telemetry.mark_span("window.queued", rec.dispatched,
                             rec.publish_begin, window=seq)
         telemetry.mark_span("window.publish", rec.scattered, rec.published,
@@ -3537,6 +3623,34 @@ class Aggregator:
             except OSError as err:
                 log.warning("training dump failed: %s", err)
         return results
+
+    # keplint: requires-lock=_results_lock
+    def _engine_stats_locked(self) -> None:
+        """The engines' compile count and introspection snapshot, taken
+        on the aggregation loop (the only thread that owns engine state)
+        so /debug/window and collect() read a coherent copy off-thread
+        without touching live engine internals."""
+        # the engines' program caches count their own compiles; the
+        # serial path's one program is counted at its cold dispatch
+        self._stats["window_compiles_total"] = (
+            self._legacy_compiles + sum(
+                e.compile_count for e in (
+                    self._engine, self._engine_serial,
+                    self._engine_fused) if e is not None))
+        engines: dict[str, dict] = {}
+        for label, eng in (("pipelined", self._engine),
+                           ("serial", self._engine_serial),
+                           ("fused", self._engine_fused)):
+            if eng is not None:
+                engines[label] = eng.introspect()
+        primary = _primary_introspect(engines)
+        skew = 0.0
+        if primary is not None:
+            occupied = [s["rows"] for s in primary["shards"]]
+            if any(occupied):
+                skew = max(occupied) / (sum(occupied) / len(occupied))
+        self._stats["shard_skew"] = round(skew, 4)
+        self._introspect_cache = engines
 
     def _scatter_packed(self, p: _Pending,
                         packed: np.ndarray) -> "FleetResults":
@@ -4198,6 +4312,13 @@ class Aggregator:
             "kepler_fleet_attributions_total", "Completed fleet attributions")
         total.add_metric([], stats["attributions_total"])
         yield total
+        early = CounterMetricFamily(
+            "kepler_fleet_windows_published_early_total",
+            "Fleet windows whose publication began before the loop "
+            "snapshotted a later window (under the served loop: as soon "
+            "as their program was done)")
+        early.add_metric([], stats["published_early_total"])
+        yield early
         reports = CounterMetricFamily(
             "kepler_fleet_reports_total", "Node reports received")
         reports.add_metric([], stats["reports_total"])

@@ -4,8 +4,9 @@ A window gets its record (and its sequence number) at its snapshot; the
 record travels on the window's ``_Pending`` from dispatch to publication
 and is complete when ``_publish`` has stored the results. With
 ``pipelineDepth`` 2 a window is dispatched in one ``aggregate_once`` call
-and published in the next, so a record — not a telemetry cycle — is what
-follows one window.
+and published by the served loop's publisher thread when its program is
+done (in the next call, where ``aggregate_once`` is called directly), so a
+record — not a telemetry cycle — is what follows one window.
 
 One clock: a leg of the serial (einsum-f32 / temporal) path ends on a mark
 of the record, the ``last_*_ms`` gauges are differences of the marks, and
@@ -64,7 +65,7 @@ LEGS = {
 # ROW_COUNTS are columns of a served row too; what the put over the mesh
 # counts is served in the sums alone (``chipbench`` pins a row's columns)
 ROW_COUNTS = ("rows_program", "rows_work", "h2d_bytes")
-SUM_COUNTS = ("devices", "h2d_bytes_max_device")
+SUM_COUNTS = ("devices", "h2d_bytes_max_device", "published_early")
 COUNTS = ROW_COUNTS + SUM_COUNTS
 
 FIELDS = ("seq", "stamp", "kind") + MARKS + ("assembly_cpu_s",) \
@@ -80,7 +81,8 @@ RECORDS_KEPT = 2048
 
 class WindowRecord:
     """The marks and counts of one window. Written by the aggregation loop
-    alone until it is complete; read-only from then on."""
+    until the window is dispatched, then by the one thread that publishes
+    it (under the aggregator's pipeline lock); read-only once complete."""
 
     __slots__ = FIELDS + SUM_COUNTS + ("base", "cpu_begin_ns", "text")
 
@@ -111,6 +113,9 @@ class WindowRecord:
         self.h2d_bytes = 0
         self.devices = 0  # the devices the window's program ran over
         self.h2d_bytes_max_device = 0  # of h2d_bytes, the most one was sent
+        # 1 where the publication began before a later window took its
+        # sequence number: the window did not wait for the loop's next step
+        self.published_early = 0
         self.compiled = False
 
     @contextlib.contextmanager

@@ -14,15 +14,30 @@ Correctness contracts of `kepler_tpu.fleet.window` + the pipelined
 * donated-buffer reuse never aliases a window still being read (the
   churn stress would corrupt the bit-exact comparison if it did);
 * bucket ladders grow geometrically and shrink only after the
-  hysteresis window; delta-H2D row accounting matches what changed.
+  hysteresis window; delta-H2D row accounting matches what changed;
+* under the served loop (``run``) a window is published when its program
+  is done, not when the loop next comes round (ISSUE 32): in dispatch
+  order, once each, bit-equal to the serial cycle, never more than
+  ``pipeline_depth`` in flight, a failure raised by the loop's next step.
+  Those tests drive the loop's interval by hand (``TickGate``) and wait
+  on conditions with deadlines: no time a CPU run yields is asserted.
 """
 
 from __future__ import annotations
 
+import collections
+import json
+import threading
+import time
+
 import numpy as np
 import pytest
 
-from kepler_tpu.fleet.aggregator import Aggregator, _Stored
+from kepler_tpu import fault
+from kepler_tpu.fault import FaultPlan, FaultSpec
+from kepler_tpu.fleet.aggregator import (RUNG_PACKED_SERIAL, RUNG_PIPELINED,
+                                         Aggregator, _Stored)
+from kepler_tpu.fleet.window_record import LEGS, MARKS
 from kepler_tpu.fleet.window import BucketLadder
 from kepler_tpu.parallel.fleet import MODE_MODEL, MODE_RATIO, NodeReport
 from kepler_tpu.parallel.mesh import make_mesh
@@ -552,3 +567,331 @@ class TestShardedWindow:
         agg.aggregate_once()
         assert agg._stats["window_compiles_total"] > compiles
         agg.shutdown()
+
+
+# -- publication on completion (ISSUE 32) -----------------------------------
+
+DEADLINE = 120.0  # generous: the suite runs under six workers
+KINDS = {"packed": {"model_mode": "mlp"},
+         "legacy": {"model_mode": "mlp", "accuracy_mode": True},
+         "temporal": {"model_mode": "temporal", "history_window": 4}}
+
+
+def wait_until(pred, what: str) -> None:
+    deadline = time.monotonic() + DEADLINE
+    while not pred():
+        assert time.monotonic() < deadline, f"timed out waiting: {what}"
+        time.sleep(0.005)
+
+
+class TickGate:
+    """In the place of the loop's ``CancelContext``: its interval ends when
+    the test says so, and ``tick`` returns once the loop has done the step
+    and is back in its wait."""
+
+    def __init__(self) -> None:
+        self._cond = threading.Condition()
+        self._ticks = 0
+        self._cancelled = False
+        self.arrivals = 0  # times the loop began a wait
+
+    def cancelled(self) -> bool:
+        return self._cancelled
+
+    def cancel(self) -> None:
+        with self._cond:
+            self._cancelled = True
+            self._cond.notify_all()
+
+    def wait(self, timeout: float | None = None) -> bool:
+        with self._cond:
+            self.arrivals += 1
+            self._cond.notify_all()
+            self._cond.wait_for(lambda: self._ticks or self._cancelled,
+                                timeout)
+            if self._cancelled:
+                return True
+            self._ticks = max(0, self._ticks - 1)
+            return False
+
+    def tick(self) -> None:
+        with self._cond:
+            seen = self.arrivals
+            self._ticks += 1
+            self._cond.notify_all()
+            assert self._cond.wait_for(lambda: self.arrivals > seen,
+                                       DEADLINE), "the step did not end"
+
+
+class ServedLoop:
+    """``Aggregator.run`` on a thread of its own behind a ``TickGate``, the
+    interval itself far beyond any deadline here: whatever is published
+    was published without the loop coming round for it. Every publication
+    is kept, with the thread that made it."""
+
+    def __init__(self, depth: int = 2, **kw) -> None:
+        self.agg = make_agg(depth, interval=3600.0, **kw)
+        self.gate = TickGate()
+        self.published: list = []  # (FleetResults, thread name)
+        inner = self.agg._publish
+
+        def publish(p, on_loop=True):
+            results = inner(p, on_loop=on_loop)
+            self.published.append((results,
+                                   threading.current_thread().name))
+            return results
+
+        self.agg._publish = publish
+        self.thread = threading.Thread(target=self.agg.run,
+                                       args=(self.gate,), daemon=True)
+        self.thread.start()
+        wait_until(lambda: self.gate.arrivals == 1, "the loop's first wait")
+
+    def step(self, sched: dict) -> None:
+        """One interval: the fleet reports, the loop takes its step."""
+        self.agg.test_clock[0] += 5.0
+        seed_window(self.agg, sched, self.agg.test_clock[0])
+        self.gate.tick()
+
+    def attributions(self) -> int:
+        with self.agg._results_lock:
+            return self.agg._stats["attributions_total"]
+
+    def stop(self) -> None:
+        self.gate.cancel()
+        self.thread.join(timeout=DEADLINE)
+        assert not self.thread.is_alive()
+        self.agg.shutdown()
+
+
+class _Request:
+    def __init__(self, path: str) -> None:
+        self.path = path
+
+
+class TestPublishedOnCompletion:
+    @pytest.mark.parametrize("kind", ["packed", "temporal"])
+    def test_first_window_is_served_before_the_second_tick(self, kind):
+        loop = ServedLoop(2, **KINDS[kind])
+        try:
+            loop.step(churn_schedule(1)[0])
+            # the loop is back in its wait and no second tick ever comes
+            wait_until(lambda: loop.attributions() == 1,
+                       "the first window's publication")
+            assert loop.agg._window_seq == 1 and loop.gate.arrivals == 2
+            assert not loop.agg._inflight
+            status, _hdr, body = loop.agg._handle_results(
+                _Request("/v1/results?node=n00"))
+            assert status == 200
+            assert json.loads(body)["timestamp"] == loop.agg.test_clock[0]
+            (_res, thread), = loop.published
+            assert thread == "kepler-window-publish"
+            families = {f.name: f for f in loop.agg.collect()}
+            early = families["kepler_fleet_windows_published_early"]
+            assert early.samples[0].value == 1
+        finally:
+            loop.stop()
+
+    @pytest.mark.parametrize("kind", sorted(KINDS))
+    def test_in_dispatch_order_and_bit_equal_to_the_serial_cycle(self, kind):
+        schedules = churn_schedule(9)
+        serial = run_schedule(make_agg(1, **KINDS[kind]), schedules)
+        loop = ServedLoop(2, **KINDS[kind])
+        try:
+            for k, sched in enumerate(schedules):
+                loop.step(sched)
+                # a step returns with fewer than `depth` windows in flight
+                assert loop.attributions() >= k
+        finally:
+            loop.stop()
+        served = [res for res, _thread in loop.published]
+        assert len(served) == len(serial) == len(schedules)
+        stamps = [res.timestamp for res in served]
+        assert stamps == sorted(set(stamps))  # in order, each once
+        for a, b in zip(serial, served):
+            assert a.timestamp == b.timestamp
+            assert_windows_equal(a, b)
+        assert loop.agg._stats["attributions_total"] == len(schedules)
+        assert loop.agg._rung == RUNG_PIPELINED
+        assert loop.agg._stats["window_demotions_total"] == 0
+
+    def test_stressed_loop_publisher_and_readers_lose_no_window(self):
+        """The loop, the publisher and four readers of what they publish,
+        handed the interpreter every 10 µs: 30 windows back to back come
+        out once each, in order, bit-equal to the serial cycle (a lost or
+        doubled publication would break the cumulative joules)."""
+        import sys
+
+        schedules = churn_schedule(30)
+        serial = run_schedule(make_agg(1), schedules)
+        loop = ServedLoop(2)
+        done = threading.Event()
+        errors: list = []
+
+        def reader() -> None:
+            try:
+                while not done.is_set():
+                    loop.agg._handle_results(_Request("/v1/results"))
+                    loop.agg._handle_window_debug(None)
+                    list(loop.agg.collect())
+            except Exception as err:  # relayed to the test's thread
+                errors.append(err)
+
+        readers = [threading.Thread(target=reader, daemon=True)
+                   for _ in range(4)]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in readers:
+                t.start()
+            for sched in schedules:
+                loop.step(sched)
+            loop.stop()
+        finally:
+            sys.setswitchinterval(interval)
+            done.set()
+            for t in readers:
+                t.join(timeout=DEADLINE)
+            loop.stop()
+        assert not errors and not any(t.is_alive() for t in readers)
+        served = [res for res, _thread in loop.published]
+        assert [r.timestamp for r in served] == [r.timestamp for r in serial]
+        for a, b in zip(serial, served):
+            assert_windows_equal(a, b)
+        assert loop.agg._stats["attributions_total"] == len(schedules)
+        assert loop.agg._stats["window_demotions_total"] == 0
+        assert not loop.agg._inflight
+
+    @pytest.mark.parametrize("depth", [2, 3])
+    def test_never_more_than_depth_windows_in_flight(self, depth):
+        """Every fetch hangs 0.2 s and the ticks come as fast as the loop
+        takes them: an unbounded loop would run five windows ahead."""
+
+        class Watched(collections.deque):
+            peak = 0
+
+            def append(self, item):
+                super().append(item)
+                self.peak = max(self.peak, len(self))
+
+        loop = ServedLoop(depth, dispatch_timeout=DEADLINE)
+        loop.agg._inflight = Watched()
+        plan = FaultPlan([FaultSpec(site="device.stall", arg=0.2)])
+        try:
+            with fault.installed(plan):
+                for sched in churn_schedule(6):
+                    loop.step(sched)
+                    assert len(loop.agg._inflight) < depth
+                loop.stop()
+        finally:
+            loop.stop()
+        assert loop.agg._inflight.peak <= depth
+        assert plan.fired("device.stall") == 6
+        stamps = [res.timestamp for res, _thread in loop.published]
+        assert len(stamps) == 6 and stamps == sorted(set(stamps))
+        assert loop.agg._stats["window_demotions_total"] == 0
+
+    @pytest.mark.parametrize("how", ["stall", "error"])
+    def test_a_failed_early_fetch_is_raised_by_the_next_step(self, how):
+        """The publisher leaves the failure on the window; the loop's next
+        step demotes one rung and publishes its own window there, once.
+        The failed window is abandoned with the ring, as a failed fetch
+        always abandoned it."""
+        loop = ServedLoop(2, dispatch_timeout=0.2 if how == "stall"
+                          else DEADLINE)
+        plan = FaultPlan([FaultSpec(site="device.stall", count=1, arg=2.0)]
+                         if how == "stall" else [])
+        if how == "error":
+            inner, calls = loop.agg._fetch_device, []
+
+            def fetch_device(fn):
+                calls.append(1)
+                if len(calls) == 1:
+                    raise RuntimeError("the device is gone")
+                return inner(fn)
+
+            loop.agg._fetch_device = fetch_device
+        schedules = churn_schedule(3)
+        try:
+            with fault.installed(plan):
+                loop.step(schedules[0])
+
+                def failed() -> bool:
+                    with loop.agg._pipeline_lock:
+                        return bool(loop.agg._inflight) and \
+                            loop.agg._inflight[0].failure is not None
+
+                wait_until(failed, "the publisher's failure")
+                # the publisher neither publishes nor demotes
+                assert loop.attributions() == 0
+                assert loop.agg._stats["window_demotions_total"] == 0
+                loop.step(schedules[1])
+                assert loop.attributions() == 1
+                assert loop.agg._rung == RUNG_PACKED_SERIAL
+                assert loop.agg._demotions_by_reason == {
+                    "stall" if how == "stall" else "runtime_error": 1}
+                loop.step(schedules[2])
+        finally:
+            loop.stop()
+        stamps = [res.timestamp for res, _thread in loop.published]
+        base = loop.agg.test_clock[0] - 15.0
+        assert stamps == [base + 10.0, base + 15.0]  # each once, in order
+        assert not loop.agg._inflight
+        assert loop.agg._stats["window_demotions_total"] == 1
+
+    def test_cancel_during_a_publication_drains_every_window(self):
+        loop = ServedLoop(2, dispatch_timeout=DEADLINE)
+        plan = FaultPlan([FaultSpec(site="device.stall", arg=0.3)])
+        try:
+            with fault.installed(plan):
+                schedules = churn_schedule(3)
+                loop.step(schedules[0])
+                loop.step(schedules[1])
+                loop.step(schedules[2])
+                # the third window's fetch is under way (or about to be)
+                wait_until(lambda: plan.fired("device.stall") >= 2,
+                           "a publication in progress")
+                loop.stop()
+        finally:
+            loop.stop()
+        assert not loop.agg._inflight
+        assert loop.agg._window_seq == 3
+        assert loop.agg._stats["attributions_total"] == 3
+        stamps = [res.timestamp for res, _thread in loop.published]
+        assert stamps == sorted(set(stamps)) and len(stamps) == 3
+
+    def test_the_record_is_ordered_and_counts_the_early_publication(self):
+        loop = ServedLoop(2, **KINDS["temporal"])
+        try:
+            for k, sched in enumerate(churn_schedule(3)):
+                loop.step(sched)
+                # as where the interval outlasts the program: the next
+                # tick finds the window published
+                wait_until(lambda: loop.attributions() == k + 1,
+                           "the window's publication")
+            body = json.loads(loop.agg._handle_window_debug(None)[2])
+        finally:
+            loop.stop()
+        assert body["counts"]["windows"] == 3
+        assert body["counts"]["published_early"] == 3
+        fields = body["records"]["fields"]
+        rows = [dict(zip(fields, row)) for row in body["records"]["rows"]]
+        assert [r["seq"] for r in rows] == [0, 1, 2]
+        for row in rows:
+            marks = [row[m] for m in MARKS[1:]]  # no tick under the gate
+            assert None not in marks and marks == sorted(marks)
+            a, b = LEGS["window.queued"]
+            assert row[b] - row[a] < loop.agg._interval
+
+    def test_called_directly_only_the_serial_depth_publishes_early(self):
+        """``published_early`` follows what happened, not a setting: at
+        depth 1 a window is published by its own call; at depth 2, with no
+        loop and so no publisher, by the next call or by a drain."""
+        for depth, want in ((1, 3), (2, 1)):
+            agg = make_agg(depth)
+            run_schedule(agg, churn_schedule(3))
+            assert agg._stats["attributions_total"] == 3
+            assert agg._stats["published_early_total"] == want
+            assert agg._window_ledger.counts["published_early"] == want
+            assert agg._publisher is None
+            agg.shutdown()

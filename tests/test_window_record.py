@@ -185,8 +185,12 @@ def test_counts_and_ingest_only_grow_and_add_up_over_the_records(served):
     # sent its share of the window's bytes (the node bucket divides evenly)
     import jax
     n_dev = len(jax.devices())
-    assert SUM_COUNTS == ("devices", "h2d_bytes_max_device")
+    assert SUM_COUNTS == ("devices", "h2d_bytes_max_device",
+                          "published_early")
     assert not set(SUM_COUNTS) & set(between[0])
+    # called directly at depth 2, a window is published by the next call:
+    # only the drained one was published before a later one was snapshotted
+    assert grew["published_early"] == 1
     assert grew["devices"] == n_dev * len(between)
     assert grew["h2d_bytes_max_device"] * n_dev == grew["h2d_bytes"]
     row = between[0]
@@ -310,7 +314,7 @@ def test_the_ledger_keeps_the_last_records_kept_and_every_count():
     assert counts == {"windows": RECORDS_KEPT + 44, "rows_work": 0,
                       "rows_program": 128 * (RECORDS_KEPT + 44),
                       "h2d_bytes": 0, "devices": 0,
-                      "h2d_bytes_max_device": 0}
+                      "h2d_bytes_max_device": 0, "published_early": 0}
     table = json.loads(records_json(kept))
     assert len(table["rows"]) == RECORDS_KEPT and table["rows"][0][0] == 44
     assert kept[0].text is not None  # rendered once, then served as text
