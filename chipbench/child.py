@@ -22,7 +22,11 @@ LAUNCHER = os.path.join(REPO, "chipbench", "launch.py")
 
 
 class BenchFailure(Exception):
-    """One-line reason a run produces no result."""
+    """One-line reason a run produces no result. ``phase`` is the part of
+    the run it was raised in (``ready``, ``fill``, ``warmup``, ``window``
+    or ``close``), where the raiser knows."""
+
+    phase: str | None = None
 
 
 def free_port() -> int:

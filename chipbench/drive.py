@@ -12,6 +12,7 @@ aggregator's own gauges and a few seeded nodes' answers for the check.
 
 from __future__ import annotations
 
+import http.client
 import json
 import threading
 import time
@@ -26,6 +27,8 @@ GAUGES = ("last_assembly_ms", "last_dispatch_ms", "last_wait_ms",
           "last_fetch_ms", "last_scatter_ms")
 SAMPLE_NODES = 6  # answers kept from every window, half of them model nodes
 WAIT_S = 60.0  # how long an answer may take before it counts as missing
+THROTTLE_WAIT_S = (0.05, 5.0)  # a 429's retry_after is kept within these
+THROTTLE_GIVE_UP_S = 30.0  # a batch throttled for longer fails the run
 
 
 @dataclass
@@ -35,6 +38,8 @@ class Round:
     batches: list = field(default_factory=list)  # [(nodes, start, end)]
     acked: int = 0
     keyframes: int = 0
+    throttled: int = 0  # reports answered 429, each time one was
+    throttle_wait_s: float = 0.0
 
     @property
     def start(self) -> float:
@@ -119,39 +124,91 @@ class Poller(threading.Thread):
 
     def stop(self) -> None:
         self._stop_flag.set()
-        self.join(timeout=30)
+        if self.ident is not None:  # it was started
+            self.join(timeout=30)
+
+
+def _seconds(raw) -> float:
+    """A ``retry_after`` as the aggregator sent it → seconds, 0 where it
+    is no number (the clamp then gives the least wait)."""
+    try:
+        return float(raw)
+    except (TypeError, ValueError):
+        return 0.0
+
+
+def _json_object(body: bytes) -> dict:
+    try:
+        got = json.loads(body)
+    except ValueError:
+        return {}
+    return got if isinstance(got, dict) else {}
 
 
 def post_round(child: AggregatorChild, fleet: Fleet, rnd: Round,
-               bodies: list, state) -> None:
-    """POST the round's batches; a 409 needs-keyframe is answered with the
-    keyframe, as an agent would. Anything else but 204 fails the run: the
-    traffic is chosen so that no operation fails."""
+               bodies: list, state, clock=time) -> None:
+    """POST the round's batches and answer as an agent would: a 409
+    needs-keyframe with the keyframe, a 429 (of one row, or of the whole
+    POST) by waiting out its ``retry_after`` and sending the same record
+    again. A throttle is a stall on the clock, not a failed operation: the
+    batch ends with its last resend. Anything else but 204 fails the run,
+    and so does a batch still throttled after ``THROTTLE_GIVE_UP_S``: the
+    traffic is chosen so that no operation fails. ``clock`` is for the
+    tests: ``time()`` and ``sleep()``."""
+    from kepler_tpu.fleet.wire import decode_report_batch, encode_report_batch
+
     for nodes, body in bodies:
-        t0 = time.time()
+        t0 = clock.time()
         pending, payload = nodes, body
-        for _attempt in range(3):
+        asked, throttled_at = 0, None
+        while pending:
             status, resp = child.request("POST", "/v1/reports", payload)
-            if status != 200:
+            if status == 429:  # admission shed the POST whole
+                rows = [{"status": 429, **_json_object(resp)}] * len(pending)
+            elif status == 200:
+                rows = json.loads(resp)["results"]
+            else:
                 raise BenchFailure(f"POST /v1/reports -> {status} "
                                    f"{resp[:120]!r}")
-            resend = []
-            for i, row in zip(pending, json.loads(resp)["results"]):
+            keyframes, again, hint = [], [], 0.0
+            for k, (i, row) in enumerate(zip(pending, rows)):
                 if row["status"] == 204:
                     rnd.acked += 1
                 elif row["status"] == 409 and row.get("needs_keyframe"):
-                    resend.append(i)
+                    keyframes.append(i)
+                elif row["status"] == 429:
+                    again.append(k)
+                    hint = max(hint, _seconds(row.get("retry_after")))
                 else:
                     raise BenchFailure(
                         f"report of {fleet.names[i]} -> {row}")
-            if not resend:
+            if not (keyframes or again):
                 break
-            rnd.keyframes += len(resend)
-            pending = resend
-            payload = fleet.keyframe_batch(resend, state, time.time())
-        else:
-            raise BenchFailure("the aggregator kept asking for keyframes")
-        rnd.batches.append((nodes, t0, time.time()))
+            records = []
+            if again:
+                now = clock.time()
+                throttled_at = now if throttled_at is None else throttled_at
+                if now - throttled_at > THROTTLE_GIVE_UP_S:
+                    raise BenchFailure(
+                        f"{len(again)} report(s) still throttled (429) "
+                        f"after {now - throttled_at:.0f}s")
+                wait = min(max(hint, THROTTLE_WAIT_S[0]), THROTTLE_WAIT_S[1])
+                rnd.throttled += len(again)
+                rnd.throttle_wait_s += wait
+                clock.sleep(wait)
+                sent = decode_report_batch(payload)
+                records = [sent[k] for k in again]
+            if keyframes:
+                asked += 1
+                if asked >= 3:
+                    raise BenchFailure(
+                        "the aggregator kept asking for keyframes")
+                rnd.keyframes += len(keyframes)
+                records += decode_report_batch(fleet.keyframe_batch(
+                    keyframes, state, clock.time()))
+            pending = [pending[k] for k in again] + keyframes
+            payload = encode_report_batch(records)
+        rnd.batches.append((nodes, t0, clock.time()))
 
 
 @dataclass
@@ -239,11 +296,13 @@ def run_window(child: AggregatorChild, fleet: Fleet, traffic: dict,
     # as ingest takes them, then warm-up rounds in closed loop until the
     # pipeline has published windows of the full fleet
     out.setup_parts["ready_s"] = time.time() - t_start
-    for _ in range(history):
-        poster.post(time.time())
-    out.setup_parts["fill_s"] = time.time() - t_start
-    poller.start()
+    phase = "fill"
     try:
+        for _ in range(history):
+            poster.post(time.time())
+        out.setup_parts["fill_s"] = time.time() - t_start
+        poller.start()
+        phase = "warmup"
         for _ in range(int(traffic.get("warmup_rounds", 3))):
             rnd = poster.post(time.time())
             poller.wait_stamp_after(rnd.end, WAIT_S + 20 * interval)
@@ -251,6 +310,7 @@ def run_window(child: AggregatorChild, fleet: Fleet, traffic: dict,
         if traced:
             out.trace_marks["start"] = child.trace("start")
 
+        phase = "window"
         out.count_from = time.time()
         out.published_open = published_total(child)
         t0 = time.time() + 0.05
@@ -276,6 +336,7 @@ def run_window(child: AggregatorChild, fleet: Fleet, traffic: dict,
         else:
             raise BenchFailure(f"traffic loop {traffic['loop']!r}: "
                                "open or closed")
+        phase = "close"
         out.t_close = t1
         out.published_close = published_total(child)
         time.sleep(0.05)  # the poller sees what was published before that
@@ -297,9 +358,18 @@ def run_window(child: AggregatorChild, fleet: Fleet, traffic: dict,
             out.final_error = str(err)  # the check counts it
         out.debug = {"first": out.debug,
                      "last": child.get_json("/debug/window")}
+        poller.stop()
+        if poller.error is not None:
+            raise BenchFailure(f"poller: {poller.error!r}")
+    except BenchFailure as err:
+        err.phase = err.phase or phase  # the FAIL line names it
+        raise
+    except (OSError, http.client.HTTPException) as err:
+        # a request that timed out or was cut: a failed run, by its name
+        fail = BenchFailure(f"{type(err).__name__}: {err}")
+        fail.phase = phase
+        raise fail from err
     finally:
         poller.stop()
-    if poller.error is not None:
-        raise BenchFailure(f"poller: {poller.error!r}")
     out.windows = poller.windows  # warm-up's too: the readers cut by time
     return out

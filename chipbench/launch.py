@@ -18,7 +18,9 @@ a benchmark, and changes nothing of how the aggregator serves:
 - on exit one JSON file: platform, device kind and count, that memory, the
   compiles, and — traced runs — the device
   planes of the profiler's trace as plain lists of ``[name, start_ns,
-  duration_ns]`` (``trace.py`` reduces them; the parent never loads JAX).
+  duration_ns]`` (``trace.py`` reduces them; the parent never loads JAX)
+  with the wall time of the trace's zero (``profile_start_time``, Unix ns),
+  which lays them on the aggregator's window records (``records.py``).
 
     python chipbench/launch.py --config.file <yaml> --out <json>
                                [--trace-dir <dir>]
@@ -37,21 +39,34 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CLOCK_STATS = ("profile_start_time", "profile_stop_time")
 
 
-def _device_planes(trace_dir: str) -> list[dict]:
-    """Every device plane of the newest ``.xplane.pb`` under ``trace_dir``
-    → [{"plane", "lines": [{"line", "events": [[name, start_ns,
-    dur_ns]]}]}]. Host planes are left out: they are large, and what the
-    host was doing comes from the aggregator's own gauges."""
+def _device_planes(trace_dir: str) -> dict:
+    """The newest ``.xplane.pb`` under ``trace_dir``, reduced
+    (``_reduce``); no planes where there is none."""
     from jax.profiler import ProfileData
 
     paths = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
     if not paths:
-        return []
-    planes = []
-    for plane in ProfileData.from_file(paths[-1]).planes:
+        return {"planes": []}
+    return _reduce(ProfileData.from_file(paths[-1]).planes)
+
+
+def _reduce(planes) -> dict:
+    """Every device plane → {"planes": [{"plane", "lines": [{"line",
+    "events": [[name, start_ns, dur_ns]]}]}]}, and beside them the stats
+    ``CLOCK_STATS`` of the ``Task Environment`` plane: the Unix time in ns
+    at which the profiler started, which is the zero every event's
+    ``start_ns`` counts from, and at which it stopped. Host planes are left
+    out: they are large, and what the host was doing comes from the
+    aggregator's own records."""
+    out: dict = {"planes": []}
+    for plane in planes:
+        if plane.name == "Task Environment":
+            out.update((key, int(value)) for key, value in plane.stats
+                       if key in CLOCK_STATS)
         if not plane.name.startswith("/device:"):
             continue
         lines = []
@@ -59,8 +74,8 @@ def _device_planes(trace_dir: str) -> list[dict]:
             events = [[ev.name, int(ev.start_ns), int(ev.duration_ns)]
                       for ev in line.events]
             lines.append({"line": line.name, "events": events})
-        planes.append({"plane": plane.name, "lines": lines})
-    return planes
+        out["planes"].append({"plane": plane.name, "lines": lines})
+    return out
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -169,7 +184,7 @@ def main(argv: list[str] | None = None) -> int:
     except RuntimeError as err:  # no backend came up: main said why
         out["device_error"] = str(err)
     if args.trace_dir and "stop" in marks:
-        out["planes"] = _device_planes(args.trace_dir)
+        out.update(_device_planes(args.trace_dir))
     tmp = args.out + ".tmp"
     with open(tmp, "w", encoding="utf-8") as f:
         json.dump(out, f)

@@ -5,27 +5,14 @@
 it lies on) and sums since start (``counts``, ``ingest``); a run keeps the
 whole body as read before the window opened and after the close
 (``run.drive.debug["first"]``, ``["last"]``). A record's boundaries are
-seconds after its ``stamp`` on the wall clock. The body holds the last 256
-records only: what joins a run's windows to their records says nothing
-when one is missing (``joined``). An older program serves none of this:
-every function here then returns nothing, never 0.
+seconds after its ``stamp`` on the wall clock. The body holds the last
+2048 records only: what joins a run's windows to their records says
+nothing when one is missing (``joined``). An older program serves none of
+this: every function here then returns nothing, never 0.
 
-The device trace counts from its own start, so one offset (the wall time of
-the trace's zero) puts it on the records' clock. Two bounds on it are hard,
-for every record whose run of the window's program is found on the ``XLA
-Modules`` line:
-
-- the run starts no earlier than the record's ``window.dispatch`` began
-  (the leg's first mark, ``h2d``): offset >= dispatch_begin - run_start, for every
-  window, and the LARGEST of these is the estimate — its error is the
-  smallest launch delay among the windows of the run;
-- the run ends no later than the record's ``window.pipeline_wait`` ended
-  (the leg's second mark, ``fetched``), and the trace's zero is no later than the
-  launcher's ``marks["start"]``: the estimate may exceed neither.
-
-Runs and records are both in order, one run a record, so they are matched
-by their spacing: the shift of one list against the other under which
-(dispatch_begin - run_start) varies least.
+The device trace counts from its own start, and says when that was
+(``profile_start_time``, Unix ns, kept by the launcher): it lies on the
+records' clock with no estimate, and the records can contradict it (``align``).
 """
 
 from __future__ import annotations
@@ -55,8 +42,7 @@ def leg_marks(body: dict) -> dict:
 def joined(run) -> list[dict] | None:
     """The record of every window of the run's measured window, joined by
     the stamp, in the windows' order. None where the last body has no
-    record for one of them: it keeps the last 256, and a median over the
-    windows that are left would read the run's tail for the run."""
+    record for one of them: a median over the rest reads the run's tail."""
     by_stamp = {r["stamp"]: r
                 for r in window_records(run.drive.debug.get("last"))}
     found = [by_stamp.get(win.stamp) for win in run.windows_in]
@@ -81,56 +67,69 @@ def window_records(body: dict) -> list[dict]:
     return out
 
 
+PROGRAM = "jit_temporal_fleet_window"  # the window's program, by its name
+FETCH_SLACK_S = 1e-3  # the two clocks' rounding, and the host's half ms
+
+
 def program_runs(planes: list) -> list[tuple[float, float]]:
-    """Whole runs of the window's program on the first plane that has any,
-    in seconds of the trace's clock, in order. The program is picked as
-    ``trace.program_ms`` picks it: the runs of at least half the longest,
-    and of those the ones within a tenth of their median."""
+    """Runs of the window's program (``XLA Modules`` events that carry its
+    name) on the first plane that has any, in trace seconds, in order."""
     for plane in planes:
-        runs = [(s, s + d) for _n, s, d in trace.module_events(plane)
-                if d > 0]
-        if not runs:
-            continue
-        longest = max(e - s for s, e in runs)
-        big = sorted(e - s for s, e in runs if e - s >= 0.5 * longest)
-        median = big[len(big) // 2]
-        return sorted((s / 1e9, e / 1e9) for s, e in runs
-                      if 0.9 * median <= e - s <= 1.1 * median)
+        runs = sorted((s / 1e9, (s + d) / 1e9)
+                      for name, s, d in trace.module_events(plane)
+                      if d > 0 and name.startswith(PROGRAM))
+        if runs:
+            return runs
     return []
 
 
-def align(body: dict, planes: list, marks: dict) -> dict | None:
-    """Match the program's runs to the body's records and estimate the
-    offset → {"offset_s", "pairs": [(record, run)], "launch_delay_s":
-    [...]} or None where nothing can be matched or a hard bound is
-    contradicted."""
+def align(body: dict, planes: list, launch: dict) -> dict | None:
+    """Hold the trace's own zero against the body's records → {"offset_s",
+    "checked", "contradicted", the least "launch_delay_s" and
+    "fetch_margin_s" of the runs that fit, "against": for the first three
+    that did not, [window, run start − its dispatch's begin, run end − its
+    fetch]}, or None without ``profile_start_time`` in the launcher's
+    report (an older JAX), records in the body or a run of the program in
+    the trace. Held is what the program guarantees: runs and windows come
+    in one order, a run starts no earlier than its window's
+    ``window.dispatch`` began and ends at most ``FETCH_SLACK_S`` after its
+    ``window.pipeline_wait`` did. So a run takes the next window not
+    fetched before the run ended, and contradicts the zero if that
+    window's dispatch had not begun when it started. (How soon a put lands
+    is no guarantee: under a busy host a run starts after the NEXT
+    window's dispatch began, and is its own window's still.)"""
     legs = leg_marks(body)
     runs = program_runs(planes)
+    zero_ns = (launch or {}).get("profile_start_time")
     if not ({"window.dispatch", "window.pipeline_wait"} <= set(legs)
-            and runs and "start" in marks):
+            and runs and zero_ns):
         return None
     began, ended = legs["window.dispatch"][0], legs["window.pipeline_wait"][1]
-    recs = [r for r in window_records(body)
-            if r.get(began) is not None and r.get(ended) is not None]
-    if not recs:
+    recs = sorted((r for r in window_records(body)
+                   if r.get(began) is not None and r.get(ended) is not None),
+                  key=lambda r: r[began])
+    offset = zero_ns / 1e9
+    k, delays, margins, against = 0, [], [], []
+    for start, end in runs:
+        start, end = offset + start, offset + end
+        if not recs or start < recs[0][began]:
+            continue  # a run from before the first record the body keeps
+        while k < len(recs) and recs[k][ended] + FETCH_SLACK_S < end:
+            k += 1  # fetched before this run ended: another run's window
+        rec = recs[min(k, len(recs) - 1)]
+        if k < len(recs) and rec[began] <= start:
+            delays.append(start - rec[began])
+            margins.append(rec[ended] - end)
+            k += 1
+        else:
+            against.append([rec["seq"], start - rec[began], end - rec[ended]])
+    if not delays and not against:
         return None
-    n = min(len(recs), len(runs))
-    best = None
-    for shift in range(len(recs) - n + 1):
-        for skip in range(len(runs) - n + 1):
-            gaps = [recs[shift + k][began] - runs[skip + k][0]
-                    for k in range(n)]
-            spread = max(gaps) - min(gaps)
-            if best is None or spread < best[0]:
-                best = (spread, shift, skip, gaps)
-    _spread, shift, skip, gaps = best
-    pairs = [(recs[shift + k], runs[skip + k]) for k in range(n)]
-    offset = max(gaps)
-    latest = min(rec[ended] - run[1] for rec, run in pairs)
-    if offset > latest or offset > marks["start"]:
-        return None
-    return {"offset_s": offset, "pairs": pairs,
-            "launch_delay_s": sorted(offset - g for g in gaps)}
+    return {"offset_s": offset, "checked": len(delays) + len(against),
+            "contradicted": len(against),
+            "launch_delay_s": min(delays, default=None),
+            "fetch_margin_s": min(margins, default=None),
+            "against": against[:3]}
 
 
 def _clip(spans: list, lo: float, hi: float) -> list:
@@ -162,14 +161,16 @@ def _complement(spans: list, lo: float, hi: float) -> list:
     return out
 
 
-def idle_by_leg(body: dict, planes: list, marks: dict,
+def idle_by_leg(body: dict, planes: list, launch: dict,
                 groups: dict) -> dict | None:
     """The device's idle seconds inside the stretch that both the trace
     and the body's records cover, how much of it falls inside each group
     of legs (``groups``: name → span names of legs, as the body's table
-    has them) and how much in none — by intersection of intervals."""
-    fit = align(body, planes, marks)
-    if fit is None:
+    has them) and how much in none — by intersection of intervals.
+    Nothing where the zero is unknown or contradicted: it is one number for
+    all runs, so by over one run in twenty (one says its record is off)."""
+    fit = align(body, planes, launch)
+    if fit is None or 20 * fit["contradicted"] > fit["checked"]:
         return None
     offset = fit["offset_s"]
     busy = []
@@ -199,6 +200,4 @@ def idle_by_leg(body: dict, planes: list, marks: dict,
     return {"idle_s": sum(b - a for a, b in idle),
             "in_s": {name: _overlap(idle, found)
                      for name, found in inside.items()},
-            "rest_s": _overlap(idle, rest),
-            "offset_s": offset,
-            "launch_delay_s": fit["launch_delay_s"]}
+            "rest_s": _overlap(idle, rest), "offset_s": offset}

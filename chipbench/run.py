@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import shutil
 import sys
@@ -31,7 +32,7 @@ if ROOT not in sys.path:
 
 import numpy as np  # noqa: E402
 
-from chipbench import check, spec, trace, work  # noqa: E402
+from chipbench import check, records, spec, trace, work  # noqa: E402
 from chipbench.child import (LAUNCHER, AggregatorChild,  # noqa: E402
                              BenchFailure, free_port)
 from chipbench.drive import Drive, run_window  # noqa: E402
@@ -101,26 +102,16 @@ class Run:
 
     def breakdown(self) -> dict:
         """The device ops that took most time, and the longest idle gaps by
-        what the aggregator's own clocks say the host was doing."""
+        what the aggregator's own clocks say the host was doing: the trace
+        lies on the host's clock by its own start time, and without one no
+        gap is named."""
         gaps = []
-        offset = self._trace_offset_s()
-        for start, end in trace.idle_gaps(self.planes):
-            mid = (start + end) / 2e9 + offset
+        zero_ns = self.launch.get("profile_start_time")
+        for start, end in trace.idle_gaps(self.planes) if zero_ns else ():
+            mid = (zero_ns + (start + end) / 2) / 1e9
             gaps.append([self._host_state(mid), (end - start) / 1e9])
         return {"device_ops": trace.top_ops(self.planes),
                 "idle_gaps": gaps}
-
-    def _trace_offset_s(self) -> float:
-        """Trace clock → host clock: the trace counts from its own start,
-        so its first device op is laid on the first dispatch after it."""
-        starts = [ev[1] for p in self.planes for ev in trace.op_events(p)]
-        if not starts:
-            return 0.0
-        first = min(starts) / 1e9
-        marks = self.launch.get("marks", {})
-        begins = [w.stamp + w.gauges["last_assembly_ms"] / 1e3
-                  for w in self.drive.windows if w.stamp >= marks["start"]]
-        return (min(begins) - first) if begins else 0.0
 
     def _host_state(self, t: float) -> str:
         for w in self.drive.windows:
@@ -151,7 +142,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
     cell = spec.load_cell(root, workload)
     work.of_config(cell.config, 0)  # an estimator nobody can count: now
     workdir = tempfile.mkdtemp(prefix="chipbench-")
-    child = None
+    child = drive = None
     try:
         params = make_params(seed, cell.config)
         params_path = os.path.join(workdir, "params.npz")
@@ -181,7 +172,8 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
             raise BenchFailure(f"{launch.get('count')} device(s), the cell "
                                f"asks for {cell.workload['chips']}")
     except BenchFailure as err:
-        print(f"chipbench: FAIL: {err}", file=sys.stderr)
+        phase = err.phase or ("ready" if drive is None else "close")
+        print(f"chipbench: FAIL: in {phase}: {err}", file=sys.stderr)
         return 1, None
     finally:
         if child is not None:
@@ -217,7 +209,19 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
         "workload": workload, "seed": seed, "seconds": seconds,
         "rounds": len(drive.rounds), "windows": len(run.windows_in),
         "reference_s": ref_s, "setup_parts": drive.setup_parts,
+        "reports_throttled": sum(r.throttled for r in drive.all_rounds),
+        "throttle_wait_s": sum(r.throttle_wait_s for r in drive.all_rounds),
         **errors.counts}
+    if cell.traffic["loop"] == "open":
+        # every window's own latency, in the windows' order: the next
+        # question about the tail costs no chip time
+        result["notes"]["latencies_ms"] = [
+            None if math.isinf(x) else x for x in run.latencies_ms]
+    if traced:
+        # how many records the trace's zero was held against, and how many
+        # contradicted it (``records.align``); null where there is no zero
+        result["notes"]["trace_zero"] = records.align(
+            drive.debug.get("last"), run.planes, launch)
     result["compared"] = compared
     for name, row in compared.items():
         print(f"chipbench: {name} = {row['value']:.6g} (limit "

@@ -11,7 +11,12 @@ under the aggregator before it starts:
 - ``half_left_out``: every second model node's history is never read, so
   half of the model rows are estimated from nothing.
 
-The comparison has to call each run not correct.
+The comparison has to call each run not correct. One more stalls the path
+and breaks nothing:
+
+- ``shed_once``: admission turns one report away (429, ``retry_after``
+  0.2 s), and with it the rest of its POST, as it does when the machine
+  freezes; the harness has to wait, send them again, and end ``correct``.
 """
 
 from __future__ import annotations
@@ -58,6 +63,20 @@ def plant(fault: str) -> None:
             return hist, tv
 
         agg.Aggregator._history_windows = halved
+    elif fault == "shed_once":
+        from kepler_tpu.fleet import admission
+
+        real_admit = admission.AdmissionController.admit
+        at = int(os.environ["CHIPBENCH_TEST_FAULT_AFTER"])
+        calls = [0]
+
+        def shed(self, priority):
+            calls[0] += 1
+            if calls[0] == at:
+                return 0.2
+            return real_admit(self, priority)
+
+        admission.AdmissionController.admit = shed
     else:
         raise SystemExit(f"unknown fault {fault!r}")
 

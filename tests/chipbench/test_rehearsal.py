@@ -156,6 +156,14 @@ def test_traced_run_prints_the_contracts_line_and_names_the_cpu(traced):
         assert set(row) == {"value", "limit"}
     notes = line["notes"]
     assert notes["answers_compared"] >= 8 + 4  # the fleet, and samples
+    # every window's own latency travels with an open-loop run's line
+    assert len(notes["latencies_ms"]) == line["attempted"] == notes["windows"]
+    assert all(0.0 < ms < 60e3 for ms in notes["latencies_ms"])
+    assert "latency_p90_ms.paced" in got
+    assert (notes["reports_throttled"], notes["throttle_wait_s"]) == (0, 0.0)
+    # a CPU trace has no device plane: the zero has nothing to be held
+    # against, and says so
+    assert notes["trace_zero"] is None
     assert json.loads(json.dumps(line)) == line
 
 
@@ -168,6 +176,24 @@ def test_untraced_closed_loop_reports_the_end_to_end_metrics(
     assert "breakdown" not in line
     assert line["metrics"]["pods_per_s"]["value"] > 0
     assert line["attempted"] >= 2 and line["failed"] == 0
+    assert "latencies_ms" not in line["notes"]  # a closed loop has rounds
+    assert "trace_zero" not in line["notes"]
+
+
+def test_a_throttle_is_waited_out_and_the_run_ends_correct(
+        root, tmp_path_factory):
+    """Admission sheds once in the warm-up (a machine freeze does that to a
+    run on the chip): the report and the rest of its POST are sent again,
+    nothing is lost, and the run says how much it was throttled."""
+    env = child_env(tmp_path_factory)
+    env["CHIPBENCH_TEST_FAULT"] = "shed_once"
+    env["CHIPBENCH_TEST_FAULT_AFTER"] = str(4 * 8 + 3)  # round 5, report 3
+    rc, line = run.run_cell(
+        "tiny.flood", SEED + 3, 1.0, False, root=root, platform="cpu",
+        env=env, launcher=os.path.join(HERE, "broken_launch.py"))
+    assert rc == 0 and line["correct"] is True and line["failed"] == 0
+    assert line["notes"]["reports_throttled"] == 8 - 2
+    assert line["notes"]["throttle_wait_s"] == pytest.approx(0.2)
 
 
 @pytest.mark.parametrize("fault, after", [
@@ -197,7 +223,7 @@ def test_without_a_tpu_the_command_fails_and_prints_no_result():
         timeout=300)
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
-    assert "chipbench: FAIL" in proc.stderr
+    assert "chipbench: FAIL: in ready:" in proc.stderr
 
 
 with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as _f:

@@ -155,25 +155,37 @@ def test_readers_return_nothing_not_zero(first, last):
     assert records.window_records(last) == []
 
 
-# -- the aligner, on the recorded trace -------------------------------------
+# -- the trace laid on the records, on the recorded trace --------------------
 
-OFFSET = 1_790_000_000.25  # the wall time of the trace's zero
+ZERO_NS = 1_790_000_000_250_000_000  # the trace's own ``profile_start_time``
+OFFSET = ZERO_NS / 1e9  # the wall time of the trace's zero
+ZERO = {"profile_start_time": ZERO_NS}
 DELAYS = (0.0071, 0.0032)  # launch delay of the two recorded runs
+FETCH = 0.002  # a window is fetched this long after its run ended
 
 
 @pytest.fixture(scope="module")
 def planes():
+    """The recorded trace (PR 26: the program carried no name yet) with its
+    two runs under the name the program has now, which ``program_runs``
+    picks them by."""
     with open(os.path.join(HERE, "trace_small.json"), encoding="utf-8") as f:
-        return json.load(f)["planes"]
+        planes = json.load(f)["planes"]
+    for event in trace.module_events(planes[0]):
+        assert event[0].startswith("jit__unknown(")
+        event[0] = event[0].replace("jit__unknown", records.PROGRAM)
+    return planes
 
 
 def planted(planes, fetched_early: float = 0.0) -> dict:
     """A body whose records 3 and 4 dispatched the two recorded runs,
-    ``DELAYS`` before each started; the records before and after them are
-    spaced otherwise, so only one shift fits. Served as the aggregator
-    serves them: boundaries as seconds after the stamp."""
+    ``DELAYS`` before each started, and fetched them ``FETCH`` after each
+    ended; the records before and after them ran nothing the trace holds.
+    Served as the aggregator serves them: boundaries as seconds after the
+    stamp."""
     runs = records.program_runs(planes)
     assert len(runs) == 2
+    ran = {3: runs[0], 4: runs[1]}
     begins = {3: OFFSET + runs[0][0] - DELAYS[0] - 0.31,
               4: OFFSET + runs[1][0] - DELAYS[1] - 0.31}
     for seq, back in ((2, 0.9), (1, 1.7), (0, 2.9)):
@@ -182,77 +194,182 @@ def planted(planes, fetched_early: float = 0.0) -> dict:
     rows = []
     for seq in sorted(begins):
         # tick 0.05 s, assembly 0.30 s, h2d 0.01 s, dispatch 0.002 s; the
-        # window is published with the next one's dispatch (depth 2)
+        # publisher takes the window at once and fetches it as its run
+        # ends (a window whose run the trace lacks: after 0.06 s)
         marks = {"tick": -0.05, "begin": 0.0, "snapshot": 0.001,
                  "batch": 0.05, "assembled": 0.30, "h2d": 0.31,
-                 "dispatched": 0.312}
-        nxt = begins.get(seq + 1, begins[seq] + 0.7) - begins[seq]
-        marks.update(publish_begin=nxt + 0.312, fetched=nxt + 0.313,
-                     scattered=nxt + 0.315, published=nxt + 0.316)
+                 "dispatched": 0.312, "publish_begin": 0.313}
+        fetched = 0.31 + 0.06
+        if seq in ran:
+            fetched = OFFSET + ran[seq][1] + FETCH - begins[seq]
         if seq == 4:
-            marks["fetched"] -= fetched_early
+            fetched -= fetched_early
+        marks.update(fetched=fetched, scattered=fetched + 0.002,
+                     published=fetched + 0.003)
         rows.append([seq, begins[seq], "legacy"]
                     + [marks[m] for m in FIELDS[3:14]]
                     + [0.2, 262144, 46080, 120_000_000, False])
     return {"records": {"fields": FIELDS, "legs": LEGS, "rows": rows}}
 
 
-def test_aligner_recovers_the_offset_to_within_the_least_launch_delay(
-        planes):
+def test_aligner_takes_the_offset_from_the_traces_own_zero(planes):
     body = planted(planes)
     recs = records.window_records(body)
     assert [r["seq"] for r in recs] == [0, 1, 2, 3, 4, 5]
     assert recs[3]["h2d"] == pytest.approx(recs[3]["stamp"] + 0.31)
-    fit = records.align(body, planes, {"start": OFFSET + 0.2})
-    assert [rec["seq"] for rec, _run in fit["pairs"]] == [3, 4]
-    assert 0.0 <= OFFSET - fit["offset_s"] <= min(DELAYS) + 1e-6
-    assert fit["launch_delay_s"] == pytest.approx(
-        [0.0, max(DELAYS) - min(DELAYS)], abs=1e-6)
+    fit = records.align(body, planes, ZERO)
+    assert fit["offset_s"] == OFFSET  # exact: no estimate is made
+    assert (fit["checked"], fit["contradicted"]) == (2, 0)
+    assert fit["launch_delay_s"] == pytest.approx(min(DELAYS), abs=1e-6)
+    assert fit["fetch_margin_s"] == pytest.approx(FETCH, abs=1e-6)
+    # the runs are picked by the program's name, not by how long they took:
+    # a helper as long as the program is no run of it
+    helper = json.loads(json.dumps(planes))
+    events = trace.module_events(helper[0])
+    events.append(["jit_convert_element_type(7)", events[0][1] + 400_000_000,
+                   events[0][2]])
+    assert records.program_runs(helper) == records.program_runs(planes)
 
 
-def test_aligner_returns_nothing_when_a_record_contradicts_a_bound(planes):
-    # the trace's zero after the launcher's mark
+def test_aligner_counts_what_contradicts_the_zero_and_then_nothing_prints(
+        planes):
+    def run_with_zero(body, launch):
+        return SimpleNamespace(
+            drive=SimpleNamespace(debug={"first": {}, "last": body}),
+            planes=planes, launch=launch)
+
     body = planted(planes)
-    assert records.align(body, planes, {"start": OFFSET - 0.5}) is None
+    assert idle_by_leg.read(run_with_zero(body, ZERO), legs=TICK) is not None
     # a window fetched before its program's run ended
-    early = planted(planes, fetched_early=0.9)
-    assert records.align(early, planes, {"start": OFFSET + 0.2}) is None
-    # no launcher mark, no record, no table of legs, no trace
+    early = planted(planes, fetched_early=0.03)
+    fit = records.align(early, planes, ZERO)
+    assert (fit["checked"], fit["contradicted"]) == (2, 1)
+    assert records.idle_by_leg(early, planes, ZERO, {"tick": TICK}) is None
+    assert idle_by_leg.read(run_with_zero(early, ZERO), legs=TICK) is None
+    # a zero 50 ms late: both runs end after their windows were fetched
+    late = {"profile_start_time": ZERO_NS + 50_000_000}
+    assert records.align(body, planes, late)["contradicted"] == 2
+    assert idle_by_leg.read(run_with_zero(body, late), legs=TICK) is None
+    # a zero 0.5 s early lays each run on a window that never ran it
+    soon = {"profile_start_time": ZERO_NS - 500_000_000}
+    assert records.align(body, planes, soon)["contradicted"] == 2
+    # a zero so early that no record had begun: nothing to hold it against
+    never = {"profile_start_time": ZERO_NS - 10_000_000_000}
+    assert records.align(body, planes, never) is None
+    # no start time (an older JAX), no record, no table of legs, no trace,
+    # no run that carries the program's name
     assert records.align(body, planes, {}) is None
+    assert records.align(body, planes, {"marks": {"start": OFFSET}}) is None
+    assert idle_by_leg.read(run_with_zero(body, {}), legs=TICK) is None
     none = {"records": {**body["records"], "rows": []}}
-    assert records.align(none, planes, {"start": OFFSET + 0.2}) is None
+    assert records.align(none, planes, ZERO) is None
     bare = {"records": {**body["records"], "legs": {}}}
-    assert records.align(bare, planes, {"start": OFFSET + 0.2}) is None
-    assert records.align(body, [], {"start": OFFSET + 0.2}) is None
+    assert records.align(bare, planes, ZERO) is None
+    assert records.align(body, [], ZERO) is None
+    with open(os.path.join(HERE, "trace_small.json"), encoding="utf-8") as f:
+        unnamed = json.load(f)["planes"]
+    assert records.program_runs(unnamed) == []
+    assert records.align(body, unnamed, ZERO) is None
+
+
+def windows_by_hand(landing: dict | None = None, stamp_off: dict | None = None,
+                  windows: int = 40) -> tuple[dict, list]:
+    """``windows`` windows 0.15 s apart, as flood's shortest cycle is: the
+    dispatch begins 0.09 s after the stamp, the put lands 0.075 s later
+    (``landing``: window → its own delay), the program runs 0.05 s and is
+    fetched 2 ms after its end; ``stamp_off`` shifts one record's wall
+    times as a stamp read late would. → (body, planes) on ``OFFSET``."""
+    rows, events, free = [], [], 0.0
+    for seq in range(windows):
+        begin = 1.0 + 0.15 * seq
+        start = max(begin + 0.09 + (landing or {}).get(seq, 0.075), free)
+        free = start + 0.05
+        marks = {"tick": -0.05, "begin": 0.0, "snapshot": 0.001,
+                 "batch": 0.01, "assembled": 0.08, "h2d": 0.09,
+                 "dispatched": 0.092, "publish_begin": 0.1,
+                 "fetched": free + FETCH - begin}
+        marks.update(scattered=marks["fetched"] + 0.002,
+                     published=marks["fetched"] + 0.003)
+        rows.append([seq, OFFSET + begin + (stamp_off or {}).get(seq, 0.0),
+                     "legacy"] + [marks[m] for m in FIELDS[3:14]]
+                    + [0.07, 262144, 46080, 120_000_000, False])
+        events.append([f"{records.PROGRAM}({seq})", int(start * 1e9),
+                       50_000_000])
+    planes = [{"plane": "/device:TPU:0",
+               "lines": [{"line": "XLA Modules", "events": events}]}]
+    return {"records": {"fields": FIELDS, "legs": LEGS, "rows": rows}}, planes
+
+
+def test_a_put_that_lands_late_is_its_own_windows_run_all_the_same():
+    # PR 35's refusal: one put of a flood run's 208 landed after the NEXT
+    # window's dispatch had begun; its run was laid on that window, which
+    # then held two, and the idle metrics printed nothing
+    body, planes = windows_by_hand()
+    fit = records.align(body, planes, ZERO)
+    assert (fit["checked"], fit["contradicted"]) == (40, 0)
+    assert fit["launch_delay_s"] == pytest.approx(0.075, abs=1e-6)
+    body, planes = windows_by_hand(landing={17: 0.19, 30: 0.40})
+    runs = records.program_runs(planes)
+    began = [r["h2d"] for r in records.window_records(body)]
+    assert runs[17][0] + OFFSET > began[18]  # after the next one's dispatch
+    assert runs[30][0] + OFFSET > began[32]  # and after the one after it
+    fit = records.align(body, planes, ZERO)
+    assert (fit["checked"], fit["contradicted"]) == (40, 0)
+    assert fit["fetch_margin_s"] == pytest.approx(FETCH, abs=1e-6)
+    assert fit["against"] == []
+    assert records.idle_by_leg(body, planes, ZERO, {"tick": TICK}) is not None
+
+
+def test_one_record_in_forty_is_no_case_against_the_zero_but_three_are():
+    # a record whose stamp was read 0.2 s late: its dispatch seems to begin
+    # after its run started, so the run fits no window; the zero is one
+    # number for all forty runs, and the other thirty-nine agree with it
+    body, planes = windows_by_hand(stamp_off={11: 0.2})
+    fit = records.align(body, planes, ZERO)
+    assert (fit["checked"], fit["contradicted"]) == (40, 1)
+    # the run is held against the next window it could be, 12, and started
+    # before that one's dispatch began and ended before it was fetched
+    window, after_dispatch, after_fetched = fit["against"][0]
+    assert window == 12
+    assert after_dispatch == pytest.approx(0.075 - 0.15, abs=1e-5)
+    assert after_fetched == pytest.approx(-FETCH - 0.15, abs=1e-5)
+    sound = records.idle_by_leg(*windows_by_hand(), ZERO, {"tick": TICK})
+    found = records.idle_by_leg(body, planes, ZERO, {"tick": TICK})
+    assert found["in_s"]["tick"] == pytest.approx(sound["in_s"]["tick"],
+                                                  rel=0.05)
+    body, planes = windows_by_hand(stamp_off={5: 0.2, 17: 0.2, 29: 0.2})
+    assert records.align(body, planes, ZERO)["contradicted"] == 3
+    assert records.idle_by_leg(body, planes, ZERO, {"tick": TICK}) is None
+    # and a zero that is wrong is contradicted by every run
+    late = {"profile_start_time": ZERO_NS + 50_000_000}
+    body, planes = windows_by_hand()
+    assert records.align(body, planes, late)["contradicted"] == 40
+    assert records.idle_by_leg(body, planes, late, {"tick": TICK}) is None
 
 
 def test_idle_shares_and_the_rest_sum_to_the_whole_idle_time(planes):
-    marks = {"start": OFFSET + 0.2}
-    found = records.idle_by_leg(planted(planes), planes, marks,
+    found = records.idle_by_leg(planted(planes), planes, ZERO,
                                 {"assembly": ASSEMBLY, "tick": TICK})
     inside = found["in_s"]
-    assert found["idle_s"] > 0
+    assert found["idle_s"] > 0 and found["offset_s"] == OFFSET
     assert inside["assembly"] + inside["tick"] + found["rest_s"] == \
         pytest.approx(found["idle_s"], rel=1e-9)
     # between the two runs the device idles 0.736 s; record 4's tick wait
-    # (0.05 s) and its assembly and h2d (0.31 s, less the launch delay's
-    # share that the estimate cannot see) lie inside that gap
+    # (0.05 s) and its assembly and h2d (0.31 s) lie inside that gap, whole
     busy = trace.busy_seconds(planes)
     window = (records.program_runs(planes)[1][1]
               - records.program_runs(planes)[0][0])
     assert found["idle_s"] == pytest.approx(window - busy, rel=0.02)
-    assert inside["tick"] == pytest.approx(0.05, abs=1e-3)
-    assert inside["assembly"] == pytest.approx(0.31, abs=5e-3)
+    assert inside["tick"] == pytest.approx(0.05, abs=1e-6)
+    assert inside["assembly"] == pytest.approx(0.31, abs=1e-6)
     run = SimpleNamespace(
         drive=SimpleNamespace(debug={"first": {}, "last": planted(planes)}),
-        planes=planes, launch={"marks": marks})
+        planes=planes, launch=ZERO)
     both = (idle_by_leg.read(run, legs=ASSEMBLY)
             + idle_by_leg.read(run, legs=TICK))
     assert both == pytest.approx(
         100.0 * (inside["assembly"] + inside["tick"]) / found["idle_s"])
     assert idle_by_leg.read(run, legs=["window.d2h"]) is None  # no such leg
-    run.launch = {"marks": {"start": OFFSET - 0.5}}
-    assert idle_by_leg.read(run, legs=TICK) is None
 
 
 def test_the_readers_know_the_records_as_the_aggregator_serves_them():
