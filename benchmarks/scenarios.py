@@ -530,14 +530,14 @@ def _measure_agg(agg, n_nodes: int, w: int, iters: int, warm: int = 2):
             last = published
         if it < warm:
             continue  # compile + resident rebuild stay untimed
-        s = agg._stats
+        s = agg.windows._stats
         cadence.append(dt)
         host.append(s["last_assembly_ms"] + s["last_scatter_ms"])
         device.append(s["last_dispatch_ms"] + s["last_wait_ms"])
     # snapshot the per-leg stats from the last STEADY window: the drain
     # below publishes its window right after dispatch (nothing overlaps
     # it), so post-shutdown legs would show zero pipeline overlap
-    steady_stats = dict(agg._stats)
+    steady_stats = agg.windows.stats()
     agg.shutdown()  # drain in-flight windows
     cadence.sort()
     host.sort()
@@ -583,7 +583,7 @@ def _sharded_window_fields(iters: int, n_nodes: int, w: int,
     uns = Aggregator(APIServer(), model_mode="mlp", node_bucket=64,
                      workload_bucket=128, stale_after=1e9,
                      pipeline_depth=1)
-    uns._mesh = make_mesh([1], devices=jax.devices()[:1])
+    uns.windows.mesh = make_mesh([1], devices=jax.devices()[:1])
     _, _, uns_dev_ms, _, uns_last = _measure_agg(uns, n_nodes, w,
                                                  max(100, iters))
     sharded_p50 = sharded_dev_ms[len(sharded_dev_ms) // 2]
@@ -638,15 +638,15 @@ def _fused_window_fields(iters: int, n_nodes: int, w: int) -> dict:
             if published is not None:
                 last = published
             if it >= warm:
-                s = agg._stats
+                s = agg.windows._stats
                 dev.append(s["last_dispatch_ms"] + s["last_wait_ms"])
         # the drain publishes whatever is still staged/in flight, so
         # BOTH runs' ``last`` is the final interval's window and the
         # bit comparison is window-for-window
-        tail = agg._drain_pipeline()
+        tail = agg.windows.drain()
         if tail is not None:
             last = tail
-        stats = dict(agg._stats)
+        stats = agg.windows.stats()
         agg.shutdown()
         dev.sort()
         return dev, stats, last
@@ -655,13 +655,13 @@ def _fused_window_fields(iters: int, n_nodes: int, w: int) -> dict:
     ref = Aggregator(APIServer(), model_mode="mlp", node_bucket=64,
                      workload_bucket=128, stale_after=1e9,
                      pipeline_depth=2)
-    ref._mesh = mesh1
+    ref.windows.mesh = mesh1
     ref_dev, _, ref_last = drive(ref, warm=2)
 
     fused = Aggregator(APIServer(), model_mode="mlp", node_bucket=64,
                        workload_bucket=128, stale_after=1e9,
                        pipeline_depth=1, fused_window_k=k)
-    fused._mesh = make_mesh([1], devices=jax.devices()[:1])
+    fused.windows.mesh = make_mesh([1], devices=jax.devices()[:1])
     # warm = k: the first flush (the cold lax.scan compile) stays
     # untimed, mirroring the compile-skipping warmup of the other legs
     fused_dev, fused_s, fused_last = drive(fused, warm=k)
@@ -797,10 +797,10 @@ def run_aggregator_window_scenario(iters: int) -> dict:
     agg = Aggregator(APIServer(), model_mode="mlp", node_bucket=64,
                      workload_bucket=128, stale_after=1e9,
                      pipeline_depth=2)
-    agg._mesh = mesh1
+    agg.windows.mesh = mesh1
     iters_pipe = max(100, iters)  # ≥100 samples → p99 is interior
     pipe_ms, _, _, s, _ = _measure_agg(agg, n_nodes, w, iters_pipe)
-    if agg._stats["attributions_total"] < iters_pipe:  # not assert: -O runs it
+    if agg.windows._stats["attributions_total"] < iters_pipe:  # not assert: -O runs it
         raise RuntimeError("pipelined aggregator lost windows")
 
     # host legs measured at depth 1: with the pipeline overlapping, the
@@ -812,14 +812,14 @@ def run_aggregator_window_scenario(iters: int) -> dict:
     host_agg = Aggregator(APIServer(), model_mode="mlp", node_bucket=64,
                           workload_bucket=128, stale_after=1e9,
                           pipeline_depth=1)
-    host_agg._mesh = mesh
+    host_agg.windows.mesh = mesh
     packed_serial_ms, host_ms, dev_ms, host_s, host_last = _measure_agg(
         host_agg, n_nodes, w, max(100, iters))
 
     serial = Aggregator(APIServer(), model_mode="mlp", node_bucket=64,
                         workload_bucket=128, stale_after=1e9,
                         accuracy_mode=True, pipeline_depth=1)
-    serial._mesh = mesh1
+    serial.windows.mesh = mesh1
     serial_ms, _, _, _, _ = _measure_agg(serial, n_nodes, w,
                                          max(3, iters // 2))
 
@@ -833,7 +833,7 @@ def run_aggregator_window_scenario(iters: int) -> dict:
     # length, so future perf PRs can correlate device-leg ratios with
     # compiled cost instead of re-deriving it
     program_flops = 0.0
-    engine = host_agg._engine
+    engine = host_agg.windows._engine
     if engine is not None:
         program_flops = max(
             (c.get("flops", 0.0) for c in engine.cost_stats().values()
@@ -858,7 +858,7 @@ def run_aggregator_window_scenario(iters: int) -> dict:
         "compile_count": int(s["window_compiles_total"]),
         "program_flops": program_flops,
         "shard_skew": float(host_s.get("shard_skew", 0.0)),
-        "rung_timeline_len": len(host_agg._rung_timeline),
+        "rung_timeline_len": len(host_agg.windows._rung_timeline),
         "window_p50_ms": round(pipe_p50, 3),
         "pipeline_p50_ms": round(pipe_p50, 3),
         "pipeline_p99_ms": round(_pctl(pipe_ms, 0.99), 3),

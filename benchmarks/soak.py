@@ -157,7 +157,7 @@ def run_soak(n_agents: int = 1000, seconds: float = 60.0,
                                                node=peers[i])
                                   if diurnal else None),
                          **peer_kw, **admission_kw)
-        agg._mesh = make_mesh()
+        agg.windows.mesh = make_mesh()
         agg.init()
         ctx = CancelContext()
         replica_threads.append([
@@ -611,7 +611,7 @@ def run_soak(n_agents: int = 1000, seconds: float = 60.0,
     # The plateau is still reported, as soak_rss_ramp_mib.
     ramp_deadline = time.monotonic() + min(4 * interval, seconds)
     while time.monotonic() < ramp_deadline:
-        done = sum(aggs[i]._stats["attributions_total"]
+        done = sum(aggs[i].windows._stats["attributions_total"]
                    for i in sorted(live))
         if done >= 2 * len(live) \
                 and time.monotonic() - t_start >= interval:
@@ -646,9 +646,9 @@ def run_soak(n_agents: int = 1000, seconds: float = 60.0,
     # surviving-replica stats: counters sum, per-window last_* figures
     # take the max (summing latencies across replicas would be a lie)
     live_aggs = [aggs[i] for i in sorted(live)]
-    stats = dict(live_aggs[0]._stats)
+    stats = live_aggs[0]._joined_stats()
     for a in live_aggs[1:]:
-        for k, v in a._stats.items():
+        for k, v in a._joined_stats().items():
             cur = stats.get(k)
             if not isinstance(v, (int, float)) \
                     or not isinstance(cur, (int, float)):

@@ -796,8 +796,8 @@ def _check_debug(dbg: dict, expect_platform: str, fused: bool,
                  k: int) -> dict:
     """The assertion surface: the window came from rung 0 on the expected
     platform, with nothing demoted."""
-    from kepler_tpu.fleet.aggregator import (RUNG_NAME_FUSED,
-                                             RUNG_NAME_SHARDED, RUNG_NAMES)
+    from kepler_tpu.fleet.scheduler import (RUNG_NAME_FUSED,
+                                            RUNG_NAME_SHARDED, RUNG_NAMES)
 
     device = {key: dbg.get(key) for key in
               ("platform", "device_kind", "devices")}

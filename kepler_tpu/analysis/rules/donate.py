@@ -16,6 +16,7 @@ from kepler_tpu.analysis.rules.common import (
 _DONATE_SCOPE = (
     "kepler_tpu/parallel/",
     "kepler_tpu/fleet/aggregator.py",
+    "kepler_tpu/fleet/scheduler.py",
     "kepler_tpu/fleet/window.py",
 )
 
@@ -59,7 +60,8 @@ class DonatedBufferRule(Rule):
         "stream-ordered backend — observes memory the program is "
         "rewriting in place (the resident fleet batch's delta update is "
         "exactly this). The check is LEXICAL, scoped to the window plane "
-        "(kepler_tpu/parallel/, fleet/aggregator.py, fleet/window.py): a "
+        "(kepler_tpu/parallel/, fleet/aggregator.py, fleet/scheduler.py, "
+        "fleet/window.py): a "
         "callable bound from a `jax.jit(…, donate_argnums=…)` call — or "
         "any callable whose binding carries `# keplint: donates=<pos>` "
         "(for jits built behind a helper) — consumes the variables at "

@@ -188,9 +188,9 @@ def _assemble(fleet: ChaosFleet, agents: list[ChaosAgent],
     window_health_ok: dict[str, bool] = {}
     for peer in sorted(fleet.alive):
         agg = fleet.aggs[peer]
-        stats[fleet.incarnation(peer)] = dict(agg._stats)
+        stats[fleet.incarnation(peer)] = agg._joined_stats()
         timelines[fleet.incarnation(peer)] = [
-            dict(e) for e in agg._rung_timeline]
+            dict(e) for e in agg.windows._rung_timeline]
         journals[fleet.incarnation(peer)] = agg._journal.snapshot()
         ring = agg._ring
         lease = agg._lease

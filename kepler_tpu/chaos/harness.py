@@ -344,9 +344,9 @@ class ChaosFleet:
                 "op": "kill", "peer": peer, "t_us": self._now_us(),
                 "epoch_before": self._member_epoch()})
         agg = self.aggs[peer]
-        self.retired_stats[self.incarnation(peer)] = dict(agg._stats)
+        self.retired_stats[self.incarnation(peer)] = agg._joined_stats()
         self.retired_timelines[self.incarnation(peer)] = [
-            dict(e) for e in agg._rung_timeline]
+            dict(e) for e in agg.windows._rung_timeline]
         self.retired_journals[self.incarnation(peer)] = \
             agg._journal.snapshot()
         self.alive.discard(peer)
@@ -520,7 +520,7 @@ class ChaosFleet:
             if ring is None or peer not in ring.peers:
                 continue
             if any(p not in self.alive for p in ring.peers):
-                agg._demote_mesh("host_dead")
+                agg._on_mesh_lost("host_dead")
 
     def shutdown(self) -> None:
         for peer in sorted(self.aggs):

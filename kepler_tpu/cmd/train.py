@@ -3,7 +3,7 @@
 ``python -m kepler_tpu.cmd.train --data DIR --model mlp --out params.npz``
 
 Reads the training windows the aggregator dumps
-(`fleet/aggregator.py:_dump_training_window`: RAPL nodes' feature inputs
+(`fleet/scheduler.py:_dump_training_window`: RAPL nodes' feature inputs
 labelled with their own ratio-attributed watts), fits the chosen estimator
 family, and writes serve-ready ``.npz`` params (`models.estimator
 .save_params`) for ``--aggregator.params-path``. Long fits checkpoint to
@@ -153,7 +153,7 @@ def main(argv: Sequence[str] | None = None) -> int:
                 "--model temporal needs history windows in the dumps — "
                 "run the aggregator with model=temporal AND a "
                 "trainingDumpDir so ratio nodes' feature histories are "
-                "captured (fleet/aggregator.py:_dump_training_window)")
+                "captured (fleet/scheduler.py:_dump_training_window)")
             return 2
         feat_hist = jnp.asarray(data["feat_hist"])
         t_valid = jnp.asarray(data["t_valid"])

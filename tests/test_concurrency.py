@@ -243,7 +243,7 @@ class TestAggregatorIngestRaces:
 
         agg = Aggregator(APIServer(), model_mode=None, node_bucket=8,
                          workload_bucket=16)
-        agg._mesh = make_mesh()
+        agg.windows.mesh = make_mesh()
         rng = np.random.default_rng(0)
         seqs = {i: 0 for i in range(N_THREADS)}
         lock = threading.Lock()

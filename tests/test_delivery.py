@@ -785,7 +785,7 @@ class TestDedupWindow:
             post_report(server, make_report("node-a"), seq=seq, run="r1")
         now[0] += 60.0  # partition: node ages out of the batch entirely
         agg.aggregate_once()
-        assert agg._stats["last_batch_nodes"] == 0
+        assert agg.windows._stats["last_batch_nodes"] == 0
         assert "node-a" in agg._seq_trackers  # survives staleness
         # replay: delivered-but-unacked tail (2, 3) then fresh 4
         for seq in (2, 3, 4):

@@ -29,10 +29,9 @@ import pytest
 
 from kepler_tpu import fault
 from kepler_tpu.fault import FaultPlan, FaultSpec
-from kepler_tpu.fleet.aggregator import (RUNG_EINSUM, RUNG_NUMPY,
-                                         RUNG_PACKED_SERIAL,
-                                         RUNG_PIPELINED, Aggregator,
-                                         _Stored)
+from kepler_tpu.fleet.aggregator import Aggregator, _Stored
+from kepler_tpu.fleet.scheduler import (RUNG_EINSUM, RUNG_NUMPY,
+                                        RUNG_PACKED_SERIAL, RUNG_PIPELINED)
 from kepler_tpu.fleet.window import DeviceWindowError  # noqa: F401 (API)
 from kepler_tpu.parallel.fleet import MODE_MODEL, MODE_RATIO, NodeReport
 from kepler_tpu.parallel.mesh import make_mesh
@@ -72,7 +71,7 @@ def make_agg(depth: int = 2, **kw) -> Aggregator:
     agg = Aggregator(APIServer(), pipeline_depth=depth,
                      clock=lambda: ticks[0], **kw)
     agg.test_clock = ticks
-    agg._mesh = make_mesh()
+    agg.windows.mesh = make_mesh()
     return agg
 
 
@@ -139,17 +138,17 @@ class TestDispatchErrorMidPipeline:
                                     skip=fail_at, count=1)])
         with fault.installed(plan):
             published = run_windows(agg, n_win)
-            tail = agg._drain_pipeline()
+            tail = agg.windows.drain()
         assert plan.fired("device.dispatch_error") == 1
 
         # demotion within ≤1 window: the failing call itself demoted and
         # still published (serial recompute at the demoted rung)
         assert published[fail_at] is not None
-        assert agg._stats["window_demotions_total"] == 1
-        assert agg._demotions_by_reason == {"dispatch_error": 1}
+        assert agg.windows._stats["window_demotions_total"] == 1
+        assert agg.windows._demotions_by_reason == {"dispatch_error": 1}
         # re-promotion landed after repromote_after clean windows
-        assert agg._stats["window_repromotions_total"] == 1
-        assert agg._rung == RUNG_PIPELINED
+        assert agg.windows._stats["window_repromotions_total"] == 1
+        assert agg.windows._rung == RUNG_PIPELINED
 
         # no gap: every call after the initial pipeline fill publishes,
         # except the single re-fill slot right after re-promotion
@@ -158,7 +157,7 @@ class TestDispatchErrorMidPipeline:
         # lands repromote_after−1 windows later and the fill slot is the
         # call after that)
         gaps = [i for i, r in enumerate(published) if r is None]
-        assert gaps == [0, fail_at + agg._repromote_after]
+        assert gaps == [0, fail_at + agg.windows._repromote_after]
         # no duplicates, monotone publication order
         seen = [r.timestamp for r in published if r is not None]
         if tail is not None:
@@ -202,7 +201,7 @@ class TestCompileErrorOnGrowth:
             published = run_windows(agg, 4, start=3, n_nodes=5, w=12)
             agg.shutdown()
         assert plan.fired("device.oom_on_grow") == 1
-        assert agg._demotions_by_reason == {"oom_on_grow": 1}
+        assert agg.windows._demotions_by_reason == {"oom_on_grow": 1}
         # the growth window itself still published, at the demoted rung
         assert published[0] is not None
         assert published[0].timestamp == 1e9 + 4 * 5.0
@@ -219,7 +218,7 @@ class TestCompileErrorOnGrowth:
             agg.shutdown()
         assert plan.fired("device.compile_error") == 1
         assert all(p is not None for p in published)
-        assert agg._stats["window_demotions_total"] == 1
+        assert agg.windows._stats["window_demotions_total"] == 1
 
 
 class TestStallWatchdog:
@@ -234,7 +233,7 @@ class TestStallWatchdog:
             published = run_windows(agg, 3)
             agg.shutdown()
         assert plan.fired("device.stall") == 1
-        assert agg._demotions_by_reason == {"stall": 1}
+        assert agg.windows._demotions_by_reason == {"stall": 1}
         assert all(p is not None for p in published)
 
     def test_timeout_zero_disables_watchdog(self):
@@ -246,7 +245,7 @@ class TestStallWatchdog:
             agg.shutdown()
         # the injected sleep ran inline (no worker thread, no timeout):
         # slow, but never a demotion
-        assert agg._stats["window_demotions_total"] == 0
+        assert agg.windows._stats["window_demotions_total"] == 0
         assert all(p is not None for p in published)
 
 
@@ -266,7 +265,7 @@ class TestFullLadderWalk:
             # rung probing: after repromote_after clean numpy windows the
             # einsum rung is retried, fails, and demotes right back —
             # the rung must never climb past einsum while the fault holds
-            assert agg._rung in (RUNG_NUMPY, RUNG_EINSUM)
+            assert agg.windows._rung in (RUNG_NUMPY, RUNG_EINSUM)
 
             health = agg.window_health()
             assert health["ok"] is False
@@ -318,13 +317,13 @@ class TestFullLadderWalk:
                                     count=3)])
         with fault.installed(plan):
             walk = run_windows(agg, 1)
-        assert agg._rung == RUNG_NUMPY
+        assert agg.windows._rung == RUNG_NUMPY
         assert walk[0] is not None
 
         # fault cleared: 2 clean → einsum, 2 → packed serial, 2 → full
         recovered = run_windows(agg, 3 * repromote + 2, start=1)
-        assert agg._rung == RUNG_PIPELINED
-        assert agg._stats["window_repromotions_total"] == 3
+        assert agg.windows._rung == RUNG_PIPELINED
+        assert agg.windows._stats["window_repromotions_total"] == 3
 
         # compare the last windows (fully recovered, pipeline refilled)
         # against a fault-free depth-1 reference of the same schedule
@@ -332,7 +331,7 @@ class TestFullLadderWalk:
         ref_published = run_windows(ref, 3 * repromote + 3)
         ref_agg_map = {round(r.timestamp, 3): r
                        for r in ref_published if r is not None}
-        tail = agg._drain_pipeline()
+        tail = agg.windows.drain()
         final = [r for r in recovered if r is not None][-2:]
         if tail is not None:
             final.append(tail)
@@ -352,25 +351,25 @@ class TestFullLadderWalk:
         with fault.installed(plan):
             run_windows(agg, 1)
             # the initial walk to numpy is 3 demotions, none a probe
-            assert agg._probe_penalty == 1
+            assert agg.windows._probe_penalty == 1
             # window 1: promote → window 2: probe dies → penalty 2;
             # then 2 clean needed → probe at window 5 dies → penalty 4
             run_windows(agg, 10, start=1)
-            assert agg._probe_penalty >= 4
-            probes_before = agg._stats["window_repromotions_total"]
+            assert agg.windows._probe_penalty >= 4
+            probes_before = agg.windows._stats["window_repromotions_total"]
             run_windows(agg, 10, start=11)
             # the decaying cadence: the second batch of 10 windows fires
             # strictly fewer probes than an un-backed-off ladder would
             # (threshold is ≥ 4 clean windows per probe by now)
-            assert (agg._stats["window_repromotions_total"]
+            assert (agg.windows._stats["window_repromotions_total"]
                     - probes_before) <= 3
         # recovery resets the penalty only on reaching full health
         # (penalty ≤ 16 by now → at most 48 clean windows to climb the
         # three rungs back to packed-pipelined)
-        assert agg._probe_penalty <= 16
+        assert agg.windows._probe_penalty <= 16
         recovered = run_windows(agg, 52, start=21)
-        assert agg._rung == RUNG_PIPELINED
-        assert agg._probe_penalty == 1
+        assert agg.windows._rung == RUNG_PIPELINED
+        assert agg.windows._probe_penalty == 1
         assert recovered[-1] is not None
         agg.shutdown()
 
@@ -382,7 +381,7 @@ class TestFullLadderWalk:
             seed_window(agg, 0)
             with pytest.raises(DeviceWindowError):
                 agg.aggregate_once()
-        assert agg._stats["window_demotions_total"] == 0
+        assert agg.windows._stats["window_demotions_total"] == 0
 
 
 class TestLadderMetrics:
@@ -391,7 +390,7 @@ class TestLadderMetrics:
         plan = FaultPlan([FaultSpec(site="device.dispatch_error",
                                     count=1)])
         with fault.installed(plan):
-            run_windows(agg, 1 + agg._repromote_after)
+            run_windows(agg, 1 + agg.windows._repromote_after)
             agg.shutdown()
         families = {f.name: f for f in agg.collect()}
         # prometheus_client strips the _total suffix into family names
@@ -427,25 +426,25 @@ class TestShardedChaos:
                                     skip=2, count=1)])
         with fault.installed(plan):
             published = run_windows(agg, 2)
-            assert isinstance(agg._engine, ShardedWindowEngine)
+            assert isinstance(agg.windows._engine, ShardedWindowEngine)
             assert agg.window_health()["rung_name"] == \
                 "packed-sharded-pipelined"
-            assert agg._stats["window_shards"] == n_dev
+            assert agg.windows._stats["window_shards"] == n_dev
             # window 2 hits the armed fault: the shard failure demotes to
             # the packed-serial rung on ONE device and still publishes
             published += run_windows(agg, 1, start=2)
             assert published[-1] is not None
-            assert agg._rung == RUNG_PACKED_SERIAL
-            serial_engine = agg._engine_serial
+            assert agg.windows._rung == RUNG_PACKED_SERIAL
+            serial_engine = agg.windows._engine_serial
             assert type(serial_engine) is PackedWindowEngine
             assert serial_engine._mesh.devices.size == 1
-            assert agg._stats["window_shards"] == 1
+            assert agg.windows._stats["window_shards"] == 1
             health = agg.window_health()
             assert health["rung_name"] == "packed-serial"
             assert health["shards"] == 1
             # sharded ring was re-seeded wholesale
-            assert agg._engine._buffers == []
-            assert agg._engine._shard_of == {}
+            assert agg.windows._engine._buffers == []
+            assert agg.windows._engine._shard_of == {}
         agg.shutdown()
 
     def test_shard_failure_demotes_and_repromotes_bit_equal(self):
@@ -457,7 +456,7 @@ class TestShardedChaos:
 
         n_win, fail_at = 10, 4
         ref = make_agg(depth=1)
-        ref._mesh = make_mesh([1], devices=jax.devices()[:1])
+        ref.windows.mesh = make_mesh([1], devices=jax.devices()[:1])
         reference = run_windows(ref, n_win)
         ref.shutdown()
         assert all(r is not None for r in reference)
@@ -467,15 +466,15 @@ class TestShardedChaos:
                                     skip=fail_at, count=1)])
         with fault.installed(plan):
             published = run_windows(agg, n_win)
-            tail = agg._drain_pipeline()
+            tail = agg.windows.drain()
         assert plan.fired("device.dispatch_error") == 1
-        assert agg._stats["window_demotions_total"] == 1
-        assert agg._stats["window_repromotions_total"] == 1
+        assert agg.windows._stats["window_demotions_total"] == 1
+        assert agg.windows._stats["window_repromotions_total"] == 1
         # back on the sharded rung, pipeline refilled
-        assert agg._rung == RUNG_PIPELINED
+        assert agg.windows._rung == RUNG_PIPELINED
         assert agg.window_health()["rung_name"] == \
             "packed-sharded-pipelined"
-        assert agg._stats["window_shards"] == len(jax.devices())
+        assert agg.windows._stats["window_shards"] == len(jax.devices())
 
         base = 1e9
         all_published = [r for r in published if r is not None]
@@ -498,14 +497,14 @@ class TestShardedChaos:
         with fault.installed(plan):
             run_windows(agg, 3, n_nodes=5, w=4)
             published = run_windows(agg, 6, start=3, n_nodes=5, w=12)
-            tail = agg._drain_pipeline()
+            tail = agg.windows.drain()
         assert plan.fired("device.oom_on_grow") == 1
-        assert agg._demotions_by_reason == {"oom_on_grow": 1}
+        assert agg.windows._demotions_by_reason == {"oom_on_grow": 1}
         assert published[0] is not None  # the growth window published
-        assert agg._rung == RUNG_PIPELINED  # recovered to sharded
+        assert agg.windows._rung == RUNG_PIPELINED  # recovered to sharded
 
         ref = make_agg(depth=1)
-        ref._mesh = make_mesh([1], devices=jax.devices()[:1])
+        ref.windows.mesh = make_mesh([1], devices=jax.devices()[:1])
         ref_published = run_windows(ref, 3, n_nodes=5, w=4)
         ref_published += run_windows(ref, 6, start=3, n_nodes=5, w=12)
         ref.shutdown()
@@ -580,8 +579,8 @@ class TestMultiHostChaos:
         import threading
 
         from kepler_tpu.fleet import wire
-        from kepler_tpu.fleet.aggregator import (RUNG_NAME_MESH_DEGRADED,
-                                                 RUNG_NAME_MULTIHOST)
+        from kepler_tpu.fleet.scheduler import (RUNG_NAME_MESH_DEGRADED,
+                                                RUNG_NAME_MULTIHOST)
         from kepler_tpu.fleet.ring import MeshRing
         from kepler_tpu.fleet.window import HostLocalFabric
 
@@ -625,7 +624,7 @@ class TestMultiHostChaos:
             for p in (0, 1):
                 assert published[p] is not None
                 assert sorted(published[p].names) == sorted(owned[p])
-        assert aggs[0]._rung_display(RUNG_PIPELINED) == \
+        assert aggs[0].windows._rung_display(RUNG_PIPELINED) == \
             RUNG_NAME_MULTIHOST
         epoch_before = aggs[0]._ring.epoch
 
@@ -640,10 +639,10 @@ class TestMultiHostChaos:
         # interval still published, on the survivor's own devices
         assert result is not None
         assert sorted(result.names) == sorted(owned[0])
-        assert survivor._mesh_degraded is True
-        assert survivor._rung == RUNG_PIPELINED
-        assert survivor._stats["window_demotions_total"] == 1
-        assert survivor._rung_display(RUNG_PIPELINED) == \
+        assert survivor.windows._mesh_degraded is True
+        assert survivor.windows._rung == RUNG_PIPELINED
+        assert survivor.windows._stats["window_demotions_total"] == 1
+        assert survivor.windows._rung_display(RUNG_PIPELINED) == \
             RUNG_NAME_MESH_DEGRADED
         # ring epoch bumped: displaced agents follow 421s to the
         # survivor (takeover ring owns everything here)
@@ -710,7 +709,7 @@ class TestMultiHostChaos:
         import json as _json
         import threading
 
-        from kepler_tpu.fleet.aggregator import (
+        from kepler_tpu.fleet.scheduler import (
             RUNG_NAME_MESH_DEGRADED, RUNG_NAME_MULTIHOST)
         from kepler_tpu.fleet.ring import MeshRing
         from kepler_tpu.fleet.window import HostLocalFabric
@@ -793,7 +792,7 @@ class TestMultiHostChaos:
         assert survivor._ring.epoch == 2
         assert survivor._membership_applied.get("succession") == 1
         assert survivor._lease.holder == self.PEERS[0]
-        assert survivor._rung_display(RUNG_PIPELINED) == \
+        assert survivor.windows._rung_display(RUNG_PIPELINED) == \
             RUNG_NAME_MESH_DEGRADED
         assert survivor._ring.owner(owned[1][0]) == self.PEERS[0]
 
@@ -811,7 +810,7 @@ class TestMultiHostChaos:
             assert agg._lease.holder == self.PEERS[0]
             assert agg._ring.epoch == 3  # death bump + join bump
             assert isinstance(agg._ring, MeshRing)
-            assert agg._mesh_degraded is False
+            assert agg.windows._mesh_degraded is False
         assert "succession" not in rejoined._membership_applied
 
         # the rejoiner owns shards again, and both rings agree
@@ -826,7 +825,7 @@ class TestMultiHostChaos:
         for p in (0, 1):
             assert published[p] is not None
             assert sorted(published[p].names) == sorted(owned_after[p])
-            assert aggs[self.PEERS[p]]._rung_display(RUNG_PIPELINED) \
+            assert aggs[self.PEERS[p]].windows._rung_display(RUNG_PIPELINED) \
                 == RUNG_NAME_MULTIHOST
         assert survivor._stats["windows_lost_total"] == 0
 

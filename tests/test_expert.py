@@ -167,8 +167,8 @@ class TestRegistry:
                            hidden=16).items()}
         agg = Aggregator(APIServer(), model_mode="moe",
                          model_params=params)
-        agg._check_params_shape()
-        assert agg._model_out_dim() == 2
+        agg.windows._check_params_shape()
+        assert agg.windows._model_out_dim() == 2
 
     def test_fleet_aggregator_rejects_unknown_model_params(self):
         import pytest
@@ -179,4 +179,4 @@ class TestRegistry:
         agg = Aggregator(APIServer(), model_mode="switch-transformer",
                          model_params={"w": np.zeros(2)})
         with pytest.raises(ValueError, match="unknown aggregator model"):
-            agg._check_params_shape()
+            agg.windows._check_params_shape()

@@ -214,14 +214,14 @@ class TestAggregator:
         post_report(server, make_report("node-a"))
         post_report(server, make_report("node-b", seed=1))
         agg.aggregate_once()
-        assert agg._stats["last_batch_nodes"] == 2
+        assert agg.windows._stats["last_batch_nodes"] == 2
         now[0] += 10.0
         post_report(server, make_report("node-b", seed=2), seq=2)
         now[0] += 10.0  # node-a now 20s old, node-b 10s old
         agg.aggregate_once()
-        assert agg._stats["last_batch_nodes"] == 1
-        with agg._results_lock:
-            assert set(agg._results.names) == {"node-b"}
+        assert agg.windows._stats["last_batch_nodes"] == 1
+        with agg.windows._results_lock:
+            assert set(agg.windows._results.names) == {"node-b"}
 
     def test_rejects_garbage_post(self, server):
         agg = Aggregator(server, model_mode=None)
@@ -253,8 +253,8 @@ class TestAggregator:
                          workload_bucket=16)
         agg.init()
         def cum(agg, name):
-            return dict(zip(agg._cum_zones,
-                            agg._cum.value(name).tolist()))
+            return dict(zip(agg.windows._cum_zones,
+                            agg.windows._cum.value(name).tolist()))
 
         post_report(server, make_report("node-a"))
         agg.aggregate_once()
@@ -310,8 +310,8 @@ class TestAggregator:
         # trained params survive the mismatch; an untrained fallback served
         # the window (review finding: transient zone changes must not
         # destroy loaded params)
-        assert agg._model_out_dim() == 5
-        assert 2 in agg._fallback_params
+        assert agg.windows._model_out_dim() == 5
+        assert 2 in agg.windows._fallback_params
 
 
 class FakeMeterMonitor:
@@ -449,8 +449,8 @@ class TestAgent:
         agent._send(sample, seq)
         result = agg.aggregate_once()
         assert result is not None
-        with agg._results_lock:
-            res = agg._results.render_node("test-node")
+        with agg.windows._results_lock:
+            res = agg.windows._results.render_node("test-node")
         assert [w["id"] for w in res["workloads"]] == ["p1", "c1"]
         # workload kinds survive the wire
         assert [w["kind"] for w in res["workloads"]] == [0, 1]
@@ -911,7 +911,7 @@ class TestParamsFeatureDimCheck:
                            n_features=6).items()}  # pre-F=7 checkpoint
         agg = Aggregator(APIServer(), model_mode="mlp", model_params=params)
         with pytest.raises(ValueError, match="feature dim"):
-            agg._check_params_shape()
+            agg.windows._check_params_shape()
 
     def test_current_feature_dim_passes(self):
         import jax
@@ -921,4 +921,4 @@ class TestParamsFeatureDimCheck:
         params = {k: np.asarray(v) for k, v in
                   init_mlp(jax.random.PRNGKey(0), 2).items()}
         Aggregator(APIServer(), model_mode="mlp",
-                   model_params=params)._check_params_shape()
+                   model_params=params).windows._check_params_shape()

@@ -175,9 +175,9 @@ class TestFleetChurnSoak:
             # -- aggregate + invariants --------------------------------
             result = agg.aggregate_once()
             assert result is not None
-            with agg._results_lock:
-                results = {name: agg._results.render_node(name)
-                           for name in agg._results.names}
+            with agg.windows._results_lock:
+                results = {name: agg.windows._results.render_node(name)
+                           for name in agg.windows._results.names}
             for name, row in results.items():
                 if name not in agents:
                     continue  # node left mid-window; skip
@@ -219,7 +219,7 @@ class TestFleetChurnSoak:
 
         assert conservation_checked > 2000
         assert rejected_strugglers >= 10
-        assert agg._stats["attributions_total"] == self.WINDOWS
+        assert agg.windows._stats["attributions_total"] == self.WINDOWS
         assert agg._stats["rejected_total"] >= rejected_strugglers
 
 

@@ -26,7 +26,7 @@ import pytest
 
 from kepler_tpu import fault
 from kepler_tpu.fault import FaultPlan, FaultSpec
-from kepler_tpu.fleet.aggregator import RUNG_NAME_FUSED, RUNG_PIPELINED
+from kepler_tpu.fleet.scheduler import RUNG_NAME_FUSED, RUNG_PIPELINED
 from kepler_tpu.fleet.window import (FusedWindowEngine, PackedWindowEngine,
                                      RowInput)
 from kepler_tpu.parallel.mesh import make_mesh
@@ -47,14 +47,14 @@ def run_capture_all(agg, schedules, fault_skip=None):
     """Drive the schedule, recording EVERY published window (a fused
     flush publishes K results inside one ``aggregate_once`` call)."""
     published = []
-    orig = agg._publish
+    orig = agg.windows._publish
 
     def spy(p):
         res = orig(p)
         published.append(res)
         return res
 
-    agg._publish = spy
+    agg.windows._publish = spy
     ctx = contextlib.nullcontext()
     if fault_skip is not None:
         ctx = fault.installed(FaultPlan([FaultSpec(
@@ -64,7 +64,7 @@ def run_capture_all(agg, schedules, fault_skip=None):
             agg.test_clock[0] += 5.0
             seed_window(agg, sched, agg.test_clock[0])
             agg.aggregate_once()
-        agg._drain_pipeline()
+        agg.windows.drain()
     return published
 
 
@@ -166,10 +166,10 @@ class TestAggregatorFusedTier:
         for a, b in zip(serial, fused):
             assert a.timestamp == b.timestamp
             assert_windows_equal(a, b)
-        assert agg._stats["attributions_total"] == len(schedules)
+        assert agg.windows._stats["attributions_total"] == len(schedules)
         # the flush set the amortized sync figure; ring-filling calls
         # reported a zero device leg
-        assert agg._stats["last_sync_per_window_ms"] > 0.0
+        assert agg.windows._stats["last_sync_per_window_ms"] > 0.0
         health = agg.window_health()
         assert health["fused"]["k"] == k
         assert health["fused"]["active"] is True
@@ -187,10 +187,10 @@ class TestAggregatorFusedTier:
             agg.test_clock[0] += 5.0
             seed_window(agg, sched, agg.test_clock[0])
             agg.aggregate_once()
-            max_pending = max(max_pending, len(agg._fused_pending))
+            max_pending = max(max_pending, len(agg.windows._fused_pending))
         assert max_pending == k - 1  # the K-th stage call flushes
         agg.shutdown()
-        assert not agg._fused_pending  # drain leaves nothing behind
+        assert not agg.windows._fused_pending  # drain leaves nothing behind
 
 
 @pytest.mark.chaos
@@ -211,10 +211,10 @@ class TestFusedChaos:
         for a, b in zip(serial, published):
             assert a.timestamp == b.timestamp
             assert_windows_equal(a, b)
-        assert agg._rung == RUNG_PIPELINED  # demotion stayed within rung 0
-        assert agg._fused_degraded
-        assert agg._stats["window_demotions_total"] == 1
-        transitions = [t for t in agg._rung_timeline
+        assert agg.windows._rung == RUNG_PIPELINED  # demotion stayed within rung 0
+        assert agg.windows._fused_degraded
+        assert agg.windows._stats["window_demotions_total"] == 1
+        transitions = [t for t in agg.windows._rung_timeline
                        if t.get("from_rung_name") == RUNG_NAME_FUSED]
         assert transitions and transitions[0]["reason"] == "dispatch_error"
         health = agg.window_health()
@@ -231,7 +231,7 @@ class TestFusedChaos:
         assert len(published) == len(schedules)
         for a, b in zip(serial, published):
             assert_windows_equal(a, b)
-        assert not agg._fused_degraded
-        assert agg._stats["window_repromotions_total"] >= 1
+        assert not agg.windows._fused_degraded
+        assert agg.windows._stats["window_repromotions_total"] >= 1
         assert agg.window_health()["fused"]["active"] is True
         agg.shutdown()

@@ -26,8 +26,8 @@ import numpy as np
 import pytest
 
 from kepler_tpu import telemetry
-from kepler_tpu.fleet.aggregator import (RUNG_NUMPY, RUNG_PIPELINED,
-                                         Aggregator)
+from kepler_tpu.fleet.aggregator import Aggregator
+from kepler_tpu.fleet.scheduler import RUNG_NUMPY, RUNG_PIPELINED
 from kepler_tpu.fleet.scoreboard import (STATE_ANOMALOUS, STATE_HEALTHY,
                                          STATE_LOSSY, STATE_NAMES,
                                          STATE_QUARANTINED, STATE_STALE,
@@ -243,7 +243,7 @@ class TestRungTimeline:
     def test_demotion_records_transition(self):
         agg = make_agg(1)
         run_schedule(agg, churn_schedule(1))
-        agg._handle_device_failure(
+        agg.windows._handle_device_failure(
             DeviceWindowError("dispatch_error", "injected"))
         probe = agg.window_health()
         assert probe["timeline_len"] == 1
@@ -262,7 +262,7 @@ class TestRungTimeline:
         agg = make_agg(1, repromote_after=2)
         schedules = churn_schedule(4)
         run_schedule(agg, schedules[:1])
-        agg._handle_device_failure(
+        agg.windows._handle_device_failure(
             DeviceWindowError("compile_error", "injected"))
         published = run_schedule(agg, schedules[1:])
         assert published  # demoted rung still publishes
@@ -283,7 +283,7 @@ class TestRungTimeline:
         agg = make_agg(1, repromote_after=100)  # stay demoted
         schedules = churn_schedule(3)
         run_schedule(agg, schedules[:1])
-        agg._handle_device_failure(
+        agg.windows._handle_device_failure(
             DeviceWindowError("dispatch_error", "injected"))
         run_schedule(agg, schedules[1:])
         assert agg.window_health()["rung"] == 1  # packed serial
@@ -301,10 +301,10 @@ class TestRungTimeline:
     def test_timeline_ring_is_bounded(self):
         agg = make_agg(1)
         for _ in range(80):
-            agg._handle_device_failure(
+            agg.windows._handle_device_failure(
                 DeviceWindowError("stall", "injected"))
         assert agg.window_health()["timeline_len"] == 64
-        assert agg._rung == RUNG_NUMPY  # pinned at the bottom rung
+        assert agg.windows._rung == RUNG_NUMPY  # pinned at the bottom rung
         agg.shutdown()
 
 

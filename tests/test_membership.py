@@ -406,7 +406,7 @@ class TestFiveHostSuccession:
         dead = PEERS5[2]
         fleet.kill(dead)
         for agg in fleet.survivors():
-            agg._demote_mesh("host_dead")
+            agg._on_mesh_lost("host_dead")
         issuers = [p for p in PEERS5 if p in fleet.alive
                    and fleet.aggs[p]._membership_applied.get("succession")]
         assert issuers == [PEERS5[0]]  # the incumbent holder, alive
@@ -420,7 +420,7 @@ class TestFiveHostSuccession:
     def test_holder_death_elects_lowest_survivor(self, fleet):
         fleet.kill(PEERS5[0])
         for agg in fleet.survivors():
-            agg._demote_mesh("host_dead")
+            agg._on_mesh_lost("host_dead")
         issuers = [p for p in PEERS5 if p in fleet.alive
                    and fleet.aggs[p]._membership_applied.get("succession")]
         assert issuers == [PEERS5[1]]  # lowest surviving peer
@@ -432,7 +432,7 @@ class TestFiveHostSuccession:
         fleet.kill(PEERS5[0])
         fleet.kill(PEERS5[3])
         for agg in fleet.survivors():
-            agg._demote_mesh("host_dead")
+            agg._on_mesh_lost("host_dead")
         epochs = {a._ring.epoch for a in fleet.survivors()}
         assert epochs == {2}
         for agg in fleet.survivors():
@@ -444,7 +444,7 @@ class TestFiveHostSuccession:
         try:
             fleet.kill(PEERS5[4])
             for agg in fleet.survivors():
-                agg._demote_mesh("host_dead")
+                agg._on_mesh_lost("host_dead")
             for agg in fleet.survivors():
                 assert agg._ring.epoch == 1  # untouched
                 assert agg._awaiting_membership is True
@@ -488,7 +488,7 @@ class TestJoinLeave:
         dead = PEERS5[1]
         fleet.kill(dead)
         for agg in fleet.survivors():
-            agg._demote_mesh("host_dead")
+            agg._on_mesh_lost("host_dead")
         holder_before = fleet.aggs[PEERS5[0]]._lease.holder
         # the host returns: fresh process, stale ring at epoch 1
         fleet.alive.add(dead)
@@ -520,7 +520,7 @@ class TestJoinLeave:
         dead = PEERS5[3]
         fleet.kill(dead)
         for agg in fleet.survivors():
-            agg._demote_mesh("host_dead")
+            agg._on_mesh_lost("host_dead")
         fleet.alive.add(dead)
         rejoiner = fleet.aggs[dead]
         reply = rejoiner.request_join(via=PEERS5[4])  # not the holder

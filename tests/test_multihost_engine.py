@@ -21,9 +21,9 @@ import threading
 import numpy as np
 import pytest
 
-from kepler_tpu.fleet.aggregator import (RUNG_NAME_MESH_DEGRADED,
-                                         RUNG_NAME_MULTIHOST,
-                                         RUNG_PIPELINED, Aggregator)
+from kepler_tpu.fleet.aggregator import Aggregator
+from kepler_tpu.fleet.scheduler import (RUNG_NAME_MESH_DEGRADED,
+                                        RUNG_NAME_MULTIHOST, RUNG_PIPELINED)
 from kepler_tpu.fleet.ring import (HashRing, MeshRing, RingError,
                                    ring_from_mesh)
 from kepler_tpu.fleet.window import (DeviceWindowError, HostLocalFabric,
@@ -421,7 +421,7 @@ class TestMultihostInitStatus:
         initialize_multihost(coordinator_address="10.0.0.1:9")
         agg = Aggregator(APIServer(), model_mode="mlp",
                          multihost_enabled=True, stale_after=1e9)
-        agg._mesh = make_mesh()
+        agg.windows.mesh = make_mesh()
         probe = agg.window_health()
         assert probe["multihost"]["init_reason"] == \
             "coordinator_unreachable"
@@ -458,9 +458,9 @@ class TestAggregatorMultihost:
             assert isinstance(agg._ring, MeshRing)
             assert agg._ring.ownership_ratio(PEERS[0]) == \
                 pytest.approx(0.5)
-            engine = agg._packed_engine(RUNG_PIPELINED)
+            engine = agg.windows._packed_engine(RUNG_PIPELINED)
             assert isinstance(engine, MultiHostWindowEngine)
-            assert agg._rung_display(RUNG_PIPELINED) == \
+            assert agg.windows._rung_display(RUNG_PIPELINED) == \
                 RUNG_NAME_MULTIHOST
             probe = agg.window_health()
             assert probe["multihost"]["active"] is True
@@ -528,11 +528,11 @@ class TestAggregatorMultihost:
         agg = self._three_host_agg(0, alive=set(peers3[:2]),
                                    delivered=delivered)
         try:
-            agg._packed_engine(RUNG_PIPELINED)
+            agg.windows._packed_engine(RUNG_PIPELINED)
             epoch_before = agg._ring.epoch
-            agg._handle_device_failure(
+            agg.windows._handle_device_failure(
                 DeviceWindowError("host_dead", "peer lost"))
-            assert agg._mesh_degraded is True
+            assert agg.windows._mesh_degraded is True
             # exactly one issuer (self = incumbent holder): epoch
             # bumped over the survivors, dead peer excised
             assert agg._ring.epoch == epoch_before + 1
@@ -561,10 +561,10 @@ class TestAggregatorMultihost:
         agg = self._three_host_agg(1, alive=set(peers3[:2]),
                                    delivered=delivered)
         try:
-            agg._packed_engine(RUNG_PIPELINED)
+            agg.windows._packed_engine(RUNG_PIPELINED)
             epoch_before = agg._ring.epoch
             owner_before = agg._ring.owner("some-node")
-            agg._handle_device_failure(
+            agg.windows._handle_device_failure(
                 DeviceWindowError("host_dead", "peer lost"))
             # not the issuer: epoch and ownership untouched, no
             # broadcast sent, probe degraded awaiting membership
@@ -603,24 +603,24 @@ class TestAggregatorMultihost:
         the probe/timeline name the mesh-minus-one-host tier."""
         agg = make_mh_aggregator(0)
         try:
-            agg._packed_engine(RUNG_PIPELINED)  # build the mh engine
+            agg.windows._packed_engine(RUNG_PIPELINED)  # build the mh engine
             epoch_before = agg._ring.epoch
-            agg._handle_device_failure(
+            agg.windows._handle_device_failure(
                 DeviceWindowError("host_dead", "peer lost"))
-            assert agg._mesh_degraded is True
-            assert agg._rung == RUNG_PIPELINED  # rung kept, tier changed
+            assert agg.windows._mesh_degraded is True
+            assert agg.windows._rung == RUNG_PIPELINED  # rung kept, tier changed
             assert agg._ring.epoch == epoch_before + 1
             assert not isinstance(agg._ring, MeshRing)
             assert agg._ring.owner("anything") == PEERS[0]  # takeover
-            assert agg._rung_display(RUNG_PIPELINED) == \
+            assert agg.windows._rung_display(RUNG_PIPELINED) == \
                 RUNG_NAME_MESH_DEGRADED
-            entry = agg._rung_timeline[-1]
+            entry = agg.windows._rung_timeline[-1]
             assert entry["from_rung_name"] == RUNG_NAME_MULTIHOST
             assert entry["rung_name"] == RUNG_NAME_MESH_DEGRADED
             assert entry["reason"] == "host_dead"
             # the rebuilt engine is the survivors' single-host sharded
             # engine over LOCAL devices only
-            engine = agg._packed_engine(RUNG_PIPELINED)
+            engine = agg.windows._packed_engine(RUNG_PIPELINED)
             assert isinstance(engine, ShardedWindowEngine)
             assert not isinstance(engine, MultiHostWindowEngine)
             assert engine.n_shards == 4
@@ -640,7 +640,7 @@ class TestAggregatorMultihost:
         agg = Aggregator(APIServer(), model_mode="mlp", stale_after=1e9,
                          node_bucket=8, workload_bucket=8,
                          pipeline_depth=1, clock=lambda: 1e9)
-        agg._mesh = make_mesh()
+        agg.windows.mesh = make_mesh()
         from kepler_tpu.fleet.aggregator import _Stored
 
         for i in range(5):
@@ -651,10 +651,10 @@ class TestAggregatorMultihost:
                 run="r1")
         result = agg.aggregate_once()
         assert result is not None
-        assert agg._stats["last_fetch_ms"] >= 0.0
-        if agg._mesh.devices.size > 1:
+        assert agg.windows._stats["last_fetch_ms"] >= 0.0
+        if agg.windows.mesh.devices.size > 1:
             # the sharded plan carries the per-shard fetch override
-            assert isinstance(agg._engine, ShardedWindowEngine)
+            assert isinstance(agg.windows._engine, ShardedWindowEngine)
         families = {f.name for f in agg.collect()}
         assert "kepler_fleet_window_fetch_ms" in families
         agg.shutdown()
@@ -662,11 +662,11 @@ class TestAggregatorMultihost:
     def test_takeover_disabled_keeps_ring_epoch(self):
         agg = make_mh_aggregator(0, multihost_takeover=False)
         try:
-            agg._packed_engine(RUNG_PIPELINED)
+            agg.windows._packed_engine(RUNG_PIPELINED)
             epoch_before = agg._ring.epoch
-            agg._handle_device_failure(
+            agg.windows._handle_device_failure(
                 DeviceWindowError("host_dead", "peer lost"))
-            assert agg._mesh_degraded is True
+            assert agg.windows._mesh_degraded is True
             assert agg._ring.epoch == epoch_before
         finally:
             agg.shutdown()

@@ -169,9 +169,9 @@ def published(n_dev: int) -> tuple:
         s = Served(n_dev)
         try:
             out = [s.window(seq) for seq in range(1, TICKS + 4)]
-            out.append(s.agg._drain_pipeline())
-            counts = s.agg._window_ledger.snapshot()[1]
-            placed = s.agg._params_placed
+            out.append(s.agg.windows.drain())
+            counts = s.agg.windows._window_ledger.snapshot()[1]
+            placed = s.agg.windows._params_placed
             spans = [sp for tr in rec.recent_traces()
                      for sp in tr.to_dict()["spans"]]
             metrics = {m.name: m for m in s.agg.collect()}
@@ -280,12 +280,12 @@ def test_a_node_silent_past_stale_after_leaves_the_results():
         for seq in range(1, 3):
             s.window(seq)
             now[0] += 1.0
-        res = s.agg._drain_pipeline()
+        res = s.agg.windows.drain()
         assert sorted(res.names) == [f"node-{k}" for k in range(NODES)]
         # nodes 0-2 go silent; 14 s later they are still answered
         now[0] += 13.0  # their last report is 14 s old
         s.window(3, nodes=range(3, NODES))
-        res = s.agg._drain_pipeline()
+        res = s.agg.windows.drain()
         assert len(res.names) == NODES
         body = s.get("/v1/results")
         assert len(body["nodes"]) == NODES
@@ -293,7 +293,7 @@ def test_a_node_silent_past_stale_after_leaves_the_results():
         # from /v1/results, and the model nodes' histories with them
         now[0] += 2.0
         s.window(4, nodes=range(3, NODES))
-        res = s.agg._drain_pipeline()
+        res = s.agg.windows.drain()
         assert sorted(res.names) == [f"node-{k}" for k in range(3, NODES)]
         body = s.get("/v1/results")
         assert sorted(body["nodes"]) == [f"node-{k}"

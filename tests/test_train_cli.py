@@ -64,7 +64,7 @@ class TestTrainingDump:
         agg = Aggregator(APIServer(), model_mode=None,
                          training_dump_dir=str(tmp_path / "dump"),
                          node_bucket=8, workload_bucket=8)
-        agg._mesh = make_mesh()
+        agg.windows.mesh = make_mesh()
         feed_reports(agg, n_windows=2)
         data, files = load_windows(str(tmp_path / "dump"))
         assert len(files) == 2
@@ -79,7 +79,7 @@ class TestTrainingDump:
         agg = Aggregator(APIServer(), model_mode="mlp",
                          training_dump_dir=str(tmp_path / "dump"),
                          node_bucket=8, workload_bucket=8)
-        agg._mesh = make_mesh()
+        agg.windows.mesh = make_mesh()
         rng = np.random.default_rng(0)
 
         class Req:
@@ -105,7 +105,7 @@ class TestTrainingDump:
                          training_dump_dir=str(tmp_path / "dump"),
                          training_dump_max_files=3,
                          node_bucket=8, workload_bucket=8)
-        agg._mesh = make_mesh()
+        agg.windows.mesh = make_mesh()
         feed_reports(agg, n_windows=5)
         _, files = load_windows(str(tmp_path / "dump"))
         assert len(files) == 3
@@ -117,7 +117,7 @@ class TestTrainCLI:
         agg = Aggregator(APIServer(), model_mode=None,
                          training_dump_dir=str(tmp_path / "dump"),
                          node_bucket=8, workload_bucket=8)
-        agg._mesh = make_mesh()
+        agg.windows.mesh = make_mesh()
         feed_reports(agg, n_windows=3)
         out = str(tmp_path / "params.npz")
         rc = train_main([
@@ -130,9 +130,9 @@ class TestTrainCLI:
         serve = Aggregator(APIServer(), model_mode=family,
                            model_params=params, node_bucket=8,
                            workload_bucket=8)
-        serve._mesh = make_mesh()
-        serve._check_params_shape()
-        assert serve._model_out_dim() == 2
+        serve.windows.mesh = make_mesh()
+        serve.windows._check_params_shape()
+        assert serve.windows._model_out_dim() == 2
 
     def test_temporal_end_to_end(self, tmp_path):
         """The fifth family closes the same loop: a TEMPORAL aggregator
@@ -144,7 +144,7 @@ class TestTrainCLI:
                          training_dump_dir=str(tmp_path / "dump"),
                          node_bucket=8, workload_bucket=8,
                          history_window=4)
-        agg._mesh = make_mesh()
+        agg.windows.mesh = make_mesh()
         feed_reports(agg, n_windows=3)
         data, files = load_windows(str(tmp_path / "dump"))
         assert "feat_hist" in data  # history windows captured for training
@@ -161,13 +161,13 @@ class TestTrainCLI:
         serve = Aggregator(APIServer(), model_mode="temporal",
                            model_params=params, node_bucket=8,
                            workload_bucket=8, history_window=4)
-        serve._mesh = make_mesh()
-        serve._check_params_shape()
-        assert serve._model_out_dim() == 2
+        serve.windows.mesh = make_mesh()
+        serve.windows._check_params_shape()
+        assert serve.windows._model_out_dim() == 2
         # and the serving program actually runs on the trained params
         feed_reports(serve, n_windows=2, seed=9)
-        with serve._results_lock:
-            assert serve._results
+        with serve.windows._results_lock:
+            assert serve.windows._results
 
     def test_temporal_without_history_dumps_errors(self, tmp_path):
         """Single-tick dumps (non-temporal aggregator) can't train the
@@ -175,7 +175,7 @@ class TestTrainCLI:
         agg = Aggregator(APIServer(), model_mode=None,
                          training_dump_dir=str(tmp_path / "dump"),
                          node_bucket=8, workload_bucket=8)
-        agg._mesh = make_mesh()
+        agg.windows.mesh = make_mesh()
         feed_reports(agg, n_windows=1)
         rc = train_main([
             "--data", str(tmp_path / "dump"), "--model", "temporal",
@@ -187,7 +187,7 @@ class TestTrainCLI:
         agg = Aggregator(APIServer(), model_mode=None,
                          training_dump_dir=str(tmp_path / "dump"),
                          node_bucket=8, workload_bucket=8)
-        agg._mesh = make_mesh()
+        agg.windows.mesh = make_mesh()
         feed_reports(agg, n_windows=2)
         out = str(tmp_path / "p.npz")
         ck = str(tmp_path / "ckpt")

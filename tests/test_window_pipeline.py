@@ -35,8 +35,8 @@ import pytest
 
 from kepler_tpu import fault
 from kepler_tpu.fault import FaultPlan, FaultSpec
-from kepler_tpu.fleet.aggregator import (RUNG_PACKED_SERIAL, RUNG_PIPELINED,
-                                         Aggregator, _Stored)
+from kepler_tpu.fleet.aggregator import Aggregator, _Stored
+from kepler_tpu.fleet.scheduler import RUNG_PACKED_SERIAL, RUNG_PIPELINED
 from kepler_tpu.fleet.window_record import LEGS, MARKS
 from kepler_tpu.fleet.window import BucketLadder
 from kepler_tpu.parallel.fleet import MODE_MODEL, MODE_RATIO, NodeReport
@@ -78,7 +78,7 @@ def make_agg(depth: int, **kw) -> Aggregator:
         agg.test_clock = ticks  # driven by run_schedule/seed helpers
     else:
         agg = Aggregator(APIServer(), pipeline_depth=depth, **kw)
-    agg._mesh = make_mesh()
+    agg.windows.mesh = make_mesh()
     return agg
 
 
@@ -122,7 +122,7 @@ def run_schedule(agg: Aggregator, schedules: list[dict]) -> list:
         result = agg.aggregate_once()
         if result is not None:
             published.append(result)
-    tail = agg._drain_pipeline()
+    tail = agg.windows.drain()
     if tail is not None:
         published.append(tail)
     return published
@@ -201,12 +201,12 @@ class TestPipelineDrain:
         agg = make_agg(2)
         seed_window(agg, churn_schedule(1)[0], 1e9)
         assert agg.aggregate_once() is None  # in flight, not published
-        assert len(agg._inflight) == 1
+        assert len(agg.windows._inflight) == 1
         agg.shutdown()
-        assert not agg._inflight
-        with agg._results_lock:
-            assert agg._results is not None
-        assert agg._stats["attributions_total"] == 1
+        assert not agg.windows._inflight
+        with agg.windows._results_lock:
+            assert agg.windows._results is not None
+        assert agg.windows._stats["attributions_total"] == 1
 
     def test_empty_fleet_drains_instead_of_rotting(self):
         agg = make_agg(2, stale_after=10.0, clock=lambda: clock[0])
@@ -216,8 +216,8 @@ class TestPipelineDrain:
         clock[0] += 100.0  # everything stale now
         result = agg.aggregate_once()  # empty fleet → drain
         assert result is not None
-        assert not agg._inflight
-        assert agg._stats["attributions_total"] == 1
+        assert not agg.windows._inflight
+        assert agg.windows._stats["attributions_total"] == 1
 
     def test_run_loop_exit_drains(self):
         from kepler_tpu.service.lifecycle import CancelContext
@@ -232,13 +232,13 @@ class TestPipelineDrain:
         import time as _t
 
         deadline = _t.monotonic() + 10
-        while (agg._stats["attributions_total"] == 0
+        while (agg.windows._stats["attributions_total"] == 0
                and _t.monotonic() < deadline):
             _t.sleep(0.02)
         ctx.cancel()
         t.join(timeout=10)
         assert not t.is_alive()
-        assert not agg._inflight
+        assert not agg.windows._inflight
 
     def test_published_results_at_most_one_interval_stale(self):
         agg = make_agg(2)
@@ -271,7 +271,7 @@ class TestMidPipelineChurn:
         seed_window(agg, win2, now)
         first = agg.aggregate_once()  # publishes window 1
         assert set(first.names) == set(win1)
-        second = agg._drain_pipeline()  # publishes window 2
+        second = agg.windows.drain()  # publishes window 2
         assert set(second.names) == set(win2)
         assert "n2" not in second.rows
         assert "n5" in second.rows
@@ -342,28 +342,28 @@ class TestDeltaAccounting:
         now = 1e9
         seed_window(agg, sched, now)
         agg.aggregate_once()
-        assert agg._stats["last_h2d_rows"] == 5  # rebuild packs all
+        assert agg.windows._stats["last_h2d_rows"] == 5  # rebuild packs all
         # same (run, seq) → nothing re-uploaded, on every ring buffer
         for _ in range(3):
             agg.aggregate_once()
-            assert agg._stats["last_h2d_rows"] == 0
+            assert agg.windows._stats["last_h2d_rows"] == 0
         # one change → staged once per ring buffer it must reach, then 0
         sched["n3"] = (99, ZONES, 1, 2, "r1")
         seed_window(agg, sched, now)
         staged = []
         for _ in range(4):
             agg.aggregate_once()
-            staged.append(agg._stats["last_h2d_rows"])
+            staged.append(agg.windows._stats["last_h2d_rows"])
         assert staged[0] == 1 and staged[-1] == 0
-        assert sum(staged) == len(agg._engine._buffers)
+        assert sum(staged) == len(agg.windows._engine._buffers)
         # the first delta compiled the scatter-update once; further
         # same-sized deltas never recompile
-        compiles = agg._stats["window_compiles_total"]
+        compiles = agg.windows._stats["window_compiles_total"]
         sched["n3"] = (123, ZONES, 1, 3, "r1")
         seed_window(agg, sched, now)
         agg.aggregate_once()
         agg.aggregate_once()
-        assert agg._stats["window_compiles_total"] == compiles
+        assert agg.windows._stats["window_compiles_total"] == compiles
 
     def test_pre_nonce_rows_always_reupload(self):
         agg = make_agg(1)
@@ -371,7 +371,7 @@ class TestDeltaAccounting:
         seed_window(agg, sched, 1e9)
         agg.aggregate_once()
         agg.aggregate_once()
-        assert agg._stats["last_h2d_rows"] == 1
+        assert agg.windows._stats["last_h2d_rows"] == 1
 
     def test_fleet_growth_compiles_once_per_rung(self):
         agg = make_agg(1, node_bucket=8)
@@ -379,17 +379,17 @@ class TestDeltaAccounting:
         sched = {f"n{i}": (i, ZONES, 0, 1, "r1") for i in range(5)}
         seed_window(agg, sched, now)
         agg.aggregate_once()
-        base_compiles = agg._stats["window_compiles_total"]
+        base_compiles = agg.windows._stats["window_compiles_total"]
         # grow past the node bucket: one new program + one new update
         sched.update({f"m{i}": (i, ZONES, 0, 1, "r1") for i in range(8)})
         seed_window(agg, sched, now)
         agg.aggregate_once()
-        grown = agg._stats["window_compiles_total"]
+        grown = agg.windows._stats["window_compiles_total"]
         assert grown > base_compiles
         # repeat windows at the new rung: no further compiles
         agg.aggregate_once()
         agg.aggregate_once()
-        assert agg._stats["window_compiles_total"] == grown
+        assert agg.windows._stats["window_compiles_total"] == grown
 
 
 class TestShardedWindow:
@@ -405,10 +405,10 @@ class TestShardedWindow:
         agg = make_agg(1)
         seed_window(agg, churn_schedule(1)[0], 1e9)
         agg.aggregate_once()
-        assert isinstance(agg._engine, ShardedWindowEngine)
-        assert agg._engine.n_shards == len(jax.devices())
-        assert agg._stats["window_shards"] == len(jax.devices())
-        assert len(agg._stats["last_h2d_shards"]) == len(jax.devices())
+        assert isinstance(agg.windows._engine, ShardedWindowEngine)
+        assert agg.windows._engine.n_shards == len(jax.devices())
+        assert agg.windows._stats["window_shards"] == len(jax.devices())
+        assert len(agg.windows._stats["last_h2d_shards"]) == len(jax.devices())
         health = agg.window_health()
         assert health["rung_name"] == "packed-sharded-pipelined"
         assert health["shards"] == len(jax.devices())
@@ -422,12 +422,12 @@ class TestShardedWindow:
                                              ShardedWindowEngine)
 
         agg = make_agg(1)
-        agg._mesh = make_mesh([4, 2], ["node", "model"])
+        agg.windows.mesh = make_mesh([4, 2], ["node", "model"])
         seed_window(agg, churn_schedule(1)[0], 1e9)
         agg.aggregate_once()
-        assert type(agg._engine) is PackedWindowEngine
-        assert not isinstance(agg._engine, ShardedWindowEngine)
-        assert agg._stats["window_shards"] == 1
+        assert type(agg.windows._engine) is PackedWindowEngine
+        assert not isinstance(agg.windows._engine, ShardedWindowEngine)
+        assert agg.windows._stats["window_shards"] == 1
         assert agg.window_health()["rung_name"] == "packed-pipelined"
         agg.shutdown()
 
@@ -439,7 +439,7 @@ class TestShardedWindow:
         schedules = churn_schedule(9)
         sharded = run_schedule(make_agg(depth), schedules)
         single = make_agg(1)
-        single._mesh = make_mesh([1], devices=jax.devices()[:1])
+        single.windows.mesh = make_mesh([1], devices=jax.devices()[:1])
         reference = run_schedule(single, schedules)
         assert len(sharded) == len(reference) == len(schedules)
         for a, b in zip(reference, sharded):
@@ -455,7 +455,7 @@ class TestShardedWindow:
         now = 1e9
         seed_window(agg, base, now)
         agg.aggregate_once()
-        engine = agg._engine
+        engine = agg.windows._engine
         slots = len(engine._buffers)
         # warm the delta path (every shard stages once, the scatter-
         # update compiles its one shared key), then settle to zero H2D
@@ -465,10 +465,10 @@ class TestShardedWindow:
         for _ in range(slots):
             agg.aggregate_once()
         agg.aggregate_once()
-        assert agg._stats["last_h2d_rows"] == 0
+        assert agg.windows._stats["last_h2d_rows"] == 0
         base = warm
         home = dict(engine._shard_of)
-        compiles = agg._stats["window_compiles_total"]
+        compiles = agg.windows._stats["window_compiles_total"]
 
         joined = dict(base)
         joined["n99"] = (99, ZONES, MODE_RATIO, 1, "r1")
@@ -476,12 +476,12 @@ class TestShardedWindow:
         touched = set()
         for _ in range(slots + 1):
             agg.aggregate_once()
-            staged = agg._stats["last_h2d_shards"]
+            staged = agg.windows._stats["last_h2d_shards"]
             touched |= {k for k, n in enumerate(staged) if n}
         # the join staged on exactly its shard (once per ring slot),
         # nothing recompiled, and nobody else moved or restaged
         assert touched == {engine._shard_of["n99"]}
-        assert agg._stats["window_compiles_total"] == compiles
+        assert agg.windows._stats["window_compiles_total"] == compiles
         assert {n: k for n, k in engine._shard_of.items()
                 if n != "n99"} == home
 
@@ -490,18 +490,18 @@ class TestShardedWindow:
         touched = set()
         for _ in range(slots + 1):
             agg.aggregate_once()
-            staged = agg._stats["last_h2d_shards"]
+            staged = agg.windows._stats["last_h2d_shards"]
             touched |= {k for k, n in enumerate(staged) if n}
         assert touched == {n99_shard}  # only the freed row's shard cleared
-        assert agg._stats["window_compiles_total"] == compiles
+        assert agg.windows._stats["window_compiles_total"] == compiles
         assert dict(engine._shard_of) == home
 
         joined["n99"] = (123, ZONES, MODE_RATIO, 2, "r1")  # rejoin, new data
         seed_window(agg, joined, now)
         result = agg.aggregate_once()
-        staged = agg._stats["last_h2d_shards"]
+        staged = agg.windows._stats["last_h2d_shards"]
         assert sum(1 for n in staged if n) == 1
-        assert agg._stats["window_compiles_total"] == compiles
+        assert agg.windows._stats["window_compiles_total"] == compiles
         assert {n: k for n, k in engine._shard_of.items()
                 if n != "n99"} == home
         # the rejoined node's published row is the FRESH report (old
@@ -521,13 +521,13 @@ class TestShardedWindow:
         sched = {f"n{i:02d}": (i, ZONES, i % 2, 1, "r1") for i in range(10)}
         seed_window(agg, sched, 1e9)
         agg.aggregate_once()
-        engine = agg._engine
+        engine = agg.windows._engine
         for _ in range(len(engine._buffers)):
             agg.aggregate_once()
         sched["n04"] = (321, ZONES, 0, 2, "r1")
         seed_window(agg, sched, 1e9)
         agg.aggregate_once()
-        staged = agg._stats["last_h2d_shards"]
+        staged = agg.windows._stats["last_h2d_shards"]
         owner = engine._shard_of["n04"]
         assert staged[owner] == 1
         assert sum(staged) == 1
@@ -547,15 +547,15 @@ class TestShardedWindow:
                  for i in range(n_dev)}
         seed_window(agg, sched, 1e9)
         agg.aggregate_once()
-        engine = agg._engine
-        compiles = agg._stats["window_compiles_total"]
+        engine = agg.windows._engine
+        compiles = agg.windows._stats["window_compiles_total"]
         sched.update({f"m{i:02d}": (50 + i, ZONES, i % 2, 1, "r1")
                       for i in range(4)})  # 12 nodes > 8 rows: overflow
         seed_window(agg, sched, 1e9)
         agg.aggregate_once()
-        staged = agg._stats["last_h2d_shards"]
+        staged = agg.windows._stats["last_h2d_shards"]
         assert all(n > 0 for n in staged)  # full rebalance restage
-        assert agg._stats["window_compiles_total"] > compiles
+        assert agg.windows._stats["window_compiles_total"] > compiles
         mode_arr = list(engine._mode)
         sb = engine._ladder_n.bucket
         per_shard_model = [
@@ -565,7 +565,7 @@ class TestShardedWindow:
         # steady again afterwards
         agg.aggregate_once()
         agg.aggregate_once()
-        assert agg._stats["window_compiles_total"] > compiles
+        assert agg.windows._stats["window_compiles_total"] > compiles
         agg.shutdown()
 
 
@@ -633,7 +633,7 @@ class ServedLoop:
         self.agg = make_agg(depth, interval=3600.0, **kw)
         self.gate = TickGate()
         self.published: list = []  # (FleetResults, thread name)
-        inner = self.agg._publish
+        inner = self.agg.windows._publish
 
         def publish(p, on_loop=True):
             results = inner(p, on_loop=on_loop)
@@ -641,7 +641,7 @@ class ServedLoop:
                                    threading.current_thread().name))
             return results
 
-        self.agg._publish = publish
+        self.agg.windows._publish = publish
         self.thread = threading.Thread(target=self.agg.run,
                                        args=(self.gate,), daemon=True)
         self.thread.start()
@@ -654,8 +654,8 @@ class ServedLoop:
         self.gate.tick()
 
     def attributions(self) -> int:
-        with self.agg._results_lock:
-            return self.agg._stats["attributions_total"]
+        with self.agg.windows._results_lock:
+            return self.agg.windows._stats["attributions_total"]
 
     def stop(self) -> None:
         self.gate.cancel()
@@ -678,8 +678,8 @@ class TestPublishedOnCompletion:
             # the loop is back in its wait and no second tick ever comes
             wait_until(lambda: loop.attributions() == 1,
                        "the first window's publication")
-            assert loop.agg._window_seq == 1 and loop.gate.arrivals == 2
-            assert not loop.agg._inflight
+            assert loop.agg.windows._window_seq == 1 and loop.gate.arrivals == 2
+            assert not loop.agg.windows._inflight
             status, _hdr, body = loop.agg._handle_results(
                 _Request("/v1/results?node=n00"))
             assert status == 200
@@ -711,9 +711,9 @@ class TestPublishedOnCompletion:
         for a, b in zip(serial, served):
             assert a.timestamp == b.timestamp
             assert_windows_equal(a, b)
-        assert loop.agg._stats["attributions_total"] == len(schedules)
-        assert loop.agg._rung == RUNG_PIPELINED
-        assert loop.agg._stats["window_demotions_total"] == 0
+        assert loop.agg.windows._stats["attributions_total"] == len(schedules)
+        assert loop.agg.windows._rung == RUNG_PIPELINED
+        assert loop.agg.windows._stats["window_demotions_total"] == 0
 
     def test_stressed_loop_publisher_and_readers_lose_no_window(self):
         """The loop, the publisher and four readers of what they publish,
@@ -758,9 +758,9 @@ class TestPublishedOnCompletion:
         assert [r.timestamp for r in served] == [r.timestamp for r in serial]
         for a, b in zip(serial, served):
             assert_windows_equal(a, b)
-        assert loop.agg._stats["attributions_total"] == len(schedules)
-        assert loop.agg._stats["window_demotions_total"] == 0
-        assert not loop.agg._inflight
+        assert loop.agg.windows._stats["attributions_total"] == len(schedules)
+        assert loop.agg.windows._stats["window_demotions_total"] == 0
+        assert not loop.agg.windows._inflight
 
     @pytest.mark.parametrize("depth", [2, 3])
     def test_never_more_than_depth_windows_in_flight(self, depth):
@@ -775,21 +775,21 @@ class TestPublishedOnCompletion:
                 self.peak = max(self.peak, len(self))
 
         loop = ServedLoop(depth, dispatch_timeout=DEADLINE)
-        loop.agg._inflight = Watched()
+        loop.agg.windows._inflight = Watched()
         plan = FaultPlan([FaultSpec(site="device.stall", arg=0.2)])
         try:
             with fault.installed(plan):
                 for sched in churn_schedule(6):
                     loop.step(sched)
-                    assert len(loop.agg._inflight) < depth
+                    assert len(loop.agg.windows._inflight) < depth
                 loop.stop()
         finally:
             loop.stop()
-        assert loop.agg._inflight.peak <= depth
+        assert loop.agg.windows._inflight.peak <= depth
         assert plan.fired("device.stall") == 6
         stamps = [res.timestamp for res, _thread in loop.published]
         assert len(stamps) == 6 and stamps == sorted(set(stamps))
-        assert loop.agg._stats["window_demotions_total"] == 0
+        assert loop.agg.windows._stats["window_demotions_total"] == 0
 
     @pytest.mark.parametrize("how", ["stall", "error"])
     def test_a_failed_early_fetch_is_raised_by_the_next_step(self, how):
@@ -802,7 +802,7 @@ class TestPublishedOnCompletion:
         plan = FaultPlan([FaultSpec(site="device.stall", count=1, arg=2.0)]
                          if how == "stall" else [])
         if how == "error":
-            inner, calls = loop.agg._fetch_device, []
+            inner, calls = loop.agg.windows._fetch_device, []
 
             def fetch_device(fn):
                 calls.append(1)
@@ -810,25 +810,25 @@ class TestPublishedOnCompletion:
                     raise RuntimeError("the device is gone")
                 return inner(fn)
 
-            loop.agg._fetch_device = fetch_device
+            loop.agg.windows._fetch_device = fetch_device
         schedules = churn_schedule(3)
         try:
             with fault.installed(plan):
                 loop.step(schedules[0])
 
                 def failed() -> bool:
-                    with loop.agg._pipeline_lock:
-                        return bool(loop.agg._inflight) and \
-                            loop.agg._inflight[0].failure is not None
+                    with loop.agg.windows._pipeline_lock:
+                        return bool(loop.agg.windows._inflight) and \
+                            loop.agg.windows._inflight[0].failure is not None
 
                 wait_until(failed, "the publisher's failure")
                 # the publisher neither publishes nor demotes
                 assert loop.attributions() == 0
-                assert loop.agg._stats["window_demotions_total"] == 0
+                assert loop.agg.windows._stats["window_demotions_total"] == 0
                 loop.step(schedules[1])
                 assert loop.attributions() == 1
-                assert loop.agg._rung == RUNG_PACKED_SERIAL
-                assert loop.agg._demotions_by_reason == {
+                assert loop.agg.windows._rung == RUNG_PACKED_SERIAL
+                assert loop.agg.windows._demotions_by_reason == {
                     "stall" if how == "stall" else "runtime_error": 1}
                 loop.step(schedules[2])
         finally:
@@ -836,8 +836,8 @@ class TestPublishedOnCompletion:
         stamps = [res.timestamp for res, _thread in loop.published]
         base = loop.agg.test_clock[0] - 15.0
         assert stamps == [base + 10.0, base + 15.0]  # each once, in order
-        assert not loop.agg._inflight
-        assert loop.agg._stats["window_demotions_total"] == 1
+        assert not loop.agg.windows._inflight
+        assert loop.agg.windows._stats["window_demotions_total"] == 1
 
     def test_cancel_during_a_publication_drains_every_window(self):
         loop = ServedLoop(2, dispatch_timeout=DEADLINE)
@@ -854,9 +854,9 @@ class TestPublishedOnCompletion:
                 loop.stop()
         finally:
             loop.stop()
-        assert not loop.agg._inflight
-        assert loop.agg._window_seq == 3
-        assert loop.agg._stats["attributions_total"] == 3
+        assert not loop.agg.windows._inflight
+        assert loop.agg.windows._window_seq == 3
+        assert loop.agg.windows._stats["attributions_total"] == 3
         stamps = [res.timestamp for res, _thread in loop.published]
         assert stamps == sorted(set(stamps)) and len(stamps) == 3
 
@@ -890,8 +890,8 @@ class TestPublishedOnCompletion:
         for depth, want in ((1, 3), (2, 1)):
             agg = make_agg(depth)
             run_schedule(agg, churn_schedule(3))
-            assert agg._stats["attributions_total"] == 3
-            assert agg._stats["published_early_total"] == want
-            assert agg._window_ledger.counts["published_early"] == want
-            assert agg._publisher is None
+            assert agg.windows._stats["attributions_total"] == 3
+            assert agg.windows._stats["published_early_total"] == want
+            assert agg.windows._window_ledger.counts["published_early"] == want
+            assert agg.windows._publisher is None
             agg.shutdown()

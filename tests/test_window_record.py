@@ -110,7 +110,7 @@ def served(request):
         for _ in range(WINDOWS):
             published.append(s.window())
             bodies.append(s.get("/debug/window"))
-        published.append(s.agg._drain_pipeline())
+        published.append(s.agg.windows.drain())
         bodies.append(s.get("/debug/window"))
         yield s, rec, bodies, published
         s.close()
@@ -274,7 +274,7 @@ def test_an_empty_fleet_opens_no_cycle_and_takes_no_sequence_number():
         agg = Aggregator(APIServer(), model_mode="temporal")
         assert agg.aggregate_once() is None
         assert agg.aggregate_once() is None
-    assert rec.recent_traces() == [] and agg._window_seq == 0
+    assert rec.recent_traces() == [] and agg.windows._window_seq == 0
     assert json.loads(agg._handle_window_debug(None)[2])["records"] == {
         "fields": list(FIELDS), "rows": [],
         "legs": {k: list(v) for k, v in LEGS.items()}}
@@ -289,7 +289,7 @@ def test_the_run_loop_times_its_wait_as_the_next_windows_tick_leg():
         loop = threading.Thread(target=s.agg.run, args=(ctx,), daemon=True)
         loop.start()
         deadline = time.monotonic() + 20
-        while s.agg._window_seq < 3 and time.monotonic() < deadline:
+        while s.agg.windows._window_seq < 3 and time.monotonic() < deadline:
             time.sleep(0.01)
         ctx.cancel()
         loop.join(timeout=20)

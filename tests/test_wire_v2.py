@@ -631,7 +631,7 @@ class TestUnchangedFleetZeroStaging:
         for b in bases:
             assert post_raw(server, b).status == 204
         assert agg.aggregate_once() is not None
-        first_h2d = agg._stats["last_h2d_rows"]
+        first_h2d = agg.windows._stats["last_h2d_rows"]
         assert first_h2d == 3
         # every node re-reports unchanged via FLAG_SAME deltas
         for win in (2, 3):
@@ -641,7 +641,7 @@ class TestUnchangedFleetZeroStaging:
                 assert parse_header(same).same
                 assert post_raw(server, same).status == 204
             assert agg.aggregate_once() is not None
-            assert agg._stats["last_h2d_rows"] == 0
+            assert agg.windows._stats["last_h2d_rows"] == 0
         # one node actually changes → exactly one row restages
         changed = make_report("z-1", seed=99)
         delta = encode_delta_v2(kf_bytes(changed, seq=4, run="run-1"),
@@ -649,7 +649,7 @@ class TestUnchangedFleetZeroStaging:
         assert not parse_header(delta).same
         assert post_raw(server, delta).status == 204
         assert agg.aggregate_once() is not None
-        assert agg._stats["last_h2d_rows"] == 1
+        assert agg.windows._stats["last_h2d_rows"] == 1
         agg.shutdown()
 
 
