@@ -213,10 +213,13 @@ def _build_fleet(case: ProgramCase) -> tuple:
         _sds((n,), _F32), _sds((n,), _I32),
     )
     if d.get("temporal"):
-        t, f = d["t"], 7
-        fn = make_temporal_fleet_program(mesh)
+        # the program the aggregator serves: the history as its valid rows
+        # (``r`` a shard), expanded on each device
+        t, f, r = d["t"], 7, d["r"]
+        fn = make_temporal_fleet_program(mesh, compact=True)
         return fn, (_temporal_avals(z),) + batch + (
-            _sds((n, w, t, f), _F32), _sds((n, w, t), _BOOL))
+            _sds((8, r, t * f), _F32), _sds((8, r, t), _BOOL),
+            _sds((8, n // 8 * w), _I32))
     fn = make_fleet_program(mesh, model_mode="mlp")
     return fn, (_mlp_avals(z),) + batch
 
@@ -540,12 +543,13 @@ DEVICE_PROGRAMS: tuple[ProgramSpec, ...] = (
     ProgramSpec(
         name="fleet.temporal",
         source="kepler_tpu/parallel/aggregator_core.py",
-        description="temporal fleet program (dense causal attention over "
-                    "per-workload history windows)",
+        description="temporal fleet program as served (dense causal "
+                    "attention over per-workload history windows, sent as "
+                    "their valid rows and expanded shard by shard)",
         build=_build_fleet,
         cases=(
-            ProgramCase("n8_w4_t8_z2",
-                        dims={"n": 8, "w": 4, "z": 2, "t": 8,
+            ProgramCase("n16_w4_t8_z2_r4",
+                        dims={"n": 16, "w": 4, "z": 2, "t": 8, "r": 4,
                               "temporal": 1}),
         ),
         allowed_half_casts=_BF16_OPS,

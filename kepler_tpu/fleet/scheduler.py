@@ -42,14 +42,16 @@ from jax.profiler import TraceAnnotation
 
 from kepler_tpu import fault, telemetry
 from kepler_tpu.fleet.journal import EventJournal
-from kepler_tpu.fleet.window import (DeviceWindowError, FusedFlush,
-                                     FusedWindowEngine,
+from kepler_tpu.fleet.window import (BucketLadder, DeviceWindowError,
+                                     FusedFlush, FusedWindowEngine,
                                      MultiHostWindowEngine,
                                      PackedWindowEngine, RowInput,
                                      ShardedWindowEngine, WindowMeta,
                                      align_zone_matrices)
 from kepler_tpu.fleet.window_record import WindowLedger, WindowRecord
 from kepler_tpu.parallel.aggregator_core import (
+    HISTORY_ROWS_BASE,
+    compact_history,
     fleet_shardings,
     make_fleet_program,
     make_temporal_fleet_program,
@@ -212,6 +214,9 @@ class _Pending:
     zone_names: list | None = None
     feat_hist: object = None
     t_valid: object = None
+    # the compact history this window put (compact_history's arrays):
+    # the window's until its outputs are fetched, then a later window's
+    history_rows: tuple | None = None
     # what the served loop's publisher thread caught while publishing
     # this window: it stays at the head of the deque and the loop's next
     # step (or a drain) raises it where a failed fetch was always raised
@@ -490,7 +495,9 @@ class WindowScheduler:
         self._cum_zones: list[str] = []
         self._cum_last_seen: dict[str, float] = {}
         self._cum_retention = cum_retention
-        self._program: Any = None  # serial-path jit; jax caches per shape
+        # serial-path jit (jax caches per shape); the temporal program in
+        # its two forms, {compact: jit}
+        self._program: Any = None
         self._legacy_compiles = 0  # its cold dispatches (loop thread)
         # one record per window (fleet/window_record.py): the id the next
         # snapshot takes, when the loop began the wait before it, and the
@@ -510,6 +517,18 @@ class WindowScheduler:
         # depth; a drain. Never held during dispatch.
         self._pipeline_depth = max(1, int(pipeline_depth))
         self._bucket_shrink_after = max(1, int(bucket_shrink_after))
+        # the rows of history a shard of the serial temporal program is
+        # sent: the fullest shard's valid rows, on a ladder of its own
+        self._history_rows = BucketLadder(HISTORY_ROWS_BASE,
+                                          self._bucket_shrink_after)
+        # ... and the host arrays of published windows, for the next to
+        # write into: a fresh 29 MB block is 7,000 page faults, 27 ms on
+        # the chip's host, whenever the allocator has handed the freed one
+        # back to the system, and whether it does differs run to run
+        # (PERF.md section 6). Appended by whoever publishes, popped by
+        # the loop: a deque's two ends are atomic
+        self._history_spare: collections.deque[tuple] = collections.deque(
+            maxlen=self._pipeline_depth + 1)
         self._pipeline_lock = threading.Lock()
         self._inflight: collections.deque[_Pending] = collections.deque()  # keplint: guarded-by=_pipeline_lock
         # the publisher sleeps on the condition until the loop appends a
@@ -1482,9 +1501,14 @@ class WindowScheduler:
                         "compile_error",
                         "injected compile failure (serial fleet program)")
                 if temporal:
-                    self._program = make_temporal_fleet_program(
-                        self.mesh, backend=self._backend,
-                        accuracy_mode=self._accuracy_mode)
+                    # in both forms: the history goes up compact wherever
+                    # that is fewer rows, dense where it is not
+                    self._program = {
+                        compact: make_temporal_fleet_program(
+                            self.mesh, backend=self._backend,
+                            accuracy_mode=self._accuracy_mode,
+                            compact=compact)
+                        for compact in (False, True)}
                 else:
                     self._program = make_fleet_program(
                         self.mesh, model_mode=self._model_mode,
@@ -1509,9 +1533,20 @@ class WindowScheduler:
                 "injected dispatch failure (serial fleet program)")
         # every device is sent its own nodes' rows, and nothing else
         rec.devices = int(self.mesh.devices.size)
+        # and of the history only the rows that hold a tick: the leg is the
+        # compaction on the host and the put's return (the bytes land later)
         with rec.leg("window.h2d", devices=rec.devices):
-            args = put_fleet_batch(batch, params, feat_hist, t_valid,
-                                   mesh=self.mesh)
+            history = (feat_hist, t_valid)
+            rows = None
+            if temporal:
+                spare = self._history_spare
+                rows = compact_history(feat_hist, t_valid,
+                                       self.mesh.shape[NODE_AXIS],
+                                       self._history_rows.fit,
+                                       out=spare.pop() if spare else None)
+                program = program[rows is not None]
+                history = rows or history
+            args = put_fleet_batch(batch, params, *history, mesh=self.mesh)
         # ASYNC dispatch: jax returns device futures at once, and the D2H
         # copies start NOW (queued behind the compute on the device
         # stream), not at _publish's np.asarray. The FIRST dispatch blocks
@@ -1538,6 +1573,8 @@ class WindowScheduler:
             counts = np.asarray(batch.workload_counts)
             rec.rows_work = int(counts[
                 batch.mode[:len(counts)] == MODE_MODEL].sum())
+        if temporal:  # S · R; of the dense window, N · W
+            rec.hist_rows_sent = math.prod(args[9].shape[:2])
         rec.h2d_bytes = sum(int(a.nbytes) for a in args[1:])
         # a NamedSharding's shards are all of one shape, so the device
         # that was sent most was sent one shard of every argument
@@ -1548,7 +1585,7 @@ class WindowScheduler:
             kind="legacy", out=result, meta=None, now=now, rec=rec,
             h2d_rows=batch.n_nodes,
             batch=batch, aligned=aligned, zone_names=zone_names,
-            feat_hist=feat_hist, t_valid=t_valid)
+            feat_hist=feat_hist, t_valid=t_valid, history_rows=rows)
 
     def _dispatch_numpy(self, stored_sorted: Reports, zone_names: list[str],
                         now: float, rec: WindowRecord) -> _Pending:
@@ -1650,6 +1687,9 @@ class WindowScheduler:
                     np.asarray(result.workload_power_uw),
                     np.asarray(result.workload_energy_uj)))
             node_power, node_energy, wl_power, wl_energy = fetched
+            if p.history_rows is not None:
+                # the program has run: the device is done with its inputs
+                self._history_spare.append(p.history_rows)
             with rec.leg("window.scatter"):
                 results = self._scatter_legacy(p, node_power, node_energy,
                                                wl_power, wl_energy)

@@ -65,7 +65,8 @@ LEGS = {
 # ROW_COUNTS are columns of a served row too; what the put over the mesh
 # counts is served in the sums alone (``chipbench`` pins a row's columns)
 ROW_COUNTS = ("rows_program", "rows_work", "h2d_bytes")
-SUM_COUNTS = ("devices", "h2d_bytes_max_device", "published_early")
+SUM_COUNTS = ("devices", "h2d_bytes_max_device", "published_early",
+              "hist_rows_sent")
 COUNTS = ROW_COUNTS + SUM_COUNTS
 
 FIELDS = ("seq", "stamp", "kind") + MARKS + ("assembly_cpu_s",) \
@@ -116,6 +117,9 @@ class WindowRecord:
         # 1 where the publication began before a later window took its
         # sequence number: the window did not wait for the loop's next step
         self.published_early = 0
+        # history rows the temporal program was sent: shards × the bucket
+        # that holds the fullest shard's valid rows (padding included)
+        self.hist_rows_sent = 0
         self.compiled = False
 
     @contextlib.contextmanager

@@ -21,10 +21,12 @@ watts scaled onto their (unknown) zone axis.
 from __future__ import annotations
 
 import functools
+from collections.abc import Callable
 from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kepler_tpu.models.estimator import predictor
@@ -258,20 +260,155 @@ def make_fleet_program(mesh: Mesh, model_mode: str | None = None,
     )
 
 
+# the smallest bucket of history rows a compact put sends a shard (the
+# base of the ladder whose ``fit`` the caller hands to put_fleet_batch)
+HISTORY_ROWS_BASE = 1024
+
+
+def _rows_with_a_tick(t_valid: np.ndarray) -> np.ndarray:
+    """``t_valid.any(-1)``. NumPy's reduction over a short last axis costs
+    80 ns a row (22 ms at 5000 nodes x 256 slots), so where the layout
+    allows, a row's T bools are read as T/8 words and the words or-ed."""
+    t = t_valid.shape[-1]
+    if t % 8 or not t_valid.flags.c_contiguous:
+        return t_valid.any(-1)
+    words = t_valid.view(np.uint64)
+    hit = words[..., 0]
+    for k in range(1, t // 8):
+        hit = hit | words[..., k]
+    return hit != 0
+
+
+def compact_history(
+    feat_hist: np.ndarray,  # f32 [N, W, T, F]
+    t_valid: np.ndarray,  # bool [N, W, T]
+    n_shards: int,
+    fit_rows: Callable[[int], int],
+    out: tuple[np.ndarray, np.ndarray, np.ndarray] | None = None,
+) -> tuple[np.ndarray, np.ndarray, np.ndarray] | None:
+    """The dense history windows as their valid rows only, a block a
+    shard of the node axis: → (hist_rows f32 [S, R, T·F], tv_rows bool
+    [S, R, T], row_of int32 [S, N/S·W]); None where that is no fewer rows
+    than the dense window has (a shard's rows fit the smallest bucket, or
+    nearly all hold a tick): the dense arrays are the put then.
+
+    A row is sent if any of its ticks is valid. The others are all zeros
+    (``Aggregator._history_windows`` writes into ``np.zeros`` and
+    ``HistoryBuffer.window_arrays`` leaves an empty slot's rows zero; the
+    estimator reads position 0 of such a row: 0.0), so leaving them out
+    changes no bit of what :func:`expand_history` hands the program.
+    ``row_of`` says, for every dense row of the shard, which compact row
+    it is; ``R``, past the end, for a row that was not sent: the device
+    reads that as zeros, so no padding entry can touch a real row. ``R``
+    is ``fit_rows`` of the fullest shard's count (a ``BucketLadder.fit``:
+    the program sees one shape while the count wanders). The rows go up flat,
+    ``[R, T·F]``: on a TPU v5e that lands in 9 ms where ``[R, T, F]``
+    takes 15 (PERF.md section 5).
+
+    ``out``: three arrays an earlier call returned, to write into if their
+    shapes still fit, and ONLY once the device has consumed them: the put
+    returns before the bytes have left the host and two windows are in
+    flight, so a block is its window's until that window's outputs are
+    fetched. Without it the arrays are new."""
+    n, w, t, f = feat_hist.shape
+    per = n // n_shards * w  # a shard's dense rows
+    dense_hist = feat_hist.reshape(n_shards, per, t * f)
+    dense_tv = t_valid.reshape(n_shards, per, t)
+    sent = [np.flatnonzero(hit) for hit in _rows_with_a_tick(dense_tv)]
+    r = fit_rows(max(len(at) for at in sent))
+    if r >= per:
+        return None
+    if out is not None and out[0].shape == (n_shards, r, t * f) \
+            and out[2].shape == (n_shards, per):
+        hist_rows, tv_rows, row_of = out
+    else:
+        hist_rows = np.empty((n_shards, r, t * f), feat_hist.dtype)
+        tv_rows = np.empty((n_shards, r, t), bool)
+        row_of = np.empty((n_shards, per), np.int32)
+    # every element is written once: the index, then rows and padding
+    row_of.fill(r)
+    for s, at in enumerate(sent):
+        k = len(at)
+        # any mode but "raise" lets take() write straight into ``out``
+        np.take(dense_hist[s], at, axis=0, out=hist_rows[s, :k], mode="clip")
+        np.take(dense_tv[s], at, axis=0, out=tv_rows[s, :k], mode="clip")
+        hist_rows[s, k:] = 0.0
+        tv_rows[s, k:] = False
+        row_of[s, at] = np.arange(k, dtype=np.int32)
+    return hist_rows, tv_rows, row_of
+
+
+def expand_history(
+    hist_rows: jax.Array,  # f32 [1, R, T·F]: this shard's block
+    tv_rows: jax.Array,  # bool [1, R, T]
+    row_of: jax.Array,  # int32 [1, n·W]
+    n_workloads: int,
+) -> tuple[jax.Array, jax.Array]:
+    """One shard's dense ``(feat_hist [n, W, T, F], t_valid [n, W, T])``
+    from what :func:`compact_history` sent it: a gather by ``row_of``, an
+    index past the end reading zeros. Shard-local: no row crosses
+    devices."""
+    t = tv_rows.shape[-1]
+    hist = jnp.take(hist_rows[0], row_of[0], axis=0, mode="fill",
+                    fill_value=0)
+    tv = jnp.take(tv_rows[0], row_of[0], axis=0, mode="fill",
+                  fill_value=False)
+    return (hist.reshape(-1, n_workloads, t, hist.shape[-1] // t),
+            tv.reshape(-1, n_workloads, t))
+
+
+def _on_expanded_history(dense, mesh: Mesh | None):
+    """``dense`` (the temporal program) behind :func:`expand_history`: →
+    a program of :func:`compact_history`'s three arrays in the dense
+    history's place. ``mesh``: run the expansion as a ``shard_map`` over
+    its node axis (the blocks' leading axis counts shards, not nodes);
+    None where the caller is per shard already."""
+    def fn(params, *args):
+        data, rows = args[:-3], args[-3:]
+        expand = functools.partial(expand_history,
+                                   n_workloads=data[3].shape[1])
+        if mesh is not None:
+            expand = jax.shard_map(expand, mesh=mesh,
+                                   in_specs=(P(NODE_AXIS),) * 3,
+                                   out_specs=(P(NODE_AXIS),) * 2)
+        hist, tv = expand(*rows)
+        # the dense history is built whole before the dense program reads
+        # it: fused into the program's first relayout the gather would
+        # save 1.2 ms of 54 a window and compile in 16 s, not 4, at every
+        # new bucket of rows (TPU v5e: PERF.md section 6)
+        hist = jax.lax.optimization_barrier(hist)
+        return dense(params, *data, hist, tv)
+
+    return fn
+
+
 def make_temporal_fleet_program(mesh: Mesh, backend: str = "einsum",
-                                accuracy_mode: bool = False):
+                                accuracy_mode: bool = False,
+                                compact: bool = False):
     """jit the TEMPORAL fleet program (extra ``feat_hist``/``t_valid``
     inputs, node-axis sharded). Params replicate — the model is tiny; for
-    very long windows serve through ``parallel.sequence`` instead."""
+    very long windows serve through ``parallel.sequence`` instead.
+
+    ``compact``: the program the aggregator serves. In the place of the
+    dense history it takes :func:`compact_history`'s three arrays, each
+    shard's block on its own device, rebuilds the dense arrays there
+    (:func:`expand_history`) and runs the same program on them."""
     replicated, by_node = fleet_shardings(mesh)
     fn = functools.partial(temporal_fleet_program,
                            attribute_fn=resolve_attribute_fn(mesh, backend),
                            accuracy_mode=accuracy_mode)
+    n_history = 2
+    if compact:
+        n_history = 3
+        # under the pallas backend's shard_map the program is per shard
+        # already; GSPMD partitions the einsum one, and there the
+        # expansion alone is spelled per shard
+        fn = _on_expanded_history(fn, None if backend == "pallas" else mesh)
     if backend == "pallas":
         data_specs = (P(NODE_AXIS, None), P(NODE_AXIS, None), P(NODE_AXIS),
                       P(NODE_AXIS, None), P(NODE_AXIS, None), P(NODE_AXIS),
-                      P(NODE_AXIS), P(NODE_AXIS), P(NODE_AXIS),
-                      P(NODE_AXIS))
+                      P(NODE_AXIS), P(NODE_AXIS)) \
+            + (P(NODE_AXIS),) * n_history
         fn = shard_by_node(fn, mesh, in_specs=(P(),) + data_specs)
 
     def temporal_fleet_window(*args):  # named as fleet_window is
@@ -279,7 +416,7 @@ def make_temporal_fleet_program(mesh: Mesh, backend: str = "einsum",
 
     return jax.jit(
         temporal_fleet_window,
-        in_shardings=(replicated,) + (by_node,) * 10,
+        in_shardings=(replicated,) + (by_node,) * (8 + n_history),
         out_shardings=by_node,
     )
 
@@ -289,6 +426,7 @@ def put_fleet_batch(
     model_params: Any = None,
     feat_hist=None,  # [N, W, T, F] — temporal programs only
     t_valid=None,  # [N, W, T]
+    row_of=None,  # [S, N/S·W]: the history is compact_history's blocks
     mesh: Mesh | None = None,
 ) -> list:
     """The H2D half of the host entry: every argument of a fleet program
@@ -300,7 +438,11 @@ def put_fleet_batch(
     other device's, and the jit has nothing to move; over one device that
     is the whole array on that device. Without it every argument goes
     whole to the default device and the jit moves what belongs elsewhere
-    (the library entry, :func:`run_fleet_attribution`)."""
+    (the library entry, :func:`run_fleet_attribution`).
+
+    With ``row_of``, ``feat_hist`` and ``t_valid`` are the blocks of
+    :func:`compact_history` (a block a shard: the mesh's node axis), for
+    the ``compact`` temporal program."""
     if model_params is None:
         model_params = jnp.zeros(())
     data = [batch.zone_deltas_uj, batch.zone_valid, batch.usage_ratio,
@@ -308,6 +450,8 @@ def put_fleet_batch(
             batch.dt_s, batch.mode]
     if feat_hist is not None:
         data += [feat_hist, t_valid]
+    if row_of is not None:
+        data.append(row_of)
     if mesh is None:
         return [model_params] + [jnp.asarray(a) for a in data]
     replicated, by_node = fleet_shardings(mesh)

@@ -4,7 +4,11 @@
 with the sharding the program declares for it, so each device of the mesh
 takes its own nodes' rows straight from the host and the jit moves
 nothing; over a mesh of one device that is the whole array on that device,
-bit for bit what the put without a mesh gives. Here on the CPU's virtual
+bit for bit what the put without a mesh gives. Since ISSUE 37 the
+served temporal window sends its history as the rows that hold a tick
+(``compact_history``) and the program rebuilds the dense arrays on each
+device (``make_temporal_fleet_program(compact=True)``); the dense entry
+stays for the library. Here on the CPU's virtual
 devices (conftest gives eight): counts, placements and published values,
 never a time. The served path against the plain reference, on a child with
 four devices, is ``tests/chipbench/test_four_chip_cell.py``.
@@ -12,6 +16,8 @@ four devices, is ``tests/chipbench/test_four_chip_cell.py``.
 
 from __future__ import annotations
 
+import collections
+import dataclasses
 import os
 import urllib.request
 
@@ -22,7 +28,10 @@ import pytest
 from kepler_tpu import telemetry
 from kepler_tpu.fleet.wire import encode_report
 from kepler_tpu.models.temporal import init_temporal
-from kepler_tpu.parallel.aggregator_core import (fleet_shardings,
+from kepler_tpu.fleet.window import BucketLadder
+from kepler_tpu.parallel.aggregator_core import (_rows_with_a_tick,
+                                                 compact_history,
+                                                 fleet_shardings,
                                                  make_fleet_program,
                                                  make_temporal_fleet_program,
                                                  put_fleet_batch)
@@ -229,6 +238,288 @@ def test_the_window_over_four_devices_is_the_window_over_one():
     for placed, n_dev in ((placed4, 4), (placed1, 1)):
         for leaf in jax.tree.leaves(placed[1]):
             assert len(leaf.devices()) == n_dev
+
+
+# -- only the history rows that exist go up (ISSUE 37) ------------------------
+
+COLLECTIVES = ("all-reduce", "all-gather", "all-to-all",
+               "collective-permute", "reduce-scatter")
+
+
+def ragged_inputs(seed: int = 0, few: bool = False):
+    """The fleet of ``temporal_inputs`` with histories as the served path
+    has them: a ratio node pushes none (its rows stay zero), a model
+    node's pod has 0…T ticks, right-padded, and zeros wherever no tick is
+    valid; node 5 has no pods at all. ``few``: one pod a model node."""
+    reports = [report(k, 1) for k in range(NODES)]
+    reports[5] = dataclasses.replace(
+        reports[5], cpu_deltas=np.zeros(0, np.float32), workload_ids=[],
+        workload_kinds=np.zeros(0, np.int8), node_cpu_delta=0.0)
+    batch = assemble_fleet_batch(reports, n_zones=2, node_bucket=NODES,
+                                 workload_bucket=SLOTS)
+    rng = np.random.default_rng(seed)
+    ticks = rng.integers(0, TICKS + 1, (NODES, SLOTS))
+    ticks[0::2] = 0  # ratio nodes between the model nodes
+    for k, r in enumerate(reports):
+        ticks[k, (1 if few and k % 2 else len(r.workload_ids)):] = 0
+    t_valid = np.arange(TICKS) < ticks[..., None]
+    hist = rng.random((NODES, SLOTS, TICKS, FEATURES), np.float32)
+    hist *= t_valid[..., None]
+    return batch, seeded_params(), hist, t_valid
+
+
+def compact_put(batch, params, hist, t_valid, mesh, fit_rows) -> list:
+    """The put as ``WindowScheduler._dispatch_legacy`` makes it where the
+    bucket holds fewer rows than the dense window."""
+    rows = compact_history(hist, t_valid, mesh.devices.size, fit_rows)
+    assert rows is not None
+    return put_fleet_batch(batch, params, *rows, mesh=mesh)
+
+
+@pytest.mark.parametrize("n_dev", [1, 4, 8])
+@pytest.mark.parametrize("backend", ["einsum", "pallas"])
+def test_the_compact_put_and_program_publish_what_the_dense_entry_does(
+        n_dev, backend):
+    """Every field of the ``FleetResult``, bit for bit, over 1, 4 and 8
+    shards, with rows of padding in every shard's block."""
+    mesh = mesh_of(n_dev)
+    _, by_node = fleet_shardings(mesh)
+    inputs = ragged_inputs()
+    hist, t_valid = inputs[2:]
+    sent = t_valid.any(-1).reshape(n_dev, -1).sum(1)
+    # ragged: a shard holds more rows than another, a row 1…T ticks
+    assert n_dev == 1 or sent.min() < sent.max()
+    assert set(t_valid.sum(-1).ravel()) == set(range(TICKS + 1))
+    want = make_temporal_fleet_program(mesh, backend=backend)(
+        *put_fleet_batch(*inputs, mesh=mesh))
+    ladder = BucketLadder(2, 16)
+    args = compact_put(*inputs, mesh, ladder.fit)
+    assert len(args) == 12
+    rows, tv_rows, row_of = args[-3:]
+    per = NODES // n_dev * SLOTS
+    assert sent.max() <= ladder.bucket < per  # padding, and fewer rows
+    assert rows.shape == (n_dev, ladder.bucket, TICKS * FEATURES)
+    assert tv_rows.shape == (n_dev, ladder.bucket, TICKS)
+    assert row_of.shape == (n_dev, per) and row_of.dtype == np.int32
+    for arr in args[-3:]:  # a block a device, and no other's
+        assert arr.sharding.is_equivalent_to(by_node, arr.ndim)
+        shards = {s.device: s for s in arr.addressable_shards}
+        for k, dev in enumerate(mesh.devices.flat):
+            assert shards[dev].data.shape == (1,) + arr.shape[1:]
+            assert shards[dev].index[0].indices(n_dev)[:2] == (k, k + 1)
+    got = make_temporal_fleet_program(mesh, backend=backend, compact=True)(
+        *args)
+    for name, a, b in zip(got._fields, got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+    model = np.asarray(inputs[0].mode) == MODE_MODEL
+    assert np.asarray(got.workload_power_uw)[model].max() > 1e6  # watts
+
+
+def test_the_rows_sent_are_the_rows_with_a_tick_and_no_others():
+    """``compact_history`` against the dense arrays, read back by the
+    index alone; and the padding can touch no real row: whatever lies in a
+    block's unused rows, the program publishes the same."""
+    batch, params, hist, t_valid = ragged_inputs()
+    rows, tv_rows, row_of = compact_history(hist, t_valid, 4,
+                                            BucketLadder(2, 16).fit)
+    r = rows.shape[1]
+    dense_hist = hist.reshape(4, -1, TICKS * FEATURES)
+    dense_tv = t_valid.reshape(4, -1, TICKS)
+    for s in range(4):
+        hit = dense_tv[s].any(-1)
+        k = int(hit.sum())
+        assert sorted(row_of[s][hit]) == list(range(k))  # each row once
+        assert (row_of[s][~hit] == r).all()  # past the end: reads zeros
+        np.testing.assert_array_equal(rows[s][row_of[s][hit]],
+                                      dense_hist[s][hit])
+        np.testing.assert_array_equal(tv_rows[s][row_of[s][hit]],
+                                      dense_tv[s][hit])
+        assert not rows[s, k:].any() and not tv_rows[s, k:].any()
+        assert not dense_hist[s][~hit].any()  # what was left out: zeros
+        rows[s, k:] = np.nan  # poison what no index names
+        tv_rows[s, k:] = True
+    mesh = mesh_of(4)
+    want = make_temporal_fleet_program(mesh)(
+        *put_fleet_batch(batch, params, hist, t_valid, mesh=mesh))
+    args = put_fleet_batch(batch, params, rows, tv_rows, row_of, mesh=mesh)
+    got = make_temporal_fleet_program(mesh, compact=True)(*args)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_block_is_written_again_only_after_its_window_is_published(
+        monkeypatch):
+    """The compact arrays of a published window are the next window's to
+    write into (fresh ones are page faults: PERF.md section 6); a window
+    still in flight keeps its own. The answers are those of a scheduler
+    that keeps nothing."""
+    from kepler_tpu.fleet import scheduler
+
+    def served(spare_kept: bool):
+        handed, given = [], []
+
+        def spy(hist, t_valid, n_shards, fit_rows, out=None):
+            rows = compact_history(hist, t_valid, n_shards, fit_rows, out)
+            handed.append(None if out is None else id(out[0]))
+            given.append(rows[0])
+            return rows
+
+        monkeypatch.setattr(scheduler, "compact_history", spy)
+        s = Served(4)
+        windows = s.agg.windows
+        windows._history_rows = BucketLadder(4, 16)
+        if not spare_kept:
+            windows._history_spare = collections.deque(maxlen=0)
+        try:
+            out = []
+            for seq in range(1, 8):
+                res = s.window(seq)
+                # depth 2, called directly: the window just dispatched is
+                # in flight, and its block is in no one else's hands
+                (flying,) = windows._inflight
+                assert flying.history_rows[0] is given[-1]
+                assert all(flying.history_rows[0] is not kept[0]
+                           for kept in windows._history_spare)
+                out.append(res)
+            out.append(windows.drain())
+        finally:
+            s.close()
+        return [r for r in out if r is not None], handed, given
+
+    kept, handed, given = served(True)
+    fresh, none_handed, _ = served(False)
+    assert none_handed == [None] * 7
+    # the first two windows find nothing to write into; from the third on
+    # each takes the block of the window published just before it
+    assert handed[:2] == [None, None] and None not in handed[2:]
+    assert [id(g) for g in given[2:]] == handed[2:]
+    assert len({id(g) for g in given}) == 2
+    assert len(kept) == len(fresh) == 7
+    for a, b in zip(kept, fresh):
+        np.testing.assert_array_equal(a.wl_power_uw, b.wl_power_uw)
+        np.testing.assert_array_equal(a.node_power_uw, b.node_power_uw)
+
+
+@pytest.mark.parametrize("n_dev, base", [(1, 128), (4, 32), (4, 1024)])
+def test_where_the_bucket_holds_no_fewer_rows_the_dense_window_goes_up(
+        n_dev, base):
+    """A shard's dense rows fit the smallest bucket: nothing to gain, so
+    ``compact_history`` hands back nothing and the served window puts the
+    dense arrays to the dense program (11 arguments, the dense bytes)."""
+    batch, params, hist, t_valid = ragged_inputs()
+    per = NODES * SLOTS // n_dev
+    ladder = BucketLadder(base, 16)
+    assert compact_history(hist, t_valid, n_dev, ladder.fit) is None
+    assert ladder.bucket == base >= per
+    assert compact_history(hist, t_valid, n_dev,
+                           BucketLadder(per // 2, 16).fit) is not None
+    s = Served(n_dev)
+    s.agg.windows._history_rows = ladder
+    try:
+        s.window(1)
+        s.agg.windows.drain()
+        body = s.get("/debug/window")
+    finally:
+        s.close()
+    dense = sum(a.nbytes for a in put_fleet_batch(
+        batch, params, hist, t_valid, mesh=mesh_of(n_dev))[1:])
+    assert body["counts"]["h2d_bytes"] == dense
+    assert body["counts"]["hist_rows_sent"] == NODES * SLOTS
+
+
+@pytest.mark.parametrize("ticks, whole", [(16, True), (8, True), (24, True),
+                                          (4, True), (12, True),
+                                          (16, False)])
+def test_rows_with_a_tick_is_any_over_the_ticks(ticks, whole):
+    rng = np.random.default_rng(ticks)
+    t_valid = rng.random((3, 40, ticks)) > 0.9
+    t_valid[:, ::3] = False
+    if not whole:
+        t_valid = t_valid[:, ::2]  # a view: no longer one block of memory
+    got = _rows_with_a_tick(t_valid)
+    assert got.dtype == bool and 0 < got.sum() < got.size
+    np.testing.assert_array_equal(got, t_valid.any(-1))
+
+
+def test_a_count_inside_the_bucket_compiles_nothing_and_a_growth_once():
+    mesh = mesh_of(4)
+    program = make_temporal_fleet_program(mesh, compact=True)
+    ladder = BucketLadder(2, 16)
+    want = make_temporal_fleet_program(mesh)
+
+    def window(seed, few):
+        inputs = ragged_inputs(seed, few)
+        args = compact_put(*inputs, mesh, ladder.fit)
+        out = program(*args)
+        ref = want(*put_fleet_batch(*inputs, mesh=mesh))
+        np.testing.assert_array_equal(np.asarray(out.workload_power_uw),
+                                      np.asarray(ref.workload_power_uw))
+        return args[-3].shape[1]
+
+    # one pod a model node: a row or two a shard, inside the first bucket
+    assert [window(seed, True) for seed in range(3)] == [2, 2, 2]
+    assert program._cache_size() == 1
+    # every pod: the fullest shard needs 5 or 6 rows, one growth to 8
+    assert [window(seed, False) for seed in range(3)] == [8, 8, 8]
+    assert program._cache_size() == 2
+    # fewer again: the bucket holds (shrinking waits for 16 such windows)
+    assert window(7, True) == 8
+    assert program._cache_size() == 2
+
+
+def test_the_compact_program_over_four_shards_holds_no_collective():
+    """What the kepljax registry's KTL122 spec holds for its case, on the
+    compiled program of this fleet: the expansion is shard-local."""
+    mesh = mesh_of(4)
+    args = compact_put(*ragged_inputs(), mesh, BucketLadder(2, 16).fit)
+    lowered = make_temporal_fleet_program(mesh, compact=True).lower(*args)
+    assert "jit_temporal_fleet_window" in lowered.as_text(debug_info=True)
+    hlo = lowered.compile().as_text()
+    assert "gather" in hlo
+    for op in COLLECTIVES:
+        assert op not in hlo, op
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_the_record_counts_the_rows_and_the_bytes_that_were_put(n_dev):
+    """``hist_rows_sent``, ``h2d_bytes`` and ``h2d_bytes_max_device`` of
+    ``/debug/window`` against the arrays of a put of the same shapes."""
+    s = Served(n_dev)
+    # model nodes (odd k) hold 3 + k % 4 pods: 4, 6, 4, 6; a ladder from 4
+    # rows fits the 6 of the fullest pair of nodes in 8 and all 20 in 32
+    s.agg.windows._history_rows = BucketLadder(4, 16)
+    try:
+        s.window(1)
+        s.window(2)
+        first = s.get("/debug/window")
+        for seq in range(3, 6):
+            s.window(seq)
+        s.agg.windows.drain()
+        last = s.get("/debug/window")
+    finally:
+        s.close()
+    grew = {k: last["counts"][k] - first["counts"][k]
+            for k in last["counts"]}
+    assert grew["windows"] == 4
+    r = {1: 32, 4: 8}[n_dev]
+    assert grew["hist_rows_sent"] == 4 * n_dev * r
+    batch = temporal_inputs()[0]
+    hist = np.zeros((NODES, SLOTS, TICKS, FEATURES), np.float32)
+    args = compact_put(batch, seeded_params(), hist,
+                       np.zeros((NODES, SLOTS, TICKS), bool),
+                       mesh_of(n_dev), lambda need: r)
+    assert [a.shape for a in args[-3:]] == [
+        (n_dev, r, TICKS * FEATURES), (n_dev, r, TICKS),
+        (n_dev, NODES * SLOTS // n_dev)]
+    put = sum(a.nbytes for a in args[1:])
+    assert put < hist.nbytes  # under the dense history alone
+    assert grew["h2d_bytes"] == 4 * put
+    assert grew["h2d_bytes_max_device"] * n_dev == grew["h2d_bytes"]
+    assert last["stats"]["last_h2d_device_bytes"] * n_dev == put
+    rows = last["records"]
+    assert "hist_rows_sent" not in rows["fields"]  # in the sums alone
+    at = rows["fields"].index("h2d_bytes")
+    assert [row[at] for row in rows["rows"][-4:]] == [put] * 4
 
 
 def test_the_benchmarks_reader_reads_a_quarter_of_the_bytes_a_device():

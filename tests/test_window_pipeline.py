@@ -67,6 +67,16 @@ def make_report(name: str, seed: int, w: int = 4, zones=ZONES,
 
 
 def make_agg(depth: int, **kw) -> Aggregator:
+    rows_base = kw.pop("history_rows_base", None)
+    agg = _make_agg(depth, **kw)
+    if rows_base is not None:
+        # a ladder small enough that this fleet's history goes up compact
+        # (a device's 8 dense rows are under the shipped base of 1024)
+        agg.windows._history_rows = BucketLadder(rows_base, 16)
+    return agg
+
+
+def _make_agg(depth: int, **kw) -> Aggregator:
     kw.setdefault("model_mode", "mlp")
     kw.setdefault("node_bucket", 8)
     kw.setdefault("workload_bucket", 8)
@@ -574,7 +584,11 @@ class TestShardedWindow:
 DEADLINE = 120.0  # generous: the suite runs under six workers
 KINDS = {"packed": {"model_mode": "mlp"},
          "legacy": {"model_mode": "mlp", "accuracy_mode": True},
-         "temporal": {"model_mode": "temporal", "history_window": 4}}
+         "temporal": {"model_mode": "temporal", "history_window": 4},
+         # the history as its valid rows, the blocks of published windows
+         # written again: the loop pops them, the publisher appends them
+         "temporal_compact": {"model_mode": "temporal", "history_window": 4,
+                              "history_rows_base": 2}}
 
 
 def wait_until(pred, what: str) -> None:
@@ -714,6 +728,12 @@ class TestPublishedOnCompletion:
         assert loop.agg.windows._stats["attributions_total"] == len(schedules)
         assert loop.agg.windows._rung == RUNG_PIPELINED
         assert loop.agg.windows._stats["window_demotions_total"] == 0
+        sent = loop.agg.windows._window_ledger.counts["hist_rows_sent"]
+        if kind == "temporal_compact":  # fewer rows than 8 nodes x 8 slots
+            assert 0 < sent < 64 * len(schedules)
+            assert loop.agg.windows._history_spare
+        else:
+            assert sent == (64 * len(schedules) if kind == "temporal" else 0)
 
     def test_stressed_loop_publisher_and_readers_lose_no_window(self):
         """The loop, the publisher and four readers of what they publish,

@@ -186,13 +186,16 @@ def test_counts_and_ingest_only_grow_and_add_up_over_the_records(served):
     import jax
     n_dev = len(jax.devices())
     assert SUM_COUNTS == ("devices", "h2d_bytes_max_device",
-                          "published_early")
+                          "published_early", "hist_rows_sent")
     assert not set(SUM_COUNTS) & set(between[0])
     # called directly at depth 2, a window is published by the next call:
     # only the drained one was published before a later one was snapshotted
     assert grew["published_early"] == 1
     assert grew["devices"] == n_dev * len(between)
     assert grew["h2d_bytes_max_device"] * n_dev == grew["h2d_bytes"]
+    # the history goes up whole here, where a device's 16 rows are under
+    # the smallest bucket of rows (tests/test_sharded_put.py: compact)
+    assert grew["hist_rows_sent"] == 8 * 16 * len(between)
     row = between[0]
     assert row["rows_program"] == 8 * 16  # node bucket × workload bucket
     assert row["rows_work"] == 3  # node-m's pods
@@ -314,7 +317,8 @@ def test_the_ledger_keeps_the_last_records_kept_and_every_count():
     assert counts == {"windows": RECORDS_KEPT + 44, "rows_work": 0,
                       "rows_program": 128 * (RECORDS_KEPT + 44),
                       "h2d_bytes": 0, "devices": 0,
-                      "h2d_bytes_max_device": 0, "published_early": 0}
+                      "h2d_bytes_max_device": 0, "published_early": 0,
+                      "hist_rows_sent": 0}
     table = json.loads(records_json(kept))
     assert len(table["rows"]) == RECORDS_KEPT and table["rows"][0][0] == 44
     assert kept[0].text is not None  # rendered once, then served as text
@@ -391,7 +395,11 @@ SCOPES = ("history_embed", "kv_proj", "last_query_attention", "mlp", "head",
           "attribute")
 
 
-def test_the_lowered_program_holds_its_name_and_the_scope_names():
+@pytest.mark.parametrize("compact", [True, False],
+                         ids=["as_served", "dense_entry"])
+def test_the_lowered_program_holds_its_name_and_the_scope_names(compact):
+    """The program the scheduler serves takes the history as its valid
+    rows and expands them first; name and scopes are the dense entry's."""
     import jax
     import jax.numpy as jnp
 
@@ -405,9 +413,16 @@ def test_the_lowered_program_holds_its_name_and_the_scope_names():
     args = [params, jnp.zeros((n, z), f32), jnp.ones((n, z), bool),
             jnp.zeros(n, f32), jnp.zeros((n, w), f32),
             jnp.ones((n, w), bool), jnp.zeros(n, f32), jnp.ones(n, f32),
-            jnp.zeros(n, jnp.int32), jnp.zeros((n, w, t, 7), f32),
-            jnp.ones((n, w, t), bool)]
-    lowered = make_temporal_fleet_program(make_mesh()).lower(*args)
+            jnp.zeros(n, jnp.int32)]
+    if compact:
+        n_dev, r = len(jax.devices()), 4
+        args += [jnp.zeros((n_dev, r, t * 7), f32),
+                 jnp.ones((n_dev, r, t), bool),
+                 jnp.zeros((n_dev, n // n_dev * w), jnp.int32)]
+    else:
+        args += [jnp.zeros((n, w, t, 7), f32), jnp.ones((n, w, t), bool)]
+    lowered = make_temporal_fleet_program(
+        make_mesh(), compact=compact).lower(*args)
     text = lowered.as_text(debug_info=True)
     assert "jit_temporal_fleet_window" in text
     for scope in SCOPES:
