@@ -11,7 +11,12 @@ What is compared is what the timed path published, at the timed sizes:
   node's pods differ between the two, so that a window may have read one
   round's ids and the other's history, is the answer skipped, and counted;
 - after the close, the whole fleet's answers from the window that the
-  pipeline published from the state after the last POST.
+  pipeline published from the state after the last POST. Every node has to
+  be there and well-formed, and every ratio node is compared; the model
+  nodes' watts all go through the reference unless the configuration says
+  how many do (``check.final_model_nodes``: that many, drawn from the
+  seed): an estimator whose reference costs hours for a whole fleet is
+  compared on a sample, at the timed sizes still.
 
 Each number has a limit of its own, kept in the configuration's file under
 ``limits`` with the readings it was set from (``PERF.md`` section 2 has the
@@ -44,7 +49,8 @@ class Errors:
         }
         self.counts = {"answers_compared": 0, "pods_compared": 0,
                        "answers_round_in_doubt": 0,
-                       "answers_skipped_torn": 0}
+                       "answers_skipped_torn": 0,
+                       "final_model_nodes_compared": 0}
 
     def _max(self, key: str, value: float) -> None:
         self.numbers[key] = max(self.numbers[key], float(value))
@@ -143,6 +149,20 @@ def _nearest(ref: Reference, i: int, entry: dict, rounds: list[int],
     return rounds[int(np.argmin(gaps))]
 
 
+def final_model_nodes(fleet) -> set[int] | None:
+    """The model nodes of the final window whose watts go through the
+    reference: None (all of them) unless the configuration's
+    ``check.final_model_nodes`` is a number, then that many, drawn from
+    the seed as ``drive.sample_nodes`` draws."""
+    how = fleet.config.get("check", {}).get("final_model_nodes", "all")
+    if how == "all":
+        return None
+    rng = np.random.default_rng([fleet.seed, 4])
+    model = np.flatnonzero(fleet.mode == 1)
+    return {int(i) for i in rng.choice(model, min(int(how), len(model)),
+                                       replace=False)}
+
+
 def compare(drive: Drive, ref: Reference, launch: dict) -> Errors:
     from chipbench.fleetgen import BATCH
 
@@ -182,8 +202,9 @@ def compare(drive: Drive, ref: Reference, launch: dict) -> Errors:
         out.numbers["answers_malformed"] += len(missing) + max(
             0, len(nodes) - fleet.n)
         have = [i for i in range(fleet.n) if fleet.names[i] in nodes]
-        _compare_nodes(out, ref, have, [nodes[fleet.names[i]] for i in have],
-                       drive.final_round)
+        out.counts["final_model_nodes_compared"] = _compare_nodes(
+            out, ref, have, [nodes[fleet.names[i]] for i in have],
+            drive.final_round, only_model=final_model_nodes(fleet))
     out.numbers["compiles_in_window"] = sum(
         1 for at, dur in launch.get("compiles", [])
         if at > drive.t_open and at - dur < drive.t_close)
@@ -195,11 +216,14 @@ def compare(drive: Drive, ref: Reference, launch: dict) -> Errors:
 
 
 def _compare_nodes(out: Errors, ref: Reference, idx: list[int],
-                   entries: list[dict], r: int,
-                   wants: dict | None = None) -> None:
+                   entries: list[dict], r: int, wants: dict | None = None,
+                   only_model: set | None = None) -> int:
     """Pool the errors of ``entries`` (the answers of nodes ``idx``) against
     the reference after round ``r``: ``wants`` where it was worked out
-    beforehand, else asked of ``ref`` for all the nodes at once."""
+    beforehand, else asked of ``ref`` for all the nodes at once. Where
+    ``only_model`` is given, a model node outside it is held to its form
+    alone (ids, zones, mode, finite numbers) and its watts are not
+    compared. → the model nodes compared."""
     fleet = ref.fleet
     gen = ref.state(r)[0].gen
     good, pubs, pub_nodes = [], [], []
@@ -208,11 +232,14 @@ def _compare_nodes(out: Errors, ref: Reference, idx: list[int],
         if got is None:
             out.numbers["answers_malformed"] += 1
             continue
+        if only_model is not None and fleet.mode[i] == 1 \
+                and i not in only_model:
+            continue
         good.append(i)
         pubs.append(got[0])
         pub_nodes.append(got[1])
     if not good:
-        return
+        return 0
     good_a = np.asarray(good)
     pubs_a, nodes_a = np.stack(pubs), np.stack(pub_nodes)
     is_model = fleet.mode[good_a] == 1
@@ -228,6 +255,7 @@ def _compare_nodes(out: Errors, ref: Reference, idx: list[int],
         want_node = np.stack([wants[i, r][1] for i in good_a[~is_model]])
         out.add_ratio(pubs_a[~is_model], nodes_a[~is_model], want,
                       want_node)
+    return int(is_model.sum())
 
 
 def verdict(errors: Errors, limits: dict) -> tuple[bool, dict]:

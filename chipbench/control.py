@@ -5,9 +5,10 @@ place — and the comparison has to call it not correct.
 
     python3 chipbench/control.py --workload <cell> --seed <n> [<n> ...]
 
-The estimator's configurations state bf16 matmul operands, so the control
-computes them in fp8 (e4m3); the ratio path's float32 product is computed
-with bf16 operands. What the lowered reference gives is laid out as the
+Which precision that is, the cell's estimator states (``CONTROL`` of
+``estimators/<name>.py``: where the configurations state bf16 matmul
+operands, fp8 e4m3); the ratio path's float32 product is computed with
+bf16 operands. What the lowered reference gives is laid out as the
 aggregator would have published it, at the cell's own size — a few sampled
 windows and the whole fleet's final window — and goes through the same
 ``check.compare`` and ``check.verdict`` as a run's publications do, against
@@ -31,7 +32,8 @@ import numpy as np  # noqa: E402
 from chipbench import check, spec  # noqa: E402
 from chipbench.drive import Drive, Round, Window, sample_nodes  # noqa: E402
 from chipbench.fleetgen import BATCH, Fleet  # noqa: E402
-from chipbench.reference import Reference, make_params  # noqa: E402
+from chipbench.precision import QUANTIZERS  # noqa: E402
+from chipbench.reference import Reference  # noqa: E402
 
 
 def published(ref: Reference, nodes: list[int], r: int,
@@ -61,14 +63,12 @@ def published(ref: Reference, nodes: list[int], r: int,
     return out
 
 
-def control_run(cell: spec.Cell, seed: int, quantize: str = "fp8",
-                windows: int = 3) -> tuple[bool, dict]:
-    """→ (correct, {name: {"value", "limit"}}) of the reference at
-    ``quantize`` in the program's place, at the cell's own size."""
+def stand_in(cell: spec.Cell, fleet: Fleet, low: Reference,
+             windows: int = 3) -> Drive:
+    """What ``low`` gives, laid out as a run's observations of the
+    aggregator: ``windows`` sampled windows and the whole fleet's final
+    one, after the history fill and the warm-up, at the cell's own size."""
     t = int(cell.config["history_window"])
-    fleet = Fleet(cell.config, cell.traffic, seed)
-    params = make_params(seed, cell.config)
-    low = Reference(fleet, params, t, quantize)
     n_batches = -(-fleet.n // BATCH)
     last = t + int(cell.traffic.get("warmup_rounds", 3)) + windows - 1
     drive = Drive(fleet=fleet, seconds=float(windows))
@@ -91,6 +91,21 @@ def control_run(cell: spec.Cell, seed: int, quantize: str = "fp8",
                                       last + 0.5)}
     drive.final_round = last
     drive.debug = {"first": {"rung": 0}, "last": {"rung": 0}}
+    return drive
+
+
+def control_run(cell: spec.Cell, seed: int, quantize: str | None = None,
+                windows: int = 3) -> tuple[bool, dict]:
+    """→ (correct, {name: {"value", "limit"}}) of the reference at
+    ``quantize`` (the estimator's ``CONTROL`` unless another is named) in
+    the program's place, at the cell's own size."""
+    estimator = cell.estimator()
+    quantize = quantize or estimator.CONTROL
+    t = int(cell.config["history_window"])
+    fleet = Fleet(cell.config, cell.traffic, seed)
+    params = estimator.make_params(seed, cell.config)
+    drive = stand_in(cell, fleet, Reference(fleet, params, t, quantize),
+                     windows)
     errors = check.compare(drive, Reference(fleet, params, t), {})
     return check.verdict(errors, cell.config["limits"])
 
@@ -99,16 +114,19 @@ def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, nargs="+", required=True)
-    ap.add_argument("--quantize", default="fp8", choices=("fp8", "bf16"))
+    ap.add_argument("--quantize", default=None,
+                    choices=sorted(q for q in QUANTIZERS if q),
+                    help="the estimator's CONTROL unless given")
     args = ap.parse_args(argv)
     cell = spec.load_cell(ROOT, args.workload)
+    quantize = args.quantize or cell.estimator().CONTROL
     passed = 0
     for seed in args.seed:
-        correct, compared = control_run(cell, seed, args.quantize)
+        correct, compared = control_run(cell, seed, quantize)
         passed += bool(correct)
         row = " ".join(f"{k}={v['value']:.6g}/{v['limit']:.6g}"
                        for k, v in compared.items() if v["limit"] > 0)
-        print(f"CONTROL {args.workload} seed {seed} {args.quantize}: "
+        print(f"CONTROL {args.workload} seed {seed} {quantize}: "
               f"correct = {correct} {row}", flush=True)
     return 1 if passed else 0
 

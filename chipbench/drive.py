@@ -26,6 +26,7 @@ from chipbench.fleetgen import Fleet
 GAUGES = ("last_assembly_ms", "last_dispatch_ms", "last_wait_ms",
           "last_fetch_ms", "last_scatter_ms")
 SAMPLE_NODES = 6  # answers kept from every window, half of them model nodes
+# (the default: a configuration's ``check.sampled_nodes`` says otherwise)
 WAIT_S = 60.0  # how long an answer may take before it counts as missing
 THROTTLE_WAIT_S = (0.05, 5.0)  # a 429's retry_after is kept within these
 THROTTLE_GIVE_UP_S = 30.0  # a batch throttled for longer fails the run
@@ -246,12 +247,14 @@ def published_total(child: AggregatorChild) -> int:
 
 
 def sample_nodes(fleet: Fleet) -> list[int]:
+    count = int(fleet.config.get("check", {}).get("sampled_nodes",
+                                                  SAMPLE_NODES))
     rng = np.random.default_rng([fleet.seed, 2])
     model = np.flatnonzero(fleet.mode == 1)
     ratio = np.flatnonzero(fleet.mode == 0)
-    half = SAMPLE_NODES // 2
+    half = count // 2
     picks = list(rng.choice(model, min(half, len(model)), replace=False))
-    picks += list(rng.choice(ratio, min(SAMPLE_NODES - half, len(ratio)),
+    picks += list(rng.choice(ratio, min(count - half, len(ratio)),
                              replace=False))
     return [int(i) for i in picks]
 

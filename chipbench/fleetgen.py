@@ -62,6 +62,7 @@ class RoundState:
 class Fleet:
     def __init__(self, config: dict, traffic: dict, seed: int) -> None:
         self.seed = int(seed)
+        self.config = config  # the reference and the check read it too
         self.n = int(config["nodes"])
         self.zones = tuple(sorted(config["zones"]))
         self.dt = float(config["dt_s"])

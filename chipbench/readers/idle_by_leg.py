@@ -11,7 +11,8 @@ def read(run, legs: list):
     body = run.drive.debug.get("last")
     if not run.planes:
         return None
-    found = idle_by_leg(body, run.planes, run.launch, {"legs": legs})
+    found = idle_by_leg(body, run.planes, run.launch, {"legs": legs},
+                        run.program)
     if found is None or found["idle_s"] <= 0:
         return None
     return 100.0 * found["in_s"]["legs"] / found["idle_s"]

@@ -18,6 +18,7 @@ records' clock with no estimate, and the records can contradict it (``align``).
 from __future__ import annotations
 
 from chipbench import trace
+from chipbench.estimators.temporal import PROGRAM  # noqa: F401 (see below)
 
 
 def delta(first: dict, last: dict, path: list):
@@ -67,23 +68,28 @@ def window_records(body: dict) -> list[dict]:
     return out
 
 
-PROGRAM = "jit_temporal_fleet_window"  # the window's program, by its name
+# ``program`` below is the prefix of the window's program's name, which the
+# cell's estimator states (``estimators/<name>.py``: ``PROGRAM``) and the
+# harness always passes (``run.Run.program``). The default is the name from
+# before there was more than one estimator, kept for the callers from then.
 FETCH_SLACK_S = 1e-3  # the two clocks' rounding, and the host's half ms
 
 
-def program_runs(planes: list) -> list[tuple[float, float]]:
+def program_runs(planes: list, program: str = PROGRAM) -> list[
+        tuple[float, float]]:
     """Runs of the window's program (``XLA Modules`` events that carry its
     name) on the first plane that has any, in trace seconds, in order."""
     for plane in planes:
         runs = sorted((s / 1e9, (s + d) / 1e9)
                       for name, s, d in trace.module_events(plane)
-                      if d > 0 and name.startswith(PROGRAM))
+                      if d > 0 and name.startswith(program))
         if runs:
             return runs
     return []
 
 
-def align(body: dict, planes: list, launch: dict) -> dict | None:
+def align(body: dict, planes: list, launch: dict,
+          program: str = PROGRAM) -> dict | None:
     """Hold the trace's own zero against the body's records → {"offset_s",
     "checked", "contradicted", the least "launch_delay_s" and
     "fetch_margin_s" of the runs that fit, "against": for the first three
@@ -99,7 +105,7 @@ def align(body: dict, planes: list, launch: dict) -> dict | None:
     is no guarantee: under a busy host a run starts after the NEXT
     window's dispatch began, and is its own window's still.)"""
     legs = leg_marks(body)
-    runs = program_runs(planes)
+    runs = program_runs(planes, program)
     zero_ns = (launch or {}).get("profile_start_time")
     if not ({"window.dispatch", "window.pipeline_wait"} <= set(legs)
             and runs and zero_ns):
@@ -161,15 +167,15 @@ def _complement(spans: list, lo: float, hi: float) -> list:
     return out
 
 
-def idle_by_leg(body: dict, planes: list, launch: dict,
-                groups: dict) -> dict | None:
+def idle_by_leg(body: dict, planes: list, launch: dict, groups: dict,
+                program: str = PROGRAM) -> dict | None:
     """The device's idle seconds inside the stretch that both the trace
     and the body's records cover, how much of it falls inside each group
     of legs (``groups``: name → span names of legs, as the body's table
     has them) and how much in none — by intersection of intervals.
     Nothing where the zero is unknown or contradicted: it is one number for
     all runs, so by over one run in twenty (one says its record is off)."""
-    fit = align(body, planes, launch)
+    fit = align(body, planes, launch, program)
     if fit is None or 20 * fit["contradicted"] > fit["checked"]:
         return None
     offset = fit["offset_s"]

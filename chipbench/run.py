@@ -37,7 +37,7 @@ from chipbench.child import (LAUNCHER, AggregatorChild,  # noqa: E402
                              BenchFailure, free_port)
 from chipbench.drive import Drive, run_window  # noqa: E402
 from chipbench.fleetgen import Fleet  # noqa: E402
-from chipbench.reference import Reference, make_params  # noqa: E402
+from chipbench.reference import Reference  # noqa: E402
 from chipbench.stats import window_latencies  # noqa: E402
 
 
@@ -75,6 +75,7 @@ class Run:
                              d.published_close - d.published_open,
                              d.count_from, d.count_to)
         self.work = work.of_config(cell.config, d.fleet.model_pods)
+        self.program = cell.estimator().PROGRAM  # the window's, by its name
         kind = launch.get("device_kind", "")
         try:
             self.peak = work.peaks(kind)
@@ -140,11 +141,10 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
     a child whose timed path is broken on purpose."""
     t_start = time.time()
     cell = spec.load_cell(root, workload)
-    work.of_config(cell.config, 0)  # an estimator nobody can count: now
     workdir = tempfile.mkdtemp(prefix="chipbench-")
     child = drive = None
     try:
-        params = make_params(seed, cell.config)
+        params = cell.estimator().make_params(seed, cell.config)
         params_path = os.path.join(workdir, "params.npz")
         np.savez(params_path, **params)
         config = aggregator_config(cell, params_path, platform)
@@ -221,7 +221,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
         # how many records the trace's zero was held against, and how many
         # contradicted it (``records.align``); null where there is no zero
         result["notes"]["trace_zero"] = records.align(
-            drive.debug.get("last"), run.planes, launch)
+            drive.debug.get("last"), run.planes, launch, run.program)
     result["compared"] = compared
     for name, row in compared.items():
         print(f"chipbench: {name} = {row['value']:.6g} (limit "

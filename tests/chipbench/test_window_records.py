@@ -236,7 +236,7 @@ def test_aligner_counts_what_contradicts_the_zero_and_then_nothing_prints(
     def run_with_zero(body, launch):
         return SimpleNamespace(
             drive=SimpleNamespace(debug={"first": {}, "last": body}),
-            planes=planes, launch=launch)
+            planes=planes, launch=launch, program=records.PROGRAM)
 
     body = planted(planes)
     assert idle_by_leg.read(run_with_zero(body, ZERO), legs=TICK) is not None
@@ -364,7 +364,7 @@ def test_idle_shares_and_the_rest_sum_to_the_whole_idle_time(planes):
     assert inside["assembly"] == pytest.approx(0.31, abs=1e-6)
     run = SimpleNamespace(
         drive=SimpleNamespace(debug={"first": {}, "last": planted(planes)}),
-        planes=planes, launch=ZERO)
+        planes=planes, launch=ZERO, program=records.PROGRAM)
     both = (idle_by_leg.read(run, legs=ASSEMBLY)
             + idle_by_leg.read(run, legs=TICK))
     assert both == pytest.approx(
