@@ -214,7 +214,7 @@ def _build_fleet(case: ProgramCase) -> tuple:
     )
     if d.get("temporal"):
         # the program the aggregator serves: the history as its valid rows
-        # (``r`` a shard), expanded on each device
+        # (``r`` a shard), the estimator run on them on each device
         t, f, r = d["t"], 7, d["r"]
         fn = make_temporal_fleet_program(mesh, compact=True)
         return fn, (_temporal_avals(z),) + batch + (

@@ -1573,8 +1573,9 @@ class WindowScheduler:
             counts = np.asarray(batch.workload_counts)
             rec.rows_work = int(counts[
                 batch.mode[:len(counts)] == MODE_MODEL].sum())
-        if temporal:  # S · R; of the dense window, N · W
-            rec.hist_rows_sent = math.prod(args[9].shape[:2])
+        if temporal:  # S · R; of the dense window, N · W: the estimator's
+            rec.hist_rows_sent = rec.rows_program = math.prod(
+                args[9].shape[:2])
         rec.h2d_bytes = sum(int(a.nbytes) for a in args[1:])
         # a NamedSharding's shards are all of one shape, so the device
         # that was sent most was sent one shard of every argument

@@ -109,7 +109,9 @@ class WindowRecord:
         # the loop thread's CPU seconds from begin to assembled: wall − CPU
         # is the time assembly was kept off the processor (GIL, locks)
         self.assembly_cpu_s: float | None = None
-        self.rows_program = 0  # node bucket × workload bucket
+        # the rows the estimator ran on: node bucket × workload bucket, or
+        # of a compact temporal window, shards × history rows a shard
+        self.rows_program = 0
         self.rows_work = 0  # pods of model nodes: the estimates published
         self.h2d_bytes = 0
         self.devices = 0  # the devices the window's program ran over

@@ -133,15 +133,18 @@ def temporal_fleet_program(
     node_cpu_delta: jax.Array,  # f32 [N]
     dt_s: jax.Array,  # f32 [N]
     mode: jax.Array,  # int32 [N]
-    feat_hist: jax.Array,  # f32 [N, W, T, F] per-workload history windows
-    t_valid: jax.Array,  # bool [N, W, T]
-    *,
+    # the dense windows: feat_hist f32 [N, W, T, F] per-workload history,
+    # t_valid bool [N, W, T]; or compact_history's three arrays
+    *history: jax.Array,
     attribute_fn=attribute_fleet,
     accuracy_mode: bool = False,
 ) -> FleetResult:
     """Mixed fleet with the TEMPORAL estimator: the aggregator accretes each
     workload's feature history (`kepler_tpu.monitor.history`) and the model
-    predicts from the whole window instead of the last tick."""
+    predicts from the whole window instead of the last tick. Handed
+    :func:`compact_history`'s blocks in the dense windows' place, it runs
+    the estimator on the rows that were sent (:func:`estimate_sent_rows`).
+    """
     from kepler_tpu.models.temporal import predict_temporal
 
     # the scope names reach the HLO's op metadata, so a profile of the
@@ -153,7 +156,12 @@ def temporal_fleet_program(
         )
     pfn = (accuracy_mode_predictor(predict_temporal, "temporal")
            if accuracy_mode else predict_temporal)
-    watts = pfn(model_params, feat_hist, workload_valid, t_valid=t_valid)
+    if len(history) == 3:  # hist_rows, tv_rows, row_of
+        watts = estimate_sent_rows(pfn, model_params, workload_valid,
+                                   *history)
+    else:
+        feat_hist, t_valid = history
+        watts = pfn(model_params, feat_hist, workload_valid, t_valid=t_valid)
     with jax.named_scope("attribute"):
         return mix_model_watts(ratio, watts, mode, dt_s)
 
@@ -260,8 +268,9 @@ def make_fleet_program(mesh: Mesh, model_mode: str | None = None,
     )
 
 
-# the smallest bucket of history rows a compact put sends a shard (the
-# base of the ladder whose ``fit`` the caller hands to put_fleet_batch)
+# the smallest bucket of history rows a compact put sends a shard, and so
+# the fewest rows the compact program runs its estimator on (the base of
+# the ladder whose ``fit`` the caller hands to compact_history)
 HISTORY_ROWS_BASE = 1024
 
 
@@ -294,12 +303,13 @@ def compact_history(
 
     A row is sent if any of its ticks is valid. The others are all zeros
     (``Aggregator._history_windows`` writes into ``np.zeros`` and
-    ``HistoryBuffer.window_arrays`` leaves an empty slot's rows zero; the
-    estimator reads position 0 of such a row: 0.0), so leaving them out
-    changes no bit of what :func:`expand_history` hands the program.
+    ``HistoryBuffer.window_arrays`` leaves an empty slot's rows zero), so
+    the estimate of every one of them is that of an empty window, which
+    the compact program computes once (:func:`estimate_sent_rows`).
     ``row_of`` says, for every dense row of the shard, which compact row
     it is; ``R``, past the end, for a row that was not sent: the device
-    reads that as zeros, so no padding entry can touch a real row. ``R``
+    reads the empty window's estimate there, so no padding entry can touch
+    a real row. ``R``
     is ``fit_rows`` of the fullest shard's count (a ``BucketLadder.fit``:
     the program sees one shape while the count wanders). The rows go up flat,
     ``[R, T·F]``: on a TPU v5e that lands in 9 ms where ``[R, T, F]``
@@ -338,48 +348,40 @@ def compact_history(
     return hist_rows, tv_rows, row_of
 
 
-def expand_history(
-    hist_rows: jax.Array,  # f32 [1, R, T·F]: this shard's block
-    tv_rows: jax.Array,  # bool [1, R, T]
-    row_of: jax.Array,  # int32 [1, n·W]
-    n_workloads: int,
-) -> tuple[jax.Array, jax.Array]:
-    """One shard's dense ``(feat_hist [n, W, T, F], t_valid [n, W, T])``
-    from what :func:`compact_history` sent it: a gather by ``row_of``, an
-    index past the end reading zeros. Shard-local: no row crosses
-    devices."""
-    t = tv_rows.shape[-1]
-    hist = jnp.take(hist_rows[0], row_of[0], axis=0, mode="fill",
-                    fill_value=0)
-    tv = jnp.take(tv_rows[0], row_of[0], axis=0, mode="fill",
-                  fill_value=False)
-    return (hist.reshape(-1, n_workloads, t, hist.shape[-1] // t),
-            tv.reshape(-1, n_workloads, t))
-
-
-def _on_expanded_history(dense, mesh: Mesh | None):
-    """``dense`` (the temporal program) behind :func:`expand_history`: →
-    a program of :func:`compact_history`'s three arrays in the dense
-    history's place. ``mesh``: run the expansion as a ``shard_map`` over
-    its node axis (the blocks' leading axis counts shards, not nodes);
-    None where the caller is per shard already."""
-    def fn(params, *args):
-        data, rows = args[:-3], args[-3:]
-        expand = functools.partial(expand_history,
-                                   n_workloads=data[3].shape[1])
-        if mesh is not None:
-            expand = jax.shard_map(expand, mesh=mesh,
-                                   in_specs=(P(NODE_AXIS),) * 3,
-                                   out_specs=(P(NODE_AXIS),) * 2)
-        hist, tv = expand(*rows)
-        # the dense history is built whole before the dense program reads
-        # it: fused into the program's first relayout the gather would
-        # save 1.2 ms of 54 a window and compile in 16 s, not 4, at every
-        # new bucket of rows (TPU v5e: PERF.md section 6)
-        hist = jax.lax.optimization_barrier(hist)
-        return dense(params, *data, hist, tv)
-
-    return fn
+def estimate_sent_rows(
+    predict,
+    params: Any,
+    workload_valid: jax.Array,  # bool [N, W]
+    hist_rows: jax.Array,  # f32 [S, R, T·F]: a block a shard
+    tv_rows: jax.Array,  # bool [S, R, T]
+    row_of: jax.Array,  # int32 [S, N/S·W]
+) -> jax.Array:
+    """The estimator watts ``[N, W, Z]`` from :func:`compact_history`'s
+    blocks: ``predict`` runs on each block's R rows and a few zero rows
+    past them (rows on the leading axis), and the watts go back to the
+    dense slots by ``row_of``. A slot that was not sent (index R) reads
+    the first zero row: the estimate of an empty window, what the dense
+    program gives such a slot. Every step is within one block, so over
+    the node axis' shards (``S`` of them; one where the caller is per
+    shard) no row crosses devices."""
+    s, r, t = tv_rows.shape
+    f = hist_rows.shape[-1] // t
+    # zero rows up to a whole multiple of 8 rows, one at least: a full
+    # sublane tile, and on XLA's CPU backend a row's arithmetic is then
+    # the dense program's to the bit (under 8 rows its dot kernels differ)
+    pad = 8 - r % 8
+    rows = jnp.pad(hist_rows, ((0, 0), (0, pad), (0, 0)))
+    tv = jnp.pad(tv_rows, ((0, 0), (0, pad), (0, 0)))
+    # a block row is a pod's if it holds a tick; the zero rows are the
+    # empty window of a pod that has none
+    valid = tv.any(-1) | (jnp.arange(r + pad) >= r)
+    b = s * (r + pad)
+    watts = predict(params, rows.reshape(b, 1, t, f), valid.reshape(b, 1),
+                    t_valid=tv.reshape(b, 1, t))
+    watts = jax.vmap(functools.partial(jnp.take, axis=0, mode="fill"))(
+        watts.reshape(s, r + pad, -1), row_of)
+    watts = watts.reshape(*workload_valid.shape, -1)
+    return jnp.where(workload_valid[..., None], watts, 0.0)
 
 
 def make_temporal_fleet_program(mesh: Mesh, backend: str = "einsum",
@@ -391,19 +393,15 @@ def make_temporal_fleet_program(mesh: Mesh, backend: str = "einsum",
 
     ``compact``: the program the aggregator serves. In the place of the
     dense history it takes :func:`compact_history`'s three arrays, each
-    shard's block on its own device, rebuilds the dense arrays there
-    (:func:`expand_history`) and runs the same program on them."""
+    shard's block on its own device, runs the estimator on the rows that
+    were sent and gathers their watts to the dense slots there
+    (:func:`estimate_sent_rows`); the ratio attribution and the mix are
+    the dense program's."""
     replicated, by_node = fleet_shardings(mesh)
+    n_history = 3 if compact else 2
     fn = functools.partial(temporal_fleet_program,
                            attribute_fn=resolve_attribute_fn(mesh, backend),
                            accuracy_mode=accuracy_mode)
-    n_history = 2
-    if compact:
-        n_history = 3
-        # under the pallas backend's shard_map the program is per shard
-        # already; GSPMD partitions the einsum one, and there the
-        # expansion alone is spelled per shard
-        fn = _on_expanded_history(fn, None if backend == "pallas" else mesh)
     if backend == "pallas":
         data_specs = (P(NODE_AXIS, None), P(NODE_AXIS, None), P(NODE_AXIS),
                       P(NODE_AXIS, None), P(NODE_AXIS, None), P(NODE_AXIS),

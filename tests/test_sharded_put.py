@@ -6,7 +6,8 @@ takes its own nodes' rows straight from the host and the jit moves
 nothing; over a mesh of one device that is the whole array on that device,
 bit for bit what the put without a mesh gives. Since ISSUE 37 the
 served temporal window sends its history as the rows that hold a tick
-(``compact_history``) and the program rebuilds the dense arrays on each
+(``compact_history``), and the program runs its estimator on those rows
+alone and gathers the watts back to the dense slots on each
 device (``make_temporal_fleet_program(compact=True)``); the dense entry
 stays for the library. Here on the CPU's virtual
 devices (conftest gives eight): counts, placements and published values,
@@ -18,7 +19,9 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import math
 import os
+import re
 import urllib.request
 
 import jax
@@ -469,7 +472,8 @@ def test_a_count_inside_the_bucket_compiles_nothing_and_a_growth_once():
 
 def test_the_compact_program_over_four_shards_holds_no_collective():
     """What the kepljax registry's KTL122 spec holds for its case, on the
-    compiled program of this fleet: the expansion is shard-local."""
+    compiled program of this fleet: the estimate and its gather are
+    shard-local."""
     mesh = mesh_of(4)
     args = compact_put(*ragged_inputs(), mesh, BucketLadder(2, 16).fit)
     lowered = make_temporal_fleet_program(mesh, compact=True).lower(*args)
@@ -478,6 +482,114 @@ def test_the_compact_program_over_four_shards_holds_no_collective():
     assert "gather" in hlo
     for op in COLLECTIVES:
         assert op not in hlo, op
+
+
+# -- the estimator runs on the rows that were sent ----------------------------
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+@pytest.mark.parametrize("backend", ["einsum", "pallas"])
+def test_a_valid_pod_with_no_tick_reads_the_empty_windows_estimate(
+        n_dev, backend):
+    """A model node's pod that is in the batch but has no tick is not sent;
+    the compact program gives it the estimate of an empty window (the
+    head's bias, clamped), bit for bit what the dense program gives it,
+    and not 0."""
+    mesh = mesh_of(n_dev)
+    inputs = ragged_inputs(seed=3)
+    batch, _, _, t_valid = inputs
+    model = (np.asarray(batch.mode) == MODE_MODEL)[:, None]
+    silent = np.asarray(batch.workload_valid) & model & ~t_valid.any(-1)
+    assert silent.any(1).sum() > 1  # on more than one node
+    want = make_temporal_fleet_program(mesh, backend=backend)(
+        *put_fleet_batch(*inputs, mesh=mesh))
+    got = make_temporal_fleet_program(mesh, backend=backend, compact=True)(
+        *compact_put(*inputs, mesh, BucketLadder(2, 16).fit))
+    for name, a, b in zip(got._fields, got, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), name)
+    power = np.asarray(got.workload_power_uw)[silent]  # [pods, Z]
+    assert (power == power[0]).all()  # one window's estimate, every pod
+    assert (power > 1e6).all()  # watts, not the 0 of a slot left empty
+    assert not np.asarray(got.workload_power_uw)[
+        ~np.asarray(batch.workload_valid)].any()
+
+
+def largest_dot_rows(hlo: str) -> int:
+    """The leading dimension of the compiled program's largest ``dot``."""
+    shapes = [tuple(int(d) for d in m.split(",")) for m in re.findall(
+        r"= \w+\[([\d,]+)\]\{[\d,]*\} dot\(", hlo)]
+    assert shapes
+    return max(shapes, key=math.prod)[0]
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_the_compact_programs_largest_dot_has_the_blocks_rows(n_dev):
+    """Per shard the trunk's matmuls run over the rows of the block (and
+    the zero rows past it, to a multiple of 8), ``T`` positions each; the
+    dense window's rows appear in no ``dot``. A return to rebuilding the
+    dense history before the trunk fails here."""
+    mesh = mesh_of(n_dev)
+    inputs = ragged_inputs(few=True)
+    per = NODES * SLOTS // n_dev
+    args = compact_put(*inputs, mesh, BucketLadder(2, 16).fit)
+    r = args[-3].shape[1]
+    assert r < 8 < per
+    compact = make_temporal_fleet_program(mesh, compact=True).lower(
+        *args).compile().as_text()
+    dense = make_temporal_fleet_program(mesh).lower(
+        *put_fleet_batch(*inputs, mesh=mesh)).compile().as_text()
+    assert largest_dot_rows(dense) == per * TICKS
+    assert largest_dot_rows(compact) == 8 * TICKS
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_an_estimate_altered_where_it_is_made_moves_one_sent_pod(
+        monkeypatch, n_dev):
+    """``predict_temporal`` wrapped as ``tests/chipbench/broken_launch.py``
+    wraps it for ``answer_altered`` (the program looks it up when it is
+    traced), served with a ladder of rows small enough that the compact
+    program runs: the second row the estimator runs on is a sent model
+    pod's, and that pod's watts, every zone, are a tenth higher in every
+    window; no other watt moves but its node's sum."""
+    from kepler_tpu.models import temporal
+
+    def served():
+        s = Served(n_dev)
+        s.agg.windows._history_rows = BucketLadder(4, 16)
+        try:
+            out = [s.window(seq) for seq in range(1, 5)]
+            out.append(s.agg.windows.drain())
+            counts = s.get("/debug/window")["counts"]
+        finally:
+            s.close()
+        return [r for r in out if r is not None], counts
+
+    want, counts = served()
+    real = temporal.predict_temporal
+
+    def altered(*args, **kw):
+        watts = real(*args, **kw)
+        return watts.at[1, 0].multiply(1.1)
+
+    monkeypatch.setattr(temporal, "predict_temporal", altered)
+    got, altered_counts = served()
+    assert altered_counts == counts
+    # the compact program served every window: fewer rows than the dense
+    assert counts["hist_rows_sent"] == counts["rows_program"] \
+        < counts["windows"] * NODES * SLOTS
+    assert len(got) == len(want) == 4
+    for a, b in zip(got, want):
+        moved = (a.wl_power_uw != b.wl_power_uw).any(-1)
+        assert moved.sum() == 1
+        (node,), (slot,) = np.nonzero(moved)
+        assert a.mode[node] == MODE_MODEL
+        np.testing.assert_allclose(a.wl_power_uw[node, slot],
+                                   1.1 * b.wl_power_uw[node, slot],
+                                   rtol=1e-6)
+        assert b.wl_power_uw[node, slot].min() > 1e6
+        others = np.arange(len(a.names)) != node
+        np.testing.assert_array_equal(a.node_power_uw[others],
+                                      b.node_power_uw[others])
+        assert (a.node_power_uw[node] > b.node_power_uw[node]).all()
 
 
 @pytest.mark.parametrize("n_dev", [1, 4])
