@@ -41,6 +41,9 @@ LN_EPS = 1e-6
 
 PROGRAM = "jit_temporal_fleet_window"  # the window's program, by its name
 CONTROL = "fp8"  # the configurations state bf16 operands: the step below
+# the sizes of ``models/temporal.py``'s trunk, which every configuration of
+# it holds uncut
+WIDTHS = {"d_model": 128, "n_heads": 4, "mlp_dim": 512, "n_features": 7}
 
 
 def make_params(seed: int, config: dict) -> dict[str, np.ndarray]:
@@ -153,6 +156,12 @@ def watts(params: dict, hist: np.ndarray, t_valid: np.ndarray, config: dict,
     """The seam's name for ``temporal_watts``; the sizes are the parameters'
     own, so ``config`` has nothing more to say."""
     return temporal_watts(params, hist, t_valid, quantize)
+
+
+def small(config: dict) -> dict:
+    """``config`` at a size a CPU test holds: itself, since a trunk 128
+    wide is one already (its parameters are 0.9 MB)."""
+    return config
 
 
 def block_rows(config: dict) -> int:

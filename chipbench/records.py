@@ -18,7 +18,6 @@ records' clock with no estimate, and the records can contradict it (``align``).
 from __future__ import annotations
 
 from chipbench import trace
-from chipbench.estimators.temporal import PROGRAM  # noqa: F401 (see below)
 
 
 def delta(first: dict, last: dict, path: list):
@@ -70,13 +69,11 @@ def window_records(body: dict) -> list[dict]:
 
 # ``program`` below is the prefix of the window's program's name, which the
 # cell's estimator states (``estimators/<name>.py``: ``PROGRAM``) and the
-# harness always passes (``run.Run.program``). The default is the name from
-# before there was more than one estimator, kept for the callers from then.
+# caller passes (``run.Run.program``): nothing here knows an estimator.
 FETCH_SLACK_S = 1e-3  # the two clocks' rounding, and the host's half ms
 
 
-def program_runs(planes: list, program: str = PROGRAM) -> list[
-        tuple[float, float]]:
+def program_runs(planes: list, program: str) -> list[tuple[float, float]]:
     """Runs of the window's program (``XLA Modules`` events that carry its
     name) on the first plane that has any, in trace seconds, in order."""
     for plane in planes:
@@ -89,7 +86,7 @@ def program_runs(planes: list, program: str = PROGRAM) -> list[
 
 
 def align(body: dict, planes: list, launch: dict,
-          program: str = PROGRAM) -> dict | None:
+          program: str) -> dict | None:
     """Hold the trace's own zero against the body's records → {"offset_s",
     "checked", "contradicted", the least "launch_delay_s" and
     "fetch_margin_s" of the runs that fit, "against": for the first three
@@ -168,7 +165,7 @@ def _complement(spans: list, lo: float, hi: float) -> list:
 
 
 def idle_by_leg(body: dict, planes: list, launch: dict, groups: dict,
-                program: str = PROGRAM) -> dict | None:
+                program: str) -> dict | None:
     """The device's idle seconds inside the stretch that both the trace
     and the body's records cover, how much of it falls inside each group
     of legs (``groups``: name → span names of legs, as the body's table

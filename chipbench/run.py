@@ -62,6 +62,16 @@ def aggregator_config(cell: spec.Cell, params_path: str,
     return out
 
 
+def child_env(cell: spec.Cell, env: dict | None) -> dict:
+    """The aggregator's environment: ``env`` (the tests') or this
+    process's, with the configuration's ``runtime_env`` over it: settings
+    of the runtime under the program, as a deployment gives them."""
+    out = dict(os.environ if env is None else env)
+    out.update((key, str(value)) for key, value
+               in cell.config.get("runtime_env", {}).items())
+    return out
+
+
 class Run:
     """One run's observations, as the metric readers see them."""
 
@@ -139,7 +149,6 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
     """One run → (exit code, the result line's object or None).
     ``platform``, ``env`` and ``launcher`` are for the tests: a CPU child,
     a child whose timed path is broken on purpose."""
-    t_start = time.time()
     cell = spec.load_cell(root, workload)
     workdir = tempfile.mkdtemp(prefix="chipbench-")
     child = drive = None
@@ -147,8 +156,12 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
         params = cell.estimator().make_params(seed, cell.config)
         params_path = os.path.join(workdir, "params.npz")
         np.savez(params_path, **params)
+        # set-up starts where a deployment's does, with its checkpoint on
+        # disk: the seeded stand-in is the harness's, its load the program's
+        t_start = time.time()
         config = aggregator_config(cell, params_path, platform)
-        child = AggregatorChild(config, workdir, traced, env, launcher)
+        child = AggregatorChild(config, workdir, traced,
+                                child_env(cell, env), launcher)
         fleet = Fleet(cell.config, cell.traffic, seed)
         child.wait_ready(600.0)
         drive = run_window(child, fleet, cell.traffic,

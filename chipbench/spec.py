@@ -38,7 +38,18 @@ there, and edits none of them. The four kinds of file a cell may bring:
     ``XLA Modules`` line;
   - ``CONTROL``: a name of ``precision.QUANTIZERS``, the precision BELOW
     the one the configuration states, which ``watts`` takes as ``quantize``
-    when ``control.py`` puts it in the program's place.
+    when ``control.py`` puts it in the program's place;
+  - ``WIDTHS``: {key: value} that every configuration of the estimator
+    holds, as published (``tests/chipbench`` holds each file to them);
+  - ``small(config)`` → the configuration at a size a CPU test holds: the
+    same estimator and code, cut in widths and depth as far as a test
+    needs, with ``limits`` of its own, each set between a sound reading and
+    the control's, both written down. The tier-1 tests draw parameters and
+    run the reference only on it, never at published widths.
+
+A configuration brings one more file, its tier-1 goldens:
+``tests/chipbench/goldens/<config>.json`` (``params_sha256`` by seed, of
+``small(config)``; ``work`` by model pods, of the configuration itself).
 """
 
 from __future__ import annotations
@@ -50,7 +61,7 @@ from dataclasses import dataclass
 
 
 ESTIMATOR_NAMES = ("make_params", "watts", "block_rows", "work", "PROGRAM",
-                   "CONTROL")
+                   "CONTROL", "WIDTHS", "small")
 
 
 class SpecError(Exception):

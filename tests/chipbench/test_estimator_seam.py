@@ -1,14 +1,16 @@
 """The seam an estimator is found by: ``chipbench/estimators/<name>.py``.
 
-Three things, on the CPU, no aggregator but in the last two tests:
+Three things, on the CPU, no aggregator but in the last three tests:
 
-1. golden pins taken on the PARENT of the PR that cut the seam (PR 39,
-   parent ``e1a6e31``), before anything moved: the seeded parameters, the
-   operation count, the reference's watts and the control's readings are
-   what they were, so the move rewrote nothing;
+1. golden pins: every configuration's seeded parameters (of its
+   ``small``) and operation count are its goldens file's
+   (``goldens/<config>.json``), and the temporal reference's watts and
+   control readings are what they were before the seam was cut (at
+   ``e1a6e31``), so the move rewrote nothing;
 2. a second estimator, put into ``sys.modules`` with a configuration that
    names it: every part of the harness goes through it with no edit to a
-   file that is there;
+   file that is there; and one at published widths, added as files alone
+   to a copy of the tree, passes every per-configuration case;
 3. ``check.final_model_nodes``: how many model nodes of the final window go
    through the reference is the configuration's, and what is not sampled is
    still held to its form.
@@ -53,13 +55,7 @@ CONFIGS = [c["name"] for c in BENCH["configs"]]
 
 # -- 1. what the parent gave ---------------------------------------------------
 
-PARAMS_SHA256 = {
-    7: "a27fffd546d4462d31d7972d23c91aebc35c0f0d77ed3d0dc07113934aa783d2",
-    2 ** 31 + 11:
-        "ad93b3208d39b572639ba3172a50a6f0cfea61139d5ecb9ede83e3b12540e0a4",
-}
-WORK = {46_080: (65166336000.0, 22924416.0),
-        75_000: (106065000000.0, 36806016.0)}
+GOLDENS = os.path.join(HERE, "goldens")
 WATTS = {
     None: [[8.01245403289795, 6.964231491088867, 6.62423038482666,
             9.611072540283203],
@@ -117,19 +113,49 @@ def digest(params: dict) -> str:
     return h.hexdigest()
 
 
-@pytest.mark.parametrize("seed", sorted(PARAMS_SHA256))
-@pytest.mark.parametrize("config", CONFIGS)
-def test_seeded_parameters_are_byte_for_byte_what_they_were(config, seed):
+def golden_cases(key: str) -> list:
+    """(configuration, seed or pods, the golden) for every entry under
+    ``key`` of each configuration's goldens (``goldens/<config>.json``:
+    ``params_sha256`` by seed, of the estimator's ``small(config)``;
+    ``work`` by model pods, of the configuration itself, arithmetic and
+    cheap at any width). A configuration without the file gets one case,
+    which fails and names it."""
+    cases = []
+    for config in CONFIGS:
+        path = os.path.join(GOLDENS, f"{config}.json")
+        if not os.path.exists(path):
+            cases.append(pytest.param(config, None, None, id=config))
+            continue
+        with open(path, encoding="utf-8") as f:
+            found = json.load(f)[key]
+        cases += [pytest.param(config, int(k), found[k], id=f"{config}-{k}")
+                  for k in sorted(found, key=int)]
+    return cases
+
+
+def brings_goldens(config: str, want) -> None:
+    assert want is not None, (f"the configuration {config!r} brings no "
+                              f"tests/chipbench/goldens/{config}.json")
+
+
+@pytest.mark.parametrize("config, seed, want", golden_cases("params_sha256"))
+def test_seeded_parameters_are_byte_for_byte_what_they_were(config, seed,
+                                                            want):
+    brings_goldens(config, want)
     cfg = shipped(config)
-    params = spec.estimator_of(cfg).make_params(seed, cfg)
-    assert digest(params) == PARAMS_SHA256[seed]
+    estimator = spec.estimator_of(cfg)
+    assert digest(estimator.make_params(seed, estimator.small(cfg))) == want
+
+
+def test_the_temporal_parameters_hold_the_layers_its_reference_reads():
+    params = temporal.make_params(7, shipped("temporal-shipped"))
     assert set(params) >= {"in_proj", "pos_emb", "w_head", "w_skip"}
 
 
-@pytest.mark.parametrize("pods", sorted(WORK))
-@pytest.mark.parametrize("config", CONFIGS)
-def test_operation_count_is_what_it_was(config, pods):
-    assert work.of_config(shipped(config), pods) == WORK[pods]
+@pytest.mark.parametrize("config, pods, want", golden_cases("work"))
+def test_operation_count_is_what_it_was(config, pods, want):
+    brings_goldens(config, want)
+    assert work.of_config(shipped(config), pods) == tuple(want)
 
 
 @pytest.mark.parametrize("quantize", [None, "bf16", "fp8"], ids=str)
@@ -177,7 +203,7 @@ def test_the_names_from_before_the_seam_are_the_modules_own():
     assert reference.make_params is temporal.make_params
     assert reference.temporal_watts is temporal.temporal_watts
     assert work.window_work is temporal.window_work
-    assert records.PROGRAM is temporal.PROGRAM
+    assert not hasattr(records, "PROGRAM")  # its callers name the program
     assert temporal.CONTROL == "fp8"
     found = spec.estimator_of({"estimator": "temporal"})
     assert found is temporal
@@ -196,6 +222,7 @@ def fake_estimator() -> types.ModuleType:
                  "quantize": set()}
     mod.PROGRAM = "jit_fake_fleet_window"
     mod.CONTROL = "bf16"  # the fake configuration states float32
+    mod.WIDTHS = {"n_features": 7}
 
     def make_params(seed, config):
         mod.calls["make_params"] += 1
@@ -224,6 +251,7 @@ def fake_estimator() -> types.ModuleType:
 
     mod.make_params, mod.watts, mod.work = make_params, watts, work_
     mod.block_rows = lambda config: 250
+    mod.small = lambda config: config  # a test's size already
     return mod
 
 
@@ -367,20 +395,20 @@ def test_the_windows_program_is_found_by_the_estimators_prefix(fake):
 
     with open(os.path.join(HERE, "trace_small.json"), encoding="utf-8") as f:
         raw = json.load(f)["planes"]
-    old = renamed(raw, records.PROGRAM)
+    old = renamed(raw, temporal.PROGRAM)
     new = renamed(raw, fake.PROGRAM)
     body = twr.planted(old)
-    assert records.program_runs(new) == []  # not by the old name
+    assert records.program_runs(new, temporal.PROGRAM) == []  # not by that
     assert records.program_runs(new, fake.PROGRAM) == \
-        records.program_runs(old)
-    assert records.align(body, new, twr.ZERO) is None
+        records.program_runs(old, temporal.PROGRAM)
+    assert records.align(body, new, twr.ZERO, temporal.PROGRAM) is None
     fit = records.align(body, new, twr.ZERO, fake.PROGRAM)
-    assert fit == records.align(body, old, twr.ZERO)
+    assert fit == records.align(body, old, twr.ZERO, temporal.PROGRAM)
     assert (fit["checked"], fit["contradicted"]) == (2, 0)
     found = records.idle_by_leg(body, new, twr.ZERO, {"tick": twr.TICK},
                                 fake.PROGRAM)
     assert found == records.idle_by_leg(body, old, twr.ZERO,
-                                        {"tick": twr.TICK})
+                                        {"tick": twr.TICK}, temporal.PROGRAM)
     # the reader takes the prefix from the run, which has it from the cell
     from chipbench.readers import idle_by_leg
 
@@ -390,7 +418,159 @@ def test_the_windows_program_is_found_by_the_estimators_prefix(fake):
             planes=new, launch=twr.ZERO, program=program)
 
     assert idle_by_leg.read(a_run(fake.PROGRAM), legs=twr.TICK) is not None
-    assert idle_by_leg.read(a_run(records.PROGRAM), legs=twr.TICK) is None
+    assert idle_by_leg.read(a_run(temporal.PROGRAM), legs=twr.TICK) is None
+
+
+# an estimator at published widths, as the files a later change would add
+WIDE_MODULE = '''"""An estimator at published widths whose parameters no test may draw:
+``make_params`` refuses more than 64 MB. Its ``small`` is the temporal trunk
+32 wide, and its arithmetic is temporal's."""
+
+from chipbench.estimators import temporal
+
+PROGRAM = "jit_wide_fleet_window"
+CONTROL = "fp8"
+WIDTHS = {"d_model": 6144, "n_heads": 48, "mlp_dim": 24576, "n_features": 7}
+MAX_BYTES = 64 * 2 ** 20
+READINGS = ("CPU, 16 nodes under paced with churn 0.25, seeds 11-22 and "
+            "2147483653: lower = largest at bf16 (the stated precision), "
+            "upper = smallest at fp8 (the control)")
+LIMITS = {
+    "model_pod_rms_rel": {"limit": 3e-3, "lower": 8.92e-4, "upper": 9.02e-3},
+    "model_pod_max_rel": {"limit": 1e-2, "lower": 3.26e-3, "upper": 2.95e-2},
+    "model_node_max_rel": {"limit": 2.5e-3, "lower": 1.08e-3,
+                           "upper": 6.39e-3},
+    "ratio_pod_max_rel": {"limit": 1e-5, "lower": 2.12e-16, "upper": 7.66e-3},
+    "ratio_node_max_rel": {"limit": 1e-5, "lower": 2.12e-16,
+                           "upper": 2.90e-3},
+}
+
+
+def make_params(seed, config):
+    d, d_mlp = int(config["d_model"]), int(config["mlp_dim"])
+    floats = 4 * d * d + 2 * d * d_mlp + (int(config["t_max"]) + 16) * d
+    if 4 * floats > MAX_BYTES:
+        raise MemoryError(f"{4 * floats} bytes of parameters asked for")
+    return temporal.make_params(seed, config)
+
+
+def small(config):
+    limits = dict(config["limits"])
+    limits.update({k: dict(v, readings=READINGS) for k, v in LIMITS.items()})
+    return dict(config, d_model=32, n_heads=4, mlp_dim=128, limits=limits)
+
+
+watts, block_rows, work = temporal.watts, temporal.block_rows, temporal.work
+'''
+WIDE_GOLDENS = {  # of the module above, taken once by hand
+    "params_sha256": {
+        "7": "0a1dbeac8096c5bed2ba17911bff1e0fe683ae5ec80007125044b345b7fc7315",
+        "2147483659":
+            "a614aab02b1360f5943f01816db9f7d4772b920bcac549fe3162adcd5aff2134"},
+    "work": {"46080": [146198592184320.0, 1834991744.0],
+             "75000": [237953437800000.0, 1848873344.0]}}
+PER_CONFIGURATION = {  # every case a configuration and its one cell have
+    "test_names_units_and_lines_keep_to_the_contract": ["", ".paced"],
+    "test_configuration_file_parses_and_states_every_limit": [""],
+    "test_cell_is_found_by_name_and_reports_what_it_must": [".paced"],
+    "test_control_in_the_programs_place_is_not_correct": [""],
+    "test_seeded_parameters_are_byte_for_byte_what_they_were":
+        ["-7", "-2147483659"],
+    "test_operation_count_is_what_it_was": ["-46080", "-75000"],
+}
+
+
+def tree_hashes(root: str) -> dict:
+    out = {}
+    for folder, _dirs, files in os.walk(root):
+        for name in files:
+            path = os.path.join(folder, name)
+            with open(path, "rb") as f:
+                out[os.path.relpath(path, root)] = hashlib.sha256(
+                    f.read()).hexdigest()
+    return out
+
+
+def test_an_estimator_at_published_widths_is_added_as_files_alone(tmp_path):
+    """A copy of the yardstick with only files added (and entries in
+    ``BENCHMARK.json``): an estimator whose parameters at its published
+    widths no test may draw, its configuration, one cell on ``paced``, the
+    configuration's goldens, and its roofline and whole-step share. Every
+    per-configuration case of the copy passes for it, none is skipped, and
+    no file that was copied changed."""
+    import subprocess
+    import xml.etree.ElementTree as ET
+
+    root = str(tmp_path / "tree")
+    skip = shutil.ignore_patterns("__pycache__", "*.pyc")
+    for sub in ("chipbench", os.path.join("tests", "chipbench")):
+        shutil.copytree(os.path.join(REPO, sub), os.path.join(root, sub),
+                        ignore=skip)
+    copied = tree_hashes(root)
+
+    name, cell = "wide-published", "wide-published.paced"
+    cfg = dict(shipped("temporal-shipped"), name=name, estimator="wide",
+               source="a test", reduced={}, d_model=6144, n_heads=48,
+               mlp_dim=24576)
+    added = {
+        "chipbench/estimators/wide.py": WIDE_MODULE,
+        f"chipbench/configs/{name}.json": json.dumps(cfg),
+        f"tests/chipbench/goldens/{name}.json": json.dumps(WIDE_GOLDENS),
+        "chipbench/metrics/wide_roofline.paced.json": json.dumps(
+            {"name": "wide_roofline.paced", "reader": "roofline"}),
+        "chipbench/metrics/wide_mfu.paced.json": json.dumps(
+            {"name": "wide_mfu.paced", "reader": "step_mfu"}),
+    }
+    for rel, text in added.items():
+        path = os.path.join(root, rel)
+        assert not os.path.exists(path), rel  # added, never overwritten
+        with open(path, "w", encoding="utf-8") as f:
+            f.write(text)
+    bench = copy.deepcopy(BENCH)
+    bench["configs"].append({"name": name, "source": "a test",
+                             "file": f"chipbench/configs/{name}.json",
+                             "reduced": [], "why": "a test"})
+    bench["workloads"].append({"name": cell, "config": name,
+                               "traffic": "paced", "chips": 1,
+                               "why": "a test"})
+    for m in bench["end_to_end"]:
+        if "temporal-shipped.paced" in m.get("workloads", []):
+            m["workloads"].append(cell)
+    for metric, layer, source in (
+            ("wide_roofline.paced", "kernels", "device_trace"),
+            ("wide_mfu.paced", "whole window path", "program_counter")):
+        bench["per_layer"].append({
+            "name": metric, "unit": "%", "better": "higher",
+            "source": source, "layer": layer,
+            "moves": "window_latency_p50_ms", "workloads": [cell]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w",
+              encoding="utf-8") as f:
+        json.dump(bench, f)
+
+    xml = str(tmp_path / "cases.xml")
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("PYTEST_")}
+    env.update(JAX_PLATFORMS="cpu", PYTHONDONTWRITEBYTECODE="1",
+               PYTHONPATH=REPO)  # the program itself, beside the copy
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-p", "no:xdist",
+         "-p", "no:cacheprovider", "-p", "no:randomly", "-q",
+         "-k", f"{name} or wide_ or test_layers_of_one_name"
+               " or test_every_file_under_traffic",
+         "--junitxml", xml, "tests/chipbench/test_harness.py",
+         "tests/chipbench/test_estimator_seam.py"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=120)
+    tail = proc.stdout[-4000:] + proc.stderr[-2000:]
+    assert proc.returncode == 0, tail
+
+    outcome = {case.get("name"): [child.tag for child in case] or ["passed"]
+               for case in ET.parse(xml).getroot().iter("testcase")}
+    want = [f"{test}[{name}{tail}]"
+            for test, tails in PER_CONFIGURATION.items() for tail in tails]
+    assert {t: outcome.get(t) for t in want} == {t: ["passed"] for t in want}
+    assert all(v == ["passed"] for v in outcome.values()), outcome
+    assert "test_layers_of_one_name_are_spelled_alike" in outcome
+    assert tree_hashes(root).items() >= copied.items()
 
 
 # -- 3. how much of the final window is compared -------------------------------

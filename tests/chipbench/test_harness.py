@@ -87,9 +87,9 @@ def test_configuration_file_parses_and_states_every_limit(config):
     assert cfg["name"] == config["name"]
     assert cfg["source"] == config["source"]
     assert sorted(cfg["reduced"]) == sorted(config["reduced"])
-    # the widths are the estimator's own: none is cut
-    assert (cfg["d_model"], cfg["n_heads"], cfg["mlp_dim"],
-            cfg["n_features"]) == (128, 4, 512, 7)
+    # the widths are the estimator's own, as published: none is cut
+    for key, value in spec.estimator_of(cfg).WIDTHS.items():
+        assert cfg.get(key) == value, key
     for name in check.Errors().numbers:
         row = cfg["limits"][name]
         assert row["limit"] >= 0
@@ -116,6 +116,19 @@ def test_cell_is_found_by_name_and_reports_what_it_must(cell):
         assert found.workload["chips"] == 4
 
 
+@pytest.mark.parametrize("cell", CELLS)
+def test_the_configurations_runtime_env_reaches_the_aggregator(cell):
+    from chipbench import run
+
+    found = spec.load_cell(REPO, cell)
+    given = {"JAX_PLATFORMS": "cpu", "TPU_PREMAPPED_BUFFER_SIZE": "1"}
+    wanted = found.config.get("runtime_env", {})
+    assert all(isinstance(v, str) for v in wanted.values())
+    assert run.child_env(found, given) == {**given, **wanted}
+    assert given == {"JAX_PLATFORMS": "cpu",
+                     "TPU_PREMAPPED_BUFFER_SIZE": "1"}  # not changed
+
+
 def test_every_file_under_traffic_and_metrics_parses():
     for sub in ("traffic", "metrics", "configs"):
         folder = os.path.join(REPO, "chipbench", sub)
@@ -132,13 +145,19 @@ def test_every_file_under_traffic_and_metrics_parses():
 def test_layers_of_one_name_are_spelled_alike():
     layers = {m["layer"] for m in BENCH["per_layer"]}
     assert len({x.lower() for x in layers}) == len(layers)
+    # every roofline has one whole-step share beside it: a metric with
+    # ``mfu`` in its name, of the same mix, moving the same metric in the
+    # same cells, so that a kernel taken off the path leaves a bound
     roofs = [m for m in BENCH["per_layer"] if "roofline" in m["name"]]
     for m in roofs:
         assert m["unit"] == "%"
-        twin = m["name"].replace("temporal_roofline", "window_mfu")
-        mfu = next(x for x in BENCH["per_layer"] if x["name"] == twin)
-        assert mfu["moves"] == m["moves"]
-        assert sorted(mfu["workloads"]) == sorted(m["workloads"])
+        mix = m["name"].rsplit(".", 1)[-1]
+        twins = [x for x in BENCH["per_layer"]
+                 if "mfu" in x["name"] and x["name"].endswith("." + mix)
+                 and x["moves"] == m["moves"]
+                 and sorted(x.get("workloads", CELLS))
+                 == sorted(m.get("workloads", CELLS))]
+        assert len(twins) == 1, (m["name"], [x["name"] for x in twins])
 
 
 # -- operations and bytes -------------------------------------------------------
@@ -334,35 +353,45 @@ def test_pods_come_and_go_and_a_newcomers_history_starts_with_it():
 
 
 def small_cell(config: dict, nodes: int = 16) -> spec.Cell:
-    """The configuration at a size a test run can hold, under the flood
-    mix's own parameters with churn a node of 16 can show."""
+    """The configuration at a size a test run can hold (its estimator's
+    ``small``, at ``nodes`` nodes), under its first cell's mix with churn a
+    node of 16 can show."""
     with open(os.path.join(REPO, config["file"]), encoding="utf-8") as f:
         cfg = json.load(f)
     found = spec.load_cell(REPO, next(
         w["name"] for w in BENCH["workloads"] if w["config"] == cfg["name"]))
     traffic = dict(found.traffic, churn_node_share=0.25)
-    return spec.Cell(REPO, BENCH, found.workload, dict(cfg, nodes=nodes),
+    cut = found.estimator().small(cfg)
+    return spec.Cell(REPO, BENCH, found.workload, dict(cut, nodes=nodes),
                      traffic)
+
+
+# the precision a configuration states, by its estimator's control, the
+# step below it
+STATED = {"fp8": "bf16", "bf16": "f32"}
 
 
 @pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
 def test_control_in_the_programs_place_is_not_correct(config):
-    """The control: the reference in fp8 (e4m3), the step below the bf16
-    the configuration states, put in the program's place and taken through
-    the run's own comparison. It has to come out not correct; the same
-    with bf16 operands, which is what the chip computes, has to pass.
-    Here at 16 nodes; ``chipbench/control.py`` does it at the cell's own
-    size, and PERF.md has those readings."""
+    """The control: the reference in the estimator's ``CONTROL`` (fp8 e4m3
+    for temporal), the step below the precision the configuration states
+    (bf16), put in the program's place and taken through the run's own
+    comparison. It has to come out not correct; the same at the stated
+    precision, which is what the chip computes, has to pass. Here on the
+    estimator's ``small`` configuration at 16 nodes, never at published
+    widths; ``chipbench/control.py`` does it at the cell's own size, and
+    PERF.md has those readings."""
     cell = small_cell(config)
-    correct, fp8 = control.control_run(cell, 11, "fp8")
+    lower = cell.estimator().CONTROL
+    correct, low = control.control_run(cell, 11, lower)
     assert correct is False
-    over = [k for k, v in fp8.items() if v["value"] > v["limit"]]
-    assert any(k.startswith("model_") for k in over), fp8
-    assert any(k.startswith("ratio_") for k in over), fp8
-    correct, bf16 = control.control_run(cell, 11, "bf16")
-    assert correct is True, bf16
-    assert fp8["model_pod_rms_rel"]["value"] \
-        > 3 * bf16["model_pod_rms_rel"]["value"]
+    over = [k for k, v in low.items() if v["value"] > v["limit"]]
+    assert any(k.startswith("model_") for k in over), low
+    assert any(k.startswith("ratio_") for k in over), low
+    correct, stated = control.control_run(cell, 11, STATED[lower])
+    assert correct is True, stated
+    assert low["model_pod_rms_rel"]["value"] \
+        > 3 * stated["model_pod_rms_rel"]["value"]
 
 
 def test_an_answer_whose_round_is_in_doubt_is_held_against_the_nearer():

@@ -17,6 +17,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 from chipbench import records, trace  # noqa: E402
+from chipbench.estimators.temporal import PROGRAM  # noqa: E402
 from chipbench.readers import count_ratio, idle_by_leg, leg_median  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -173,7 +174,7 @@ def planes():
         planes = json.load(f)["planes"]
     for event in trace.module_events(planes[0]):
         assert event[0].startswith("jit__unknown(")
-        event[0] = event[0].replace("jit__unknown", records.PROGRAM)
+        event[0] = event[0].replace("jit__unknown", PROGRAM)
     return planes
 
 
@@ -183,7 +184,7 @@ def planted(planes, fetched_early: float = 0.0) -> dict:
     ended; the records before and after them ran nothing the trace holds.
     Served as the aggregator serves them: boundaries as seconds after the
     stamp."""
-    runs = records.program_runs(planes)
+    runs = records.program_runs(planes, PROGRAM)
     assert len(runs) == 2
     ran = {3: runs[0], 4: runs[1]}
     begins = {3: OFFSET + runs[0][0] - DELAYS[0] - 0.31,
@@ -217,7 +218,7 @@ def test_aligner_takes_the_offset_from_the_traces_own_zero(planes):
     recs = records.window_records(body)
     assert [r["seq"] for r in recs] == [0, 1, 2, 3, 4, 5]
     assert recs[3]["h2d"] == pytest.approx(recs[3]["stamp"] + 0.31)
-    fit = records.align(body, planes, ZERO)
+    fit = records.align(body, planes, ZERO, PROGRAM)
     assert fit["offset_s"] == OFFSET  # exact: no estimate is made
     assert (fit["checked"], fit["contradicted"]) == (2, 0)
     assert fit["launch_delay_s"] == pytest.approx(min(DELAYS), abs=1e-6)
@@ -228,7 +229,8 @@ def test_aligner_takes_the_offset_from_the_traces_own_zero(planes):
     events = trace.module_events(helper[0])
     events.append(["jit_convert_element_type(7)", events[0][1] + 400_000_000,
                    events[0][2]])
-    assert records.program_runs(helper) == records.program_runs(planes)
+    assert records.program_runs(helper, PROGRAM) == \
+        records.program_runs(planes, PROGRAM)
 
 
 def test_aligner_counts_what_contradicts_the_zero_and_then_nothing_prints(
@@ -236,40 +238,42 @@ def test_aligner_counts_what_contradicts_the_zero_and_then_nothing_prints(
     def run_with_zero(body, launch):
         return SimpleNamespace(
             drive=SimpleNamespace(debug={"first": {}, "last": body}),
-            planes=planes, launch=launch, program=records.PROGRAM)
+            planes=planes, launch=launch, program=PROGRAM)
 
     body = planted(planes)
     assert idle_by_leg.read(run_with_zero(body, ZERO), legs=TICK) is not None
     # a window fetched before its program's run ended
     early = planted(planes, fetched_early=0.03)
-    fit = records.align(early, planes, ZERO)
+    fit = records.align(early, planes, ZERO, PROGRAM)
     assert (fit["checked"], fit["contradicted"]) == (2, 1)
-    assert records.idle_by_leg(early, planes, ZERO, {"tick": TICK}) is None
+    assert records.idle_by_leg(early, planes, ZERO, {"tick": TICK},
+                               PROGRAM) is None
     assert idle_by_leg.read(run_with_zero(early, ZERO), legs=TICK) is None
     # a zero 50 ms late: both runs end after their windows were fetched
     late = {"profile_start_time": ZERO_NS + 50_000_000}
-    assert records.align(body, planes, late)["contradicted"] == 2
+    assert records.align(body, planes, late, PROGRAM)["contradicted"] == 2
     assert idle_by_leg.read(run_with_zero(body, late), legs=TICK) is None
     # a zero 0.5 s early lays each run on a window that never ran it
     soon = {"profile_start_time": ZERO_NS - 500_000_000}
-    assert records.align(body, planes, soon)["contradicted"] == 2
+    assert records.align(body, planes, soon, PROGRAM)["contradicted"] == 2
     # a zero so early that no record had begun: nothing to hold it against
     never = {"profile_start_time": ZERO_NS - 10_000_000_000}
-    assert records.align(body, planes, never) is None
+    assert records.align(body, planes, never, PROGRAM) is None
     # no start time (an older JAX), no record, no table of legs, no trace,
     # no run that carries the program's name
-    assert records.align(body, planes, {}) is None
-    assert records.align(body, planes, {"marks": {"start": OFFSET}}) is None
+    assert records.align(body, planes, {}, PROGRAM) is None
+    assert records.align(body, planes, {"marks": {"start": OFFSET}},
+                         PROGRAM) is None
     assert idle_by_leg.read(run_with_zero(body, {}), legs=TICK) is None
     none = {"records": {**body["records"], "rows": []}}
-    assert records.align(none, planes, ZERO) is None
+    assert records.align(none, planes, ZERO, PROGRAM) is None
     bare = {"records": {**body["records"], "legs": {}}}
-    assert records.align(bare, planes, ZERO) is None
-    assert records.align(body, [], ZERO) is None
+    assert records.align(bare, planes, ZERO, PROGRAM) is None
+    assert records.align(body, [], ZERO, PROGRAM) is None
     with open(os.path.join(HERE, "trace_small.json"), encoding="utf-8") as f:
         unnamed = json.load(f)["planes"]
-    assert records.program_runs(unnamed) == []
-    assert records.align(body, unnamed, ZERO) is None
+    assert records.program_runs(unnamed, PROGRAM) == []
+    assert records.align(body, unnamed, ZERO, PROGRAM) is None
 
 
 def windows_by_hand(landing: dict | None = None, stamp_off: dict | None = None,
@@ -293,7 +297,7 @@ def windows_by_hand(landing: dict | None = None, stamp_off: dict | None = None,
         rows.append([seq, OFFSET + begin + (stamp_off or {}).get(seq, 0.0),
                      "legacy"] + [marks[m] for m in FIELDS[3:14]]
                     + [0.07, 262144, 46080, 120_000_000, False])
-        events.append([f"{records.PROGRAM}({seq})", int(start * 1e9),
+        events.append([f"{PROGRAM}({seq})", int(start * 1e9),
                        50_000_000])
     planes = [{"plane": "/device:TPU:0",
                "lines": [{"line": "XLA Modules", "events": events}]}]
@@ -305,19 +309,20 @@ def test_a_put_that_lands_late_is_its_own_windows_run_all_the_same():
     # window's dispatch had begun; its run was laid on that window, which
     # then held two, and the idle metrics printed nothing
     body, planes = windows_by_hand()
-    fit = records.align(body, planes, ZERO)
+    fit = records.align(body, planes, ZERO, PROGRAM)
     assert (fit["checked"], fit["contradicted"]) == (40, 0)
     assert fit["launch_delay_s"] == pytest.approx(0.075, abs=1e-6)
     body, planes = windows_by_hand(landing={17: 0.19, 30: 0.40})
-    runs = records.program_runs(planes)
+    runs = records.program_runs(planes, PROGRAM)
     began = [r["h2d"] for r in records.window_records(body)]
     assert runs[17][0] + OFFSET > began[18]  # after the next one's dispatch
     assert runs[30][0] + OFFSET > began[32]  # and after the one after it
-    fit = records.align(body, planes, ZERO)
+    fit = records.align(body, planes, ZERO, PROGRAM)
     assert (fit["checked"], fit["contradicted"]) == (40, 0)
     assert fit["fetch_margin_s"] == pytest.approx(FETCH, abs=1e-6)
     assert fit["against"] == []
-    assert records.idle_by_leg(body, planes, ZERO, {"tick": TICK}) is not None
+    assert records.idle_by_leg(body, planes, ZERO, {"tick": TICK},
+                               PROGRAM) is not None
 
 
 def test_one_record_in_forty_is_no_case_against_the_zero_but_three_are():
@@ -325,7 +330,7 @@ def test_one_record_in_forty_is_no_case_against_the_zero_but_three_are():
     # after its run started, so the run fits no window; the zero is one
     # number for all forty runs, and the other thirty-nine agree with it
     body, planes = windows_by_hand(stamp_off={11: 0.2})
-    fit = records.align(body, planes, ZERO)
+    fit = records.align(body, planes, ZERO, PROGRAM)
     assert (fit["checked"], fit["contradicted"]) == (40, 1)
     # the run is held against the next window it could be, 12, and started
     # before that one's dispatch began and ended before it was fetched
@@ -333,23 +338,26 @@ def test_one_record_in_forty_is_no_case_against_the_zero_but_three_are():
     assert window == 12
     assert after_dispatch == pytest.approx(0.075 - 0.15, abs=1e-5)
     assert after_fetched == pytest.approx(-FETCH - 0.15, abs=1e-5)
-    sound = records.idle_by_leg(*windows_by_hand(), ZERO, {"tick": TICK})
-    found = records.idle_by_leg(body, planes, ZERO, {"tick": TICK})
+    sound = records.idle_by_leg(*windows_by_hand(), ZERO, {"tick": TICK},
+                                PROGRAM)
+    found = records.idle_by_leg(body, planes, ZERO, {"tick": TICK}, PROGRAM)
     assert found["in_s"]["tick"] == pytest.approx(sound["in_s"]["tick"],
                                                   rel=0.05)
     body, planes = windows_by_hand(stamp_off={5: 0.2, 17: 0.2, 29: 0.2})
-    assert records.align(body, planes, ZERO)["contradicted"] == 3
-    assert records.idle_by_leg(body, planes, ZERO, {"tick": TICK}) is None
+    assert records.align(body, planes, ZERO, PROGRAM)["contradicted"] == 3
+    assert records.idle_by_leg(body, planes, ZERO, {"tick": TICK},
+                               PROGRAM) is None
     # and a zero that is wrong is contradicted by every run
     late = {"profile_start_time": ZERO_NS + 50_000_000}
     body, planes = windows_by_hand()
-    assert records.align(body, planes, late)["contradicted"] == 40
-    assert records.idle_by_leg(body, planes, late, {"tick": TICK}) is None
+    assert records.align(body, planes, late, PROGRAM)["contradicted"] == 40
+    assert records.idle_by_leg(body, planes, late, {"tick": TICK},
+                               PROGRAM) is None
 
 
 def test_idle_shares_and_the_rest_sum_to_the_whole_idle_time(planes):
     found = records.idle_by_leg(planted(planes), planes, ZERO,
-                                {"assembly": ASSEMBLY, "tick": TICK})
+                                {"assembly": ASSEMBLY, "tick": TICK}, PROGRAM)
     inside = found["in_s"]
     assert found["idle_s"] > 0 and found["offset_s"] == OFFSET
     assert inside["assembly"] + inside["tick"] + found["rest_s"] == \
@@ -357,14 +365,14 @@ def test_idle_shares_and_the_rest_sum_to_the_whole_idle_time(planes):
     # between the two runs the device idles 0.736 s; record 4's tick wait
     # (0.05 s) and its assembly and h2d (0.31 s) lie inside that gap, whole
     busy = trace.busy_seconds(planes)
-    window = (records.program_runs(planes)[1][1]
-              - records.program_runs(planes)[0][0])
+    window = (records.program_runs(planes, PROGRAM)[1][1]
+              - records.program_runs(planes, PROGRAM)[0][0])
     assert found["idle_s"] == pytest.approx(window - busy, rel=0.02)
     assert inside["tick"] == pytest.approx(0.05, abs=1e-6)
     assert inside["assembly"] == pytest.approx(0.31, abs=1e-6)
     run = SimpleNamespace(
         drive=SimpleNamespace(debug={"first": {}, "last": planted(planes)}),
-        planes=planes, launch=ZERO, program=records.PROGRAM)
+        planes=planes, launch=ZERO, program=PROGRAM)
     both = (idle_by_leg.read(run, legs=ASSEMBLY)
             + idle_by_leg.read(run, legs=TICK))
     assert both == pytest.approx(
