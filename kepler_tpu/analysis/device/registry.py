@@ -224,6 +224,21 @@ def _build_fleet(case: ProgramCase) -> tuple:
     return fn, (_mlp_avals(z),) + batch
 
 
+def _build_pallas_temporal(case: ProgramCase) -> tuple:
+    import functools
+
+    import jax
+
+    from kepler_tpu.ops.pallas_temporal import last_query_trunk_pallas
+
+    d = case.dims
+    b, t = d["b"], d["t"]
+    fn = jax.jit(functools.partial(last_query_trunk_pallas,
+                                   block_rows=d["block"], interpret=True))
+    return fn, (_temporal_avals(d["z"]), _sds((b, t, 7), _F32),
+                _sds((b, t), _BOOL))
+
+
 def _build_pallas_attribution(case: ProgramCase) -> tuple:
     import functools
 
@@ -564,6 +579,23 @@ DEVICE_PROGRAMS: tuple[ProgramSpec, ...] = (
             ProgramCase("n8_w8_z2", dims={"n": 8, "w": 8, "z": 2}),
         ),
         n_devices=1,
+    ),
+    ProgramSpec(
+        name="ops.pallas_temporal",
+        source="kepler_tpu/ops/pallas_temporal.py",
+        description="the served temporal trunk as one Mosaic kernel over "
+                    "blocks of history rows, unsharded (interpret mode "
+                    "off-TPU)",
+        build=_build_pallas_temporal,
+        cases=(
+            ProgramCase("b40_t16_block16", "a part-full last block",
+                        dims={"b": 40, "t": 16, "block": 16, "z": 2}),
+        ),
+        n_devices=1,
+        # bf16 matmul operands; bf16-rounded K, V, query and
+        # probabilities widened back to f32 for exact VPU products
+        allowed_half_casts=frozenset({"float32->bfloat16",
+                                      "bfloat16->float32"}),
     ),
     ProgramSpec(
         name="ring.attention",

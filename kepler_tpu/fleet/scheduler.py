@@ -53,6 +53,7 @@ from kepler_tpu.parallel.aggregator_core import (
     HISTORY_ROWS_BASE,
     compact_history,
     fleet_shardings,
+    fused_trunk_chosen,
     make_fleet_program,
     make_temporal_fleet_program,
     put_fleet_batch,
@@ -1576,6 +1577,9 @@ class WindowScheduler:
         if temporal:  # S · R; of the dense window, N · W: the estimator's
             rec.hist_rows_sent = rec.rows_program = math.prod(
                 args[9].shape[:2])
+            if fused_trunk_chosen(self.mesh.devices.flat[0].platform,
+                                  self._accuracy_mode):
+                rec.rows_fused = rec.rows_program
         rec.h2d_bytes = sum(int(a.nbytes) for a in args[1:])
         # a NamedSharding's shards are all of one shape, so the device
         # that was sent most was sent one shard of every argument

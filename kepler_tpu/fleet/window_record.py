@@ -66,7 +66,7 @@ LEGS = {
 # counts is served in the sums alone (``chipbench`` pins a row's columns)
 ROW_COUNTS = ("rows_program", "rows_work", "h2d_bytes")
 SUM_COUNTS = ("devices", "h2d_bytes_max_device", "published_early",
-              "hist_rows_sent")
+              "hist_rows_sent", "rows_fused")
 COUNTS = ROW_COUNTS + SUM_COUNTS
 
 FIELDS = ("seq", "stamp", "kind") + MARKS + ("assembly_cpu_s",) \
@@ -122,6 +122,9 @@ class WindowRecord:
         # history rows the temporal program was sent: shards × the bucket
         # that holds the fullest shard's valid rows (padding included)
         self.hist_rows_sent = 0
+        # of rows_program, the rows the fused trunk kernel ran on: all of
+        # them where the program was built with it, else none
+        self.rows_fused = 0
         self.compiled = False
 
     @contextlib.contextmanager

@@ -214,6 +214,7 @@ def predict_temporal(
     clamp: bool = True,
     compute_dtype: jnp.dtype = jnp.bfloat16,
     attention_fn=None,  # override for sequence-parallel ring attention
+    last_query_fn=None,  # a kernel in _last_query_trunk's place
 ) -> jax.Array:
     """→ watts f32 [..., W, Z] predicted from each workload's history.
 
@@ -222,7 +223,9 @@ def predict_temporal(
     last ``t_valid`` position, falling back to position 0 when empty).
     Dense serving (no ``attention_fn``) uses the single-query fast path;
     a custom attention_fn (ring attention over a sharded T axis) keeps the
-    full-sequence trunk.
+    full-sequence trunk. ``last_query_fn`` (same arguments and result as
+    ``_last_query_trunk``) replaces the fast path: the served program passes
+    the fused kernel of ``ops.pallas_temporal`` there.
     """
     lead = feat_hist.shape[:-2]
     t, f = feat_hist.shape[-2:]
@@ -231,7 +234,8 @@ def predict_temporal(
           else t_valid.reshape(-1, t))
     last = jnp.maximum(jnp.sum(tv, axis=-1) - 1, 0).astype(jnp.int32)
     if attention_fn is None:
-        pooled = _last_query_trunk(params, x, tv, compute_dtype)
+        trunk = last_query_fn or _last_query_trunk
+        pooled = trunk(params, x, tv, compute_dtype)
     else:
         hidden = temporal_trunk(params, x, tv, attention_fn=attention_fn,
                                 compute_dtype=compute_dtype)

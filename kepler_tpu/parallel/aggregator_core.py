@@ -138,12 +138,15 @@ def temporal_fleet_program(
     *history: jax.Array,
     attribute_fn=attribute_fleet,
     accuracy_mode: bool = False,
+    last_query_fn=None,
 ) -> FleetResult:
     """Mixed fleet with the TEMPORAL estimator: the aggregator accretes each
     workload's feature history (`kepler_tpu.monitor.history`) and the model
     predicts from the whole window instead of the last tick. Handed
     :func:`compact_history`'s blocks in the dense windows' place, it runs
     the estimator on the rows that were sent (:func:`estimate_sent_rows`).
+    ``last_query_fn`` reaches ``predict_temporal`` (the fused trunk:
+    :func:`fused_trunk_chosen`).
     """
     from kepler_tpu.models.temporal import predict_temporal
 
@@ -156,6 +159,8 @@ def temporal_fleet_program(
         )
     pfn = (accuracy_mode_predictor(predict_temporal, "temporal")
            if accuracy_mode else predict_temporal)
+    if last_query_fn is not None:
+        pfn = functools.partial(pfn, last_query_fn=last_query_fn)
     if len(history) == 3:  # hist_rows, tv_rows, row_of
         watts = estimate_sent_rows(pfn, model_params, workload_valid,
                                    *history)
@@ -384,6 +389,43 @@ def estimate_sent_rows(
     return jnp.where(workload_valid[..., None], watts, 0.0)
 
 
+def fused_trunk_chosen(platform: str, accuracy_mode: bool,
+                       compute_dtype: jnp.dtype = jnp.bfloat16) -> bool:
+    """Whether the served temporal program runs its trunk as the fused
+    kernel (``ops.pallas_temporal``): on a TPU, in bf16, and not in
+    accuracy mode (f32 at precision HIGHEST keeps the XLA trunk). Off a
+    TPU the XLA trunk runs, the one every other caller differentiates,
+    shards or pins bit for bit."""
+    return (platform == "tpu" and not accuracy_mode
+            and jnp.dtype(compute_dtype) == jnp.dtype(jnp.bfloat16))
+
+
+def _fused_trunk(mesh: Mesh, per_shard: bool):
+    """→ the fused kernel as a ``last_query_fn``. Unless the caller already
+    runs per shard, it is mapped over the node axis: rows are shard-major,
+    so each device runs the kernel on its own block of rows. Off a TPU it
+    runs interpreted."""
+    from kepler_tpu.ops.pallas_temporal import last_query_trunk_pallas
+
+    interpret = mesh.devices.flat[0].platform != "tpu"
+
+    def kernel_fn(params, feat_hist, t_valid, compute_dtype):
+        return last_query_trunk_pallas(params, feat_hist, t_valid,
+                                       compute_dtype, interpret=interpret)
+
+    if per_shard:
+        return kernel_fn
+
+    def sharded(params, feat_hist, t_valid, compute_dtype):
+        kernel = functools.partial(kernel_fn, compute_dtype=compute_dtype)
+        return jax.shard_map(
+            kernel, mesh=mesh, in_specs=(P(), P(NODE_AXIS), P(NODE_AXIS)),
+            out_specs=P(NODE_AXIS), check_vma=False,
+        )(params, feat_hist, t_valid)
+
+    return sharded
+
+
 def make_temporal_fleet_program(mesh: Mesh, backend: str = "einsum",
                                 accuracy_mode: bool = False,
                                 compact: bool = False):
@@ -396,12 +438,19 @@ def make_temporal_fleet_program(mesh: Mesh, backend: str = "einsum",
     shard's block on its own device, runs the estimator on the rows that
     were sent and gathers their watts to the dense slots there
     (:func:`estimate_sent_rows`); the ratio attribution and the mix are
-    the dense program's."""
+    the dense program's.
+
+    The estimator's trunk is the fused kernel where
+    :func:`fused_trunk_chosen` says so for the mesh's devices."""
     replicated, by_node = fleet_shardings(mesh)
     n_history = 3 if compact else 2
+    last_query_fn = None
+    if fused_trunk_chosen(mesh.devices.flat[0].platform, accuracy_mode):
+        last_query_fn = _fused_trunk(mesh, per_shard=backend == "pallas")
     fn = functools.partial(temporal_fleet_program,
                            attribute_fn=resolve_attribute_fn(mesh, backend),
-                           accuracy_mode=accuracy_mode)
+                           accuracy_mode=accuracy_mode,
+                           last_query_fn=last_query_fn)
     if backend == "pallas":
         data_specs = (P(NODE_AXIS, None), P(NODE_AXIS, None), P(NODE_AXIS),
                       P(NODE_AXIS, None), P(NODE_AXIS, None), P(NODE_AXIS),

@@ -186,7 +186,7 @@ def test_counts_and_ingest_only_grow_and_add_up_over_the_records(served):
     import jax
     n_dev = len(jax.devices())
     assert SUM_COUNTS == ("devices", "h2d_bytes_max_device",
-                          "published_early", "hist_rows_sent")
+                          "published_early", "hist_rows_sent", "rows_fused")
     assert not set(SUM_COUNTS) & set(between[0])
     # called directly at depth 2, a window is published by the next call:
     # only the drained one was published before a later one was snapshotted
@@ -196,6 +196,7 @@ def test_counts_and_ingest_only_grow_and_add_up_over_the_records(served):
     # the history goes up whole here, where a device's 16 rows are under
     # the smallest bucket of rows (tests/test_sharded_put.py: compact)
     assert grew["hist_rows_sent"] == 8 * 16 * len(between)
+    assert grew["rows_fused"] == 0  # the XLA trunk: the CPU's
     row = between[0]
     assert row["rows_program"] == 8 * 16  # node bucket × workload bucket
     assert row["rows_work"] == 3  # node-m's pods
@@ -310,7 +311,7 @@ def test_the_ledger_keeps_the_last_records_kept_and_every_count():
     for seq in range(RECORDS_KEPT + 44):
         rec = WindowRecord(seq, 1000.0 + seq, begin=float(seq))
         rec.snapshot, rec.batch, rec.assembled = seq + 0.1, seq + 0.2, seq + 0.5
-        rec.rows_program = 128
+        rec.rows_program = rec.rows_fused = 128
         ledger.add(rec)
     kept, counts = ledger.snapshot()
     assert len(kept) == RECORDS_KEPT and kept[0].seq == 44
@@ -318,7 +319,8 @@ def test_the_ledger_keeps_the_last_records_kept_and_every_count():
                       "rows_program": 128 * (RECORDS_KEPT + 44),
                       "h2d_bytes": 0, "devices": 0,
                       "h2d_bytes_max_device": 0, "published_early": 0,
-                      "hist_rows_sent": 0}
+                      "hist_rows_sent": 0,
+                      "rows_fused": 128 * (RECORDS_KEPT + 44)}
     table = json.loads(records_json(kept))
     assert len(table["rows"]) == RECORDS_KEPT and table["rows"][0][0] == 44
     assert kept[0].text is not None  # rendered once, then served as text
